@@ -254,9 +254,9 @@ class GBDT:
             # kernel left, and the lowering-proven one); pallas_fused=off
             # / use_pallas=false force the MXU-shaped einsum oracle;
             # off-TPU picks the cpu_hist_method reference
-            hist_method=("fused" if cfg.use_pallas and _on_tpu()
+            hist_method=("fused" if cfg.use_pallas and on_tpu()
                          and cfg.pallas_fused != "off"
-                         else "einsum" if _on_tpu()   # MXU-friendly debug
+                         else "einsum" if on_tpu()    # MXU-friendly debug
                          else cfg.cpu_hist_method),   # scatter-add on CPU
             row_tile=cfg.pallas_row_tile,
             bucket_min_log2=cfg.pallas_bucket_min_log2,
@@ -275,6 +275,9 @@ class GBDT:
             cat_smooth_ratio=cfg.cat_smooth_ratio,
             min_cat_smooth=cfg.min_cat_smooth,
             max_cat_smooth=cfg.max_cat_smooth,
+            # off-TPU a Pallas kernel (partition_impl=compact) can only
+            # run interpreted; on a TPU backend it compiles or raises
+            hist_interpret=not on_tpu(),
             split_find=cfg.split_find)
         self._setup_grower(cfg, train)
         # rollback must act BEFORE the next iteration trains on poisoned
@@ -532,7 +535,7 @@ class GBDT:
         # grower re-checks the same gate at trace time as a safety net
         if self.grower_cfg.hist_method == "fused":
             from .data.packing import PACK_JOINT_BINS
-            from .grower import fused_gate_reason
+            from .grower import fused_fallback_method, fused_gate_reason
             plan = self._pack_plan
             hw = (max(PACK_JOINT_BINS, self.grower_cfg.max_bin)
                   if plan is not None else self.grower_cfg.max_bin)
@@ -542,13 +545,14 @@ class GBDT:
                 train.binned.dtype, jnp.float32, hw, ncols,
                 self.grower_cfg.ordered_bins == "on" and plan is None)
             if reason is not None:
-                log.warning("pallas_fused=on unavailable (%s); using the "
-                            "gen-1 pallas kernel", reason)
+                resolved = fused_fallback_method()
+                log.warning("hist_method=fused unavailable (%s); using the "
+                            "%s reference path", reason, resolved)
                 obs_counters.event("layout_downgrade", stage="boosting",
-                                   requested="fused", resolved="pallas",
+                                   requested="fused", resolved=resolved,
                                    reason=reason)
                 self.grower_cfg = self.grower_cfg._replace(
-                    hist_method="pallas")
+                    hist_method=resolved)
         # the bagged-subset optimization (gbdt.cpp:323-382 is_use_subset_)
         # gathers rows into a compact matrix — serial learner only for now
         self._can_subset = not use_dist
@@ -980,11 +984,11 @@ class GBDT:
                 gspmd_hist = "flat"
         # the gspmd builder keys off hist_method: "fused" = hybrid island,
         # anything else = flat (recorded as method=segment by dispatch).
-        # Off-TPU the island runs the kernel's interpret mode — same
-        # program shape, Pallas emulated — so the hybrid is CPU-testable.
+        # Off-TPU the island runs the kernel's interpret mode
+        # (hist_interpret, set with the config) — same program shape,
+        # Pallas emulated — so the hybrid is CPU-testable.
         self.grower_cfg = self.grower_cfg._replace(
-            hist_method="fused" if gspmd_hist == "fused" else "segment",
-            hist_interpret=(gspmd_hist == "fused" and not _on_tpu()))
+            hist_method="fused" if gspmd_hist == "fused" else "segment")
         obs_counters.event(
             "mesh_plan", data=plan.data, feature=plan.feature,
             block_shard_bins=plan.block_shard_bins,
@@ -2259,10 +2263,3 @@ def create_boosting(config: Config, train_set: Optional[TrainingData] = None,
     else:
         log.fatal("Unknown boosting type %s", t)
     return cls(config, train_set, objective)
-
-
-def _on_tpu() -> bool:
-    try:
-        return on_tpu()
-    except Exception:
-        return False

@@ -18,6 +18,7 @@ from .config import (Config, canonicalize_params, config_from_params,
                      parse_config_file)
 from .engine import train as train_fn
 from .utils import log
+from .utils.cache import enable_persistent_cache
 
 
 def parse_cli(argv: List[str]) -> Dict[str, str]:
@@ -223,6 +224,7 @@ def main(argv: List[str] = None) -> int:
     params = parse_cli(argv)
     cfg = config_from_params(params)
     log.set_verbosity(cfg.verbose)
+    enable_persistent_cache()
     if cfg.num_machines > 1:
         # bring the network layer up before any device work, exactly like
         # the reference CLI (application.cpp:190-224)

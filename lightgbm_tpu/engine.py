@@ -19,6 +19,7 @@ from .basic import Booster, Dataset
 from .config import canonicalize_params
 from .utils import faults as faults_mod
 from .utils import log
+from .utils.cache import enable_persistent_cache
 
 
 def train(params: Dict[str, Any], train_set: Dataset,
@@ -50,6 +51,7 @@ def train(params: Dict[str, Any], train_set: Dataset,
         num_boost_round = int(params.pop("num_iterations"))
     if "early_stopping_round" in params and params["early_stopping_round"]:
         early_stopping_rounds = int(params.pop("early_stopping_round"))
+    enable_persistent_cache()
     # structured telemetry (lightgbm_tpu.obs): trace_path writes a
     # Chrome-trace span file; telemetry=true enables counters/spans without
     # a file.  The counter registry is reset per training so two runs in

@@ -87,9 +87,10 @@ class GrowerConfig(NamedTuple):
     cat_smooth_ratio: float = 0.01
     min_cat_smooth: float = 5.0
     max_cat_smooth: float = 100.0
-    hist_interpret: bool = False     # run the fused Pallas kernel in
-                                     # interpret mode — CPU-side parity
-                                     # tests (never on-chip)
+    hist_interpret: bool = False     # run the Pallas kernels (fused
+                                     # histogram, compaction partition) in
+                                     # interpret mode — the off-TPU parity
+                                     # path; never inferred, never on-chip
     split_find: str = "fused"        # best-split scan formulation: fused
                                      # (per-direction reductions right off
                                      # the hot histogram, loop-invariant
@@ -186,6 +187,14 @@ def fused_gate_reason(bins_dtype, weights_dtype, hist_width: int,
     if use_ordered:
         return "ordered_bins=on replaces the row gather entirely"
     return None
+
+
+def fused_fallback_method() -> str:
+    """The XLA reference rung a fused request resolves to when
+    :func:`fused_gate_reason` refuses the layout: einsum on TPU (the
+    MXU-shaped form), segment elsewhere.  One answer for the grower's
+    trace-time gate and boosting's method resolution."""
+    return "einsum" if on_tpu() else "segment"
 
 
 def _row_leaf_from_intervals(order, leaf_start, leaf_cnt, n):
@@ -625,7 +634,7 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
         # numbers): einsum on TPU (the MXU-shaped form), segment on CPU.
         n_hist_cols = hbins.shape[1]
         use_fused = cfg.hist_method == "fused"
-        fallback_method = "einsum" if on_tpu() else "segment"
+        fallback_method = fused_fallback_method()
         if use_fused:
             reason = fused_gate_reason(hbins.dtype, dtype, hist_width,
                                        n_hist_cols, use_ordered)
@@ -817,13 +826,10 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
 
                 if use_compact:
                     from .ops.pallas_compact import compact_window
-                    # interpret tracks the COMPILE TARGET, not the host
-                    # backend: an un-interpreted fused program is being
-                    # lowered for a real TPU (incl. AOT lowering from a
-                    # CPU host, tests/test_mosaic_aot.py) and the kernel
-                    # must go through Mosaic; anything else is the
-                    # CPU/interpret path
-                    interp = cfg.hist_method != "fused" or cfg.hist_interpret
+                    # interpret mode is only ever REQUESTED (CPU tests
+                    # set cfg.hist_interpret), never inferred: on a TPU
+                    # backend the kernel compiles through Mosaic or raises
+                    interp = cfg.hist_interpret
                     if use_ordered:
                         payload, info = payload_cols()
                         new_win, newpay, nl = compact_window(

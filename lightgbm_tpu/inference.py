@@ -1,5 +1,5 @@
-"""High-QPS inference artifact: ensemble SoA node arrays + bucketed,
-donated-buffer microbatch executables.
+"""High-QPS inference artifact: ensemble SoA node arrays + bucketed
+microbatch executables.
 
 ``Predictor.predict`` (the training-side oracle) walks a Python list of
 :class:`~lightgbm_tpu.tree.Tree` objects per call — per-tree host
@@ -34,8 +34,9 @@ Boosting Decision Trees" is the layout reference):
   model array as an *argument* (nothing is baked in as a constant), so a
   hot-swapped model with the same bucket shape reuses the compiled
   executable — zero recompiles across a swap.  Batch shapes are padded up
-  a pow2-ish ladder (default 1/8/64/512/4096; ``serving_buckets`` param)
-  and the input buffer is donated on backends that support donation.
+  a pow2-ish ladder (default 1/8/64/512/4096; ``serving_buckets`` param).
+  Nothing is donated: no output has an input's shape and dtype, so a
+  donation could never be used (the v5e said so on every compile).
   :func:`jit_entries` exposes the compiled-signature count as the
   ``predict_jit_entries`` gauge (the ``grower_jit_entries`` discipline).
 
@@ -488,35 +489,18 @@ def _aux_jitted():
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted(donate: bool):
-    if donate:
-        return (jax.jit(_leaves_from_raw_impl, donate_argnums=(0,)),
-                jax.jit(_leaves_from_binned_impl,
-                        donate_argnums=(0, 1, 2, 3)))
+def _jitted():
     return (jax.jit(_leaves_from_raw_impl),
             jax.jit(_leaves_from_binned_impl))
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted_packed(donate: bool):
+def _jitted_packed():
     """Packed-node-word twins (serving_traversal=packed).  ``depth`` is a
     traced scalar, so one executable pair serves every same-shape model —
     the hot-swap zero-recompile contract is unchanged."""
-    if donate:
-        return (jax.jit(_leaves_from_raw_packed_impl, donate_argnums=(0,)),
-                jax.jit(_leaves_from_binned_packed_impl,
-                        donate_argnums=(0, 1, 2, 3)))
     return (jax.jit(_leaves_from_raw_packed_impl),
             jax.jit(_leaves_from_binned_packed_impl))
-
-
-def _donate_ok() -> bool:
-    """Donate the microbatch input buffers only where donation is real —
-    the CPU backend warns 'donated buffers were not usable' per compile."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:           # pragma: no cover - backend init failures
-        return False
 
 
 def jit_entries() -> int:
@@ -524,14 +508,7 @@ def jit_entries() -> int:
     ``predict_jit_entries`` gauge (``grower_jit_entries`` discipline): a
     mixed-size request replay over a warmed ladder must not move it.
     (Wrapping via ``_jitted`` is free — only executions compile.)"""
-    total = 0
-    for donate in (False, True):
-        for fn in _jitted(donate) + _jitted_packed(donate):
-            try:
-                total += int(fn._cache_size())
-            except Exception:       # pragma: no cover - jax API drift
-                return -1
-    return total
+    return sum(int(fn._cache_size()) for fn in _jitted() + _jitted_packed())
 
 
 # ----------------------------------------------------------------- engine
@@ -565,7 +542,6 @@ class PredictEngine:
         self.buckets = parse_serving_buckets(buckets)
         self.num_class = max(num_class, 1)
         self.timers = PhaseTimers()
-        self._donate = _donate_ok()
         self._warmed = False
         if backend not in ("auto", "xla", "native"):
             raise ValueError(f"predict engine backend must be auto, xla, or "
@@ -605,10 +581,7 @@ class PredictEngine:
                            "field width)")
                 return "xla"
             return "packed"
-        try:
-            backend_cpu = jax.default_backend() == "cpu"
-        except Exception:       # pragma: no cover - backend init failure
-            backend_cpu = True
+        backend_cpu = jax.default_backend() == "cpu"
         return "packed" if (packable and backend_cpu) else "xla"
 
     def _resolve_backend(self, want: str, model_str: Optional[str]) -> str:
@@ -617,10 +590,7 @@ class PredictEngine:
         native_ok = False
         if model_str is not None:
             from . import native
-            try:
-                backend_cpu = jax.default_backend() == "cpu"
-            except Exception:   # pragma: no cover - backend init failure
-                backend_cpu = True
+            backend_cpu = jax.default_backend() == "cpu"
             if native.available() and (want == "native" or backend_cpu):
                 try:
                     self._native = native.NativePredictor(model_str=model_str)
@@ -678,16 +648,16 @@ class PredictEngine:
     # ------------------------------------------------- traversal plumbing
 
     def _raw_fn(self):
-        return (_jitted_packed(self._donate)[0] if self.traversal == "packed"
-                else _jitted(self._donate)[0])
+        return (_jitted_packed()[0] if self.traversal == "packed"
+                else _jitted()[0])
 
     def _binned_fn(self):
-        return (_jitted_packed(self._donate)[1] if self.traversal == "packed"
-                else _jitted(self._donate)[1])
+        return (_jitted_packed()[1] if self.traversal == "packed"
+                else _jitted()[1])
 
     def _raw_args(self) -> tuple:
         """Model-side arguments of the raw-input executable (after the
-        donated batch buffer)."""
+        batch buffer)."""
         b = self.bundle
         if self.traversal == "packed":
             return (b.thr_table, b.node_w0, b.node_w1,

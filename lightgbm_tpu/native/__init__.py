@@ -5,15 +5,19 @@ predictor.hpp); this package provides the same split for the TPU framework:
 text parsing, value->bin quantization and model prediction run in an
 OpenMP-parallel shared library, while training compute stays on TPU.
 
-The library builds on demand with g++ (cached next to the source); when no
-toolchain is available every entry point degrades to the pure-python
-implementations, so the native layer is an accelerator, not a dependency.
+The library builds on demand with g++ (cached next to the source, keyed
+on a hash of the sources + the Python ABI so a copied or stale binary is
+rebuilt, never loaded); when no toolchain is available every entry point
+degrades to the pure-python implementations, so the native layer is an
+accelerator, not a dependency.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sysconfig
 import threading
 from typing import List, Optional, Tuple
 
@@ -23,6 +27,7 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "gbt_native.cpp")
 _SRC_TRAIN = os.path.join(_DIR, "gbt_capi_train.cpp")
 _LIB_PATH = os.path.join(_DIR, "_gbt_native.so")
+_KEY_PATH = _LIB_PATH + ".key"     # sidecar: _source_key() of the build
 
 _lock = threading.Lock()
 _lib = None
@@ -30,10 +35,48 @@ _load_failed = False
 _has_train_api = False
 
 
+def _source_key() -> str:
+    """What a binary must have been built from to be loadable: both
+    sources and the Python ABI the training shim links against."""
+    h = hashlib.sha256((sysconfig.get_config_var("SOABI") or "").encode())
+    for src in (_SRC, _SRC_TRAIN):
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _build_is_current() -> bool:
+    try:
+        with open(_KEY_PATH) as f:
+            recorded = f.read().strip()
+    except OSError:
+        return False
+    return os.path.exists(_LIB_PATH) and recorded == _source_key()
+
+
 def _build() -> bool:
+    """Compile to a temporary name, drop the old key, then move binary
+    and key into place — at no point does a key sit beside a binary it
+    does not describe, whatever a concurrent or interrupted build does."""
+    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    try:
+        if not _compile(tmp):
+            return False
+        if os.path.exists(_KEY_PATH):
+            os.remove(_KEY_PATH)
+        os.replace(tmp, _LIB_PATH)
+        with open(tmp, "w") as f:
+            f.write(_source_key())
+        os.replace(tmp, _KEY_PATH)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    return True
+
+
+def _compile(out_path: str) -> bool:
     import sys
-    import sysconfig
-    base = ["g++", "-O3", "-shared", "-fPIC", "-o", _LIB_PATH]
+    base = ["g++", "-O3", "-shared", "-fPIC", "-o", out_path]
     # preferred: serving runtime + the CPython-embedding training ABI,
     # linked against libpython so standalone C callers (and hosts whose
     # python binary does not re-export libpython symbols) resolve Py_*
@@ -256,14 +299,9 @@ def get_lib() -> Optional[ctypes.CDLL]:
             _load_failed = True
             return None
         try:
-            src_mtime = max(os.path.getmtime(_SRC),
-                            os.path.getmtime(_SRC_TRAIN)
-                            if os.path.exists(_SRC_TRAIN) else 0.0)
-            if (not os.path.exists(_LIB_PATH)
-                    or os.path.getmtime(_LIB_PATH) < src_mtime):
-                if not _build():
-                    _load_failed = True
-                    return None
+            if not _build_is_current() and not _build():
+                _load_failed = True
+                return None
             _lib = _bind(ctypes.CDLL(_LIB_PATH))
         except OSError:
             _load_failed = True

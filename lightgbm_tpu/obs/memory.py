@@ -63,12 +63,11 @@ def device_memory_stats(device=None) -> Optional[Dict[str, int]]:
     """Normalized ``device.memory_stats()`` or None when the backend does
     not expose allocator stats (CPU).  Keys (when present):
     ``bytes_in_use``, ``peak_bytes_in_use``, ``bytes_limit``."""
-    try:
-        import jax
-        dev = device if device is not None else jax.devices()[0]
-        stats = dev.memory_stats()
-    except Exception:
-        return None
+    import jax
+    # local_devices: under multi-process jax.devices()[0] belongs to
+    # rank 0, and another process's device has no stats to read
+    dev = device if device is not None else jax.local_devices()[0]
+    stats = dev.memory_stats()
     if not stats:
         return None
     out = {}
@@ -501,11 +500,20 @@ def predict_hbm(rows: int, features: int, bins: int = 255, leaves: int = 31,
 
 
 def device_capacity(device=None) -> Optional[int]:
-    """Total device memory in bytes when the backend reports it (TPU
-    ``bytes_limit``), else None (CPU host memory is not the budgeted
-    resource)."""
-    stats = device_memory_stats(device)
-    return stats.get("bytes_limit") if stats else None
+    """Total device memory in bytes (``bytes_limit``).  None only where
+    the backend keeps no allocator stats (CPU: host memory is not the
+    budgeted resource); a TPU that reports no limit is an error, not a
+    placement walk that quietly goes resident."""
+    import jax
+    dev = device if device is not None else jax.local_devices()[0]
+    stats = device_memory_stats(dev)
+    if stats and "bytes_limit" in stats:
+        return stats["bytes_limit"]
+    if dev.platform == "tpu":
+        raise RuntimeError(
+            f"{dev} reports no bytes_limit (memory_stats={stats}); the "
+            "placement pre-flight needs the device capacity")
+    return None
 
 
 def preflight(pred: Dict[str, Any], hbm_budget: float = 0.0,

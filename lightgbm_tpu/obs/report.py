@@ -501,8 +501,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     try:
         if as_json:
             # machine-readable: one entry per file (rank-tagged) so
-            # tpu_capture_phase2.sh / decide_flips.py consume reports
-            # without re-parsing markdown
+            # decide_flips.py consumes reports without re-parsing markdown
             files = []
             for p, rank, events in load_events_ranked(argv):
                 summary = summary_payload(events, "counters") or {}

@@ -48,8 +48,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams, MemorySpace
-
 BLK = 512       # rows per block; every gather-bucket size divides it
 LANES = 128     # output DMA width must be a multiple of this (Mosaic)
 
@@ -115,13 +113,13 @@ def compact_pallas(mat: jnp.ndarray, bases: jnp.ndarray,
             num_scalar_prefetch=1,
             grid=(2, nb),
             in_specs=[pl.BlockSpec((BLK, cp), lambda p, k, bases: (k, 0))],
-            out_specs=pl.BlockSpec(memory_space=MemorySpace.ANY),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[pltpu.VMEM((BLK, out_w), jnp.float32),
                             pltpu.SemaphoreType.DMA],
         ),
         out_shape=jax.ShapeDtypeStruct((size + BLK, out_w), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(bases, mat)
 

@@ -33,8 +33,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams, MemorySpace
-
 NUM_CH = 6   # weight channels: (g_hi, g_lo, h_hi, h_lo, c, unused)
 LANES = 128  # TPU vector register lane width — bin axis is padded to this
 NIB = 16     # nibble radix: bin = hi*16 + lo, each one-hot 16 wide
@@ -258,8 +256,8 @@ def hist6_fused(order: jnp.ndarray, panel: jnp.ndarray, start, cnt,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(num_row_tiles,),
-            in_specs=[pl.BlockSpec(memory_space=MemorySpace.ANY),
-                      pl.BlockSpec(memory_space=MemorySpace.ANY)],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((NUM_CH * NIB, n_cols_pad * NIB),
                                    lambda ri, sc: (0, 0)),
             scratch_shapes=[pltpu.SMEM((fused_idx_fetch(row_tile),),
@@ -272,7 +270,8 @@ def hist6_fused(order: jnp.ndarray, panel: jnp.ndarray, start, cnt,
         out_shape=jax.ShapeDtypeStruct((NUM_CH * NIB, n_cols_pad * NIB),
                                        jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
     )(sc, order, panel)
     # [(ch, hi), (f, lo)] -> [ch, f, hi*16+lo], all in XLA (the same
     # epilogue the retired gen-1 nibble form used)

@@ -68,7 +68,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..data.packing import (PACK_JOINT_BINS, pack_fused_panel,
@@ -80,7 +80,6 @@ from ..obs import trace as obs_trace
 from ..obs.counters import counters as obs_counters
 from ..ops.histogram import subset_histogram_flat, subset_histogram_fused_local
 from ..ops.split import best_split, leaf_output, make_fused_ctx
-from .learner import _CHECK_KW, shard_map
 from .mesh import BATCH_AXIS, FEATURE_AXIS
 
 
@@ -118,7 +117,7 @@ def make_gspmd_grower(cfg: GrowerConfig, mesh: Mesh,
 
     def smap(fn, in_specs, out_specs):
         return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, **{_CHECK_KW: False})
+                         out_specs=out_specs, check_vma=False)
 
     def grow_impl(bins, hist_src, gw, hw, cw, meta: FeatureMeta,
                   feat_valid):

@@ -36,18 +36,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-import inspect
-
-# the replication-check kwarg was renamed check_rep -> check_vma across
-# jax releases; resolve the spelling this runtime accepts once (same
-# version-tolerance discipline as ops/pallas_compat.py)
-_CHECK_KW = ("check_vma" if "check_vma"
-             in inspect.signature(shard_map).parameters else "check_rep")
 
 from ..grower import (FeatureMeta, GrowerConfig, SerialStrategy, TreeArrays,
                       expand_bundle_hist, make_expand_maps, make_grower)
@@ -367,5 +357,5 @@ def make_distributed_grower(cfg: GrowerConfig, mesh: Mesh,
                    in_specs=(bins_spec, *hist_spec, in_row, in_row, in_row,
                              meta_spec, P()),
                    out_specs=(tree_spec, row_out),
-                   **{_CHECK_KW: False})
+                   check_vma=False)
     return jax.jit(fn)

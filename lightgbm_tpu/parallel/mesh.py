@@ -26,24 +26,6 @@ FEATURE_AXIS = "feature"
 BATCH_AXIS = "batch"
 
 
-def distributed_is_initialized() -> bool:
-    """Is the multi-process runtime up?  ``jax.distributed.is_initialized``
-    is not present on every jax this repo supports (0.4.37 dropped it from
-    the public module), so fall back to the distributed global state the
-    way the ops/pallas_compat.py shim handles renamed Pallas API."""
-    try:
-        return bool(jax.distributed.is_initialized())
-    except AttributeError:
-        pass
-    try:
-        from jax._src import distributed as _dist
-        return getattr(_dist.global_state, "client", None) is not None
-    except Exception:       # pragma: no cover - future-jax defensive
-        from ..obs.counters import counters
-        counters.inc("distributed_probe_fallback")
-        return False
-
-
 def make_mesh(num_devices: int = 0, axis_name: str = DATA_AXIS,
               devices: Optional[Sequence] = None) -> Mesh:
     """1-D mesh over the given axis (rows for data-parallel, columns for
@@ -401,25 +383,6 @@ def parse_mesh_shape(spec: str, n_devices: int, prefer: str = "data"):
         f"(e.g. 2x4); got {spec!r}")
 
 
-def _enable_cpu_collectives() -> None:
-    """Multi-process CPU needs a cross-process collectives transport: jax
-    0.4.37's default (``none``) makes every cross-host computation fail
-    with "Multiprocess computations aren't implemented on the CPU
-    backend".  Select gloo — but only when the job explicitly runs on CPU
-    (the 2-process CI harness); TPU slices keep their ICI transport."""
-    import os
-    plats = jax.config.jax_platforms or os.environ.get("JAX_PLATFORMS", "")
-    if "cpu" not in str(plats):
-        return
-    try:
-        # flag-only option: no attribute access, go through the value table
-        cur = jax.config.values.get("jax_cpu_collectives_implementation")
-        if cur in (None, "none"):
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, KeyError):  # pragma: no cover - older/newer jax
-        pass
-
-
 # epoch the runtime was last initialized under (the incarnation fence,
 # parallel/sync.py): a relaunched in-process training at a NEWER epoch
 # tears the stale runtime down and re-initializes instead of rejoining a
@@ -434,7 +397,7 @@ def shutdown_distributed() -> None:
     the dead incarnation's coordination client before the new epoch's
     barrier can form."""
     global _init_epoch
-    if distributed_is_initialized():
+    if jax.distributed.is_initialized():
         jax.distributed.shutdown()
     _init_epoch = None
 
@@ -452,7 +415,6 @@ def init_distributed(coordinator_address: Optional[str] = None,
     global _init_epoch
     if coordinator_address is None:
         return
-    _enable_cpu_collectives()
     kwargs = {}
     if timeout and timeout > 0:
         kwargs["initialization_timeout"] = max(1, int(timeout))
@@ -460,10 +422,6 @@ def init_distributed(coordinator_address: Optional[str] = None,
         jax.distributed.initialize(coordinator_address=coordinator_address,
                                    num_processes=num_processes,
                                    process_id=process_id, **kwargs)
-    except TypeError:       # older jax: no initialization_timeout kwarg
-        jax.distributed.initialize(coordinator_address=coordinator_address,
-                                   num_processes=num_processes,
-                                   process_id=process_id)
     except RuntimeError as e:
         from ..obs.counters import counters
         from .sync import CollectiveError
@@ -578,7 +536,7 @@ def init_distributed_from_config(cfg) -> bool:
             frame_epoch=my_epoch, group_epoch=stamped)
     # must not touch the backend (jax.devices/process_count) before
     # jax.distributed.initialize; use is_initialized to test idempotently
-    if distributed_is_initialized():
+    if jax.distributed.is_initialized():
         if _init_epoch is not None and _init_epoch != my_epoch:
             # in-process relaunch under a new incarnation: the old
             # runtime's coordination client belongs to a dead group

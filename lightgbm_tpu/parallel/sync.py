@@ -138,17 +138,9 @@ def configure(timeout: Optional[float] = None,
 
 def process_count() -> int:
     """Number of participating processes; 1 when the distributed runtime is
-    not initialized (safe to call before backend init).
-
-    Goes through the mesh.py ``distributed_is_initialized`` compat shim:
-    the bare ``jax.distributed.is_initialized`` probe this used to do
-    raises AttributeError on jax 0.4.37 — which the old ``except`` turned
-    into a silent, WRONG "1 process" answer inside real multi-process
-    runs."""
+    not initialized (safe to call before backend init)."""
     import jax
-
-    from .mesh import distributed_is_initialized
-    if not distributed_is_initialized():
+    if not jax.distributed.is_initialized():
         return 1
     return jax.process_count()
 
@@ -157,9 +149,7 @@ def process_index() -> int:
     """This process's rank; 0 when the distributed runtime is not
     initialized (the single-process identity)."""
     import jax
-
-    from .mesh import distributed_is_initialized
-    if not distributed_is_initialized():
+    if not jax.distributed.is_initialized():
         return 0
     return jax.process_index()
 

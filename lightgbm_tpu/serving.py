@@ -47,6 +47,7 @@ from .obs import metrics as obs_metrics
 from .obs import trace as obs_trace
 from .obs.counters import counters as obs_counters
 from .utils import log
+from .utils.cache import enable_persistent_cache
 
 # per-bucket latency histogram edges (ms) for the obs report
 _HIST_EDGES_MS = (0.5, 1, 2, 5, 10, 20, 50, 100, 500)
@@ -135,6 +136,7 @@ class ModelServer:
                  params: Optional[Dict[str, Any]] = None,
                  prewarm: bool = True, autostart: bool = True):
         from .basic import Booster
+        enable_persistent_cache()
         self.params = dict(params or {})
         cfg = config_from_params(
             {k: v for k, v in self.params.items()})

@@ -14,10 +14,10 @@ Accepted entry forms (sniffed per file, mixed freely):
   (the external runner banks the last 2000 chars of output as ``tail``
   and the last JSON line as ``parsed``);
 * **bare bench JSON** — ``bench.py`` stdout (the last ``{``-line rule);
-* **probe_failed artifact** — ``{"kind": "probe_failed", ...}`` written
-  by ``tpu_capture_phase2.sh fail_artifact``;
-* **capture directory** — ``docs/tpu_capture_*``; its ``bench_1m.json``
-  headline artifact is the entry.
+* **probe_failed artifact** — ``{"kind": "probe_failed", ...}``, the
+  record a stage that died leaves behind;
+* **capture directory** — a directory whose ``bench_1m.json`` headline
+  artifact is the entry.
 
 Verdicts (entries are taken in the given CLI order = time order):
 

@@ -40,7 +40,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 from lightgbm_tpu.utils.cache import enable_persistent_cache  # noqa: E402
-enable_persistent_cache()   # live-config bootstrap; see utils/cache.py
+enable_persistent_cache()
 
 from lightgbm_tpu.grower import FeatureMeta, GrowerConfig  # noqa: E402
 # the interception machinery (lax monkeypatch, byte counting, the

@@ -1,7 +1,7 @@
 """Automated regression diffing between two telemetry artifacts.
 
-The capture playbook's before/after verdicts were eyeballed JSON; this
-script mechanizes them for CI and ``decide_flips.py``:
+Before/after verdicts used to be eyeballed JSON; this script mechanizes
+them for CI and ``decide_flips.py``:
 
     python scripts/obs_diff.py BASELINE CANDIDATE [options]
 
@@ -19,9 +19,8 @@ Both artifacts must be the same kind; the kind is sniffed from content:
   ``{"schema_version", "samples"}`` block ``obs/metrics.snapshot()``
   emits (bench JSONs embed one as ``metrics_snapshot``) — drift on
   latency/memory samples, dispatch-identity label-set mismatch;
-* **probe_failed record** (``{"kind": "probe_failed", ...}``, written by
-  ``tpu_capture_phase2.sh fail_artifact`` or the microprobe's SIGTERM
-  flush when a stage dies) — sniffed on EITHER side: a failed candidate
+* **probe_failed record** (``{"kind": "probe_failed", ...}``, e.g. the
+  microprobe's SIGTERM flush when a stage dies) — sniffed on EITHER side: a failed candidate
   is a FAIL finding naming the dead stage and exit code, a failed
   baseline is a warn (nothing to compare against), never a load error.
 

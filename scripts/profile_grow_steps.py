@@ -38,10 +38,8 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                 ".jax_cache"))
+from lightgbm_tpu.utils.cache import enable_persistent_cache  # noqa: E402
+enable_persistent_cache()
 
 
 def make_problem(n, f, b, seed=42):

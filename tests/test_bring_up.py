@@ -1,0 +1,150 @@
+"""Bring-up contracts (PR 21): nothing on the main path hides the device.
+
+CPU-tier pins of what ``chip_smoke.py`` relies on: the smoke and the bench
+refuse a host with no accelerator, the compile cache is placed by one
+helper that defers to the environment, an unfusable layout resolves to a
+histogram method that exists, a native library built from other sources
+is rebuilt rather than loaded, and importing the package touches no
+backend (one process owns the chip).
+"""
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run(args, **env):
+    return subprocess.run(
+        [sys.executable] + args, cwd=ROOT, capture_output=True, text=True,
+        timeout=300, env={**os.environ, "JAX_PLATFORMS": "cpu", **env})
+
+
+def _json_lines(text):
+    out = []
+    for line in text.splitlines():
+        if line.strip().startswith("{"):
+            try:
+                out.append(json.loads(line))
+            except json.JSONDecodeError:
+                pass
+    return out
+
+
+def test_chip_smoke_refuses_cpu_naming_the_platform():
+    r = _run(["chip_smoke.py"])
+    assert r.returncode != 0
+    assert "'cpu'" in r.stderr and "not 'tpu'" in r.stderr, r.stderr[-500:]
+    assert not _json_lines(r.stdout), r.stdout[-500:]
+
+
+def test_bench_without_chip_exits_nonzero_with_the_childs_error():
+    r = _run(["bench.py"])
+    assert r.returncode != 0
+    assert "wanted tpu, got cpu" in r.stderr, r.stderr[-500:]
+    assert not _json_lines(r.stdout), r.stdout[-500:]
+
+
+def test_package_import_initialises_no_backend():
+    """Under a platform that does not exist any backend touch raises, so
+    a clean import proves the parent of a chip-owning child stays off
+    the device."""
+    imports = ("import lightgbm_tpu, lightgbm_tpu.supervisor, "
+               "lightgbm_tpu.serving, lightgbm_tpu.cli\n")
+    r = _run(["-c", imports], JAX_PLATFORMS="no_such_platform")
+    assert r.returncode == 0, r.stderr[-800:]
+    r = _run(["-c", imports + "import jax; jax.devices()\n"],
+             JAX_PLATFORMS="no_such_platform")
+    assert r.returncode != 0 and "no_such_platform" in r.stderr
+
+
+def test_cache_helper_defers_to_the_environment(monkeypatch):
+    import jax
+    from lightgbm_tpu.utils import cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert cache.enable_persistent_cache() == "/some/dir"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert cache.enable_persistent_cache() == os.path.join(
+            ROOT, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            ROOT, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_unfusable_layout_resolves_to_a_method_that_exists(monkeypatch):
+    """600 histogram columns exceed the fused kernel's ceiling: the
+    downgrade must name a method ``subset_histogram`` accepts (it used to
+    name the deleted gen-1 kernel) and the training must run on it."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu import boosting, grower
+    from lightgbm_tpu.obs.counters import counters
+    from lightgbm_tpu.ops.pallas_hist import FUSED_MAX_COLS
+    monkeypatch.setattr(boosting, "on_tpu", lambda: True)
+    monkeypatch.setattr(grower, "on_tpu", lambda: True)
+    counters.reset()
+    rng = np.random.RandomState(0)
+    X = rng.randn(300, 600)
+    assert X.shape[1] > FUSED_MAX_COLS
+    y = (X[:, 0] + X[:, 1] > 0).astype(np.float64)
+    bst = lgb.train({"objective": "binary", "num_leaves": 4, "verbose": -1,
+                     "min_data_in_leaf": 5, "enable_bin_packing": False},
+                    lgb.Dataset(X, label=y), num_boost_round=2,
+                    verbose_eval=False)
+    resolved = bst.inner.grower_cfg.hist_method
+    assert resolved == "einsum"          # the TPU answer of the shared gate
+    events = [e for e in counters.events("layout_downgrade")
+              if e.get("requested") == "fused"]
+    assert events and events[0]["resolved"] == resolved, events
+    assert set(counters.get("hist_dispatch")) == {
+        f"interpret=False,method={resolved},site={s}"
+        for s in ("root", "split")}
+    assert bst.inner.models[0].num_leaves > 1
+
+
+def test_native_library_with_another_source_hash_is_rebuilt(tmp_path,
+                                                            monkeypatch):
+    from lightgbm_tpu import native
+    lib_path = tmp_path / "_gbt_native.so"
+    key_path = tmp_path / "_gbt_native.so.key"
+    builds = []
+
+    def fake_compile(out_path):
+        builds.append(out_path)
+        with open(out_path, "w") as f:
+            f.write("built from the current sources")
+        return True
+
+    monkeypatch.setattr(native, "_LIB_PATH", str(lib_path))
+    monkeypatch.setattr(native, "_KEY_PATH", str(key_path))
+    monkeypatch.setattr(native, "_compile", fake_compile)
+    monkeypatch.setattr(native, "_bind", lambda lib: lib)
+    monkeypatch.setattr(native.ctypes, "CDLL",
+                        lambda path: open(path).read())
+    monkeypatch.delenv("LGBM_TPU_NO_NATIVE", raising=False)
+
+    def load():
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_load_failed", False)
+        return native.get_lib()
+
+    # a binary copied in from a checkout with other sources
+    lib_path.write_text("stale binary")
+    key_path.write_text("0" * 64)
+    assert load() == "built from the current sources"
+    assert len(builds) == 1
+    assert key_path.read_text() == native._source_key()
+    # same sources again: loaded as is
+    assert load() == "built from the current sources"
+    assert len(builds) == 1
+    # a binary with no recorded key at all (the pre-PR-21 layout)
+    key_path.unlink()
+    load()
+    assert len(builds) == 2

@@ -307,6 +307,13 @@ def test_gspmd_hist_fused_downgrades_loudly_on_unfusable_layout():
 
 # ---- compiled-HLO collective audit -----------------------------------------
 
+# Recorded on jax 0.9.0 (XLA:CPU, 8 virtual devices).  Its all-reduce
+# combiner folds the three root scalar sums (g, h, count) into the ROOT
+# histogram's reduction as one tuple all-reduce, so the largest reduction
+# payload is the histogram plus 12 bytes; the per-split reduction inside
+# the while body is the bare histogram.  (jax 0.4.37 kept them apart.)
+ROOT_SCALAR_BYTES = 3 * 4
+
 
 def _compile_gspmd(mesh):
     cfg = _cfg()
@@ -334,7 +341,8 @@ def test_hlo_census_scattered_reduce_no_pool_allgather():
     reduces = {op: rec for op, rec in census.items()
                if op in ("all-reduce", "reduce-scatter")}
     assert reduces, f"no histogram reduction collective found: {census}"
-    assert max(r["max_bytes"] for r in reduces.values()) <= slice_hist, (
+    assert (max(r["max_bytes"] for r in reduces.values())
+            <= slice_hist + ROOT_SCALAR_BYTES), (
         f"histogram reduction moves more than the feature shard's slice "
         f"({slice_hist} B) — the scattered-reduce contract broke: {census}")
     ag = census.get("all-gather", {"max_bytes": 0})
@@ -353,7 +361,8 @@ def test_hlo_census_data_parallel_is_plain_allreduce():
     reduces = {op: rec for op, rec in census.items()
                if op in ("all-reduce", "reduce-scatter")}
     assert reduces
-    assert max(r["max_bytes"] for r in reduces.values()) == full_hist
+    assert (max(r["max_bytes"] for r in reduces.values())
+            == full_hist + ROOT_SCALAR_BYTES)
     assert "all-gather" not in census
 
 
@@ -381,7 +390,8 @@ def test_hlo_census_fused_hybrid_no_rowshard_or_pool_allgather():
     reduces = {op: rec for op, rec in census.items()
                if op in ("all-reduce", "reduce-scatter")}
     assert reduces, f"no histogram reduction collective found: {census}"
-    assert max(r["max_bytes"] for r in reduces.values()) <= slice_hist, (
+    assert (max(r["max_bytes"] for r in reduces.values())
+            <= slice_hist + ROOT_SCALAR_BYTES), (
         f"hybrid reduction moves more than the feature shard's slice "
         f"({slice_hist} B): {census}")
     ag = census.get("all-gather", {"max_bytes": 0})
@@ -399,7 +409,8 @@ def test_hlo_census_fused_hybrid_data_parallel():
     reduces = {op: rec for op, rec in census.items()
                if op in ("all-reduce", "reduce-scatter")}
     assert reduces
-    assert max(r["max_bytes"] for r in reduces.values()) == full_hist
+    assert (max(r["max_bytes"] for r in reduces.values())
+            == full_hist + ROOT_SCALAR_BYTES)
     assert "all-gather" not in census
 
 

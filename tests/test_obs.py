@@ -318,16 +318,15 @@ def test_cli_merges_multiple_traces_rank_tagged(tmp_path):
 def test_collectives_intercept_records_traced_psum():
     import jax
     import jax.numpy as jnp
+    from jax import lax, shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-    from lightgbm_tpu.parallel.learner import _CHECK_KW, shard_map
-    from jax import lax
     mesh = Mesh(np.array(jax.devices()[:2]), ("d",))
 
     def f(x):
         return lax.psum(x, "d")
 
     sm = shard_map(f, mesh=mesh, in_specs=(P("d"),), out_specs=P(),
-                   **{_CHECK_KW: False})
+                   check_vma=False)
     counters.reset()
     with obs_coll.intercept(count=True) as records:
         jax.jit(sm).lower(jax.ShapeDtypeStruct((8,), jnp.float32))

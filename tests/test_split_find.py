@@ -1,4 +1,4 @@
-"""Round 8: fused split-find parity + deep-tree fixed-cost regression.
+"""Round 8: fused split-find parity.
 
 The fused scan (``ops/split.py:_fused_numerical``) restructures ONLY the
 candidate selection — per-direction row reductions instead of the packed
@@ -11,15 +11,8 @@ tests pin that contract byte-for-byte:
   the hoisted loop-invariant ctx;
 * the full grower at 255 leaves: ``split_find=fused`` and ``chain`` grow
   BYTE-identical trees (bf16-exact integer weights, the
-  test_fused_hist.py discipline);
-* a leaves-sweep-shaped ratchet: the per-tree cost RATIO between 255 and
-  31 leaves at a small N stays under a recorded ceiling, so a
-  reintroduced per-split fixed cost (the round-7 copy-insertion class, a
-  de-hoisted find chain, per-split host callbacks) fails tier-1 instead
-  of waiting for a bench run.
+  test_fused_hist.py discipline).
 """
-import time
-
 import numpy as np
 import pytest
 
@@ -148,57 +141,3 @@ def test_grower_255_leaf_fused_chain_byte_identical(has_missing):
         a, b = getattr(t_f, name), getattr(t_c, name)
         assert a.tobytes() == b.tobytes(), (has_missing, name)
     assert rl_f.tobytes() == rl_c.tobytes()
-
-
-# ---- deep-tree fixed-cost ratchet (tier-1 twin of the bench leaves_sweep)
-#
-# Per-tree time at fixed N decomposes into row-proportional work
-# (~N * log2(leaves): grows ~1.6x from 31 to 255 leaves here) and
-# per-split fixed cost (grows ~8.1x: 254/30 splits).  Measured on the
-# round-8 code this RATIO (255-leaf time / 31-leaf time) sits around
-# 2.5-3.5 on an idle 1-core host; the round-7 regression class (whole-pool
-# copy insertion re-widening, ~5 ms/split at this shape's scale) pushes it
-# past 6.  The ratchet at 5.5 leaves ~1.7x timing-noise headroom while
-# still failing loudly on any reintroduced per-split fixed cost.  A ratio
-# is used instead of absolute ms so the pin survives slow/loaded CI hosts.
-
-LEAVES_RATIO_RATCHET = 5.5
-
-
-def test_leaves_sweep_ratio_ratchet():
-    n, f, b = 30_000, 12, 127
-    rng = np.random.RandomState(3)
-    bins = jnp.asarray(rng.randint(0, b, size=(n, f)).astype(np.uint8))
-    g = jnp.asarray(rng.randn(n).astype(np.float32))
-    h = jnp.asarray((np.abs(rng.randn(n)) + 0.1).astype(np.float32))
-    c = jnp.ones((n,), jnp.float32)
-    meta = FeatureMeta(num_bin=jnp.full((f,), b, jnp.int32),
-                       missing_type=jnp.zeros((f,), jnp.int32),
-                       default_bin=jnp.zeros((f,), jnp.int32),
-                       is_categorical=jnp.zeros((f,), bool))
-    fv = jnp.ones((f,), bool)
-
-    def per_tree(leaves):
-        cfg = GrowerConfig(num_leaves=leaves, min_data_in_leaf=1,
-                           min_sum_hessian_in_leaf=1.0, max_bin=b,
-                           hist_method="segment", has_missing=False)
-        grow = jax.jit(make_grower(cfg))
-        out = grow(bins, g, h, c, meta, fv)
-        jax.block_until_ready(out)
-        assert int(out[0].num_leaves) == leaves    # fully grown
-        best = float("inf")
-        for _ in range(3):
-            t0 = time.perf_counter()
-            jax.block_until_ready(grow(bins, g, h, c, meta, fv))
-            best = min(best, time.perf_counter() - t0)
-        return best
-
-    t31 = per_tree(31)
-    t255 = per_tree(255)
-    ratio = t255 / t31
-    assert ratio < LEAVES_RATIO_RATCHET, (
-        f"255-leaf tree costs {ratio:.2f}x the 31-leaf tree at fixed N "
-        f"(ratchet {LEAVES_RATIO_RATCHET}) — a per-split FIXED cost has "
-        f"been reintroduced (round-7/8 regression class: carried-state "
-        f"copy insertion, de-hoisted split-find, per-split host work); "
-        f"t31={t31 * 1e3:.0f} ms t255={t255 * 1e3:.0f} ms")
