@@ -18,6 +18,7 @@ from .config import Config, canonicalize_params, config_from_params
 from .data.dataset import TrainingData, construct
 from .data.parser import load_text_file, read_header_names
 from .objectives import create_objective
+from .obs import trace as obs_trace
 from .utils import log
 
 
@@ -159,6 +160,12 @@ class Dataset:
                                         # sharded by [sel] like weight
             else:
                 return self
+        # the one place binning happens: load, bin and pack, as one span
+        with obs_trace.phase("dataset.construct"):
+            return self._build(cfg, config, dist_rows)
+
+    def _build(self, cfg: Config, config: Optional[Config],
+               dist_rows: bool) -> "Dataset":
         if dist_rows:
             # bring the distributed runtime up BEFORE any jax backend
             # touch, so an early construct() (num_data, save_binary, ...)
@@ -794,8 +801,7 @@ class Booster:
             self.add_valid(data, name)   # not attached: score from scratch
             vs = self.inner.valid_sets[-1]
         res = [(name, m, v, h) for (_, m, v, h)
-               in self.inner._eval(vs.name, vs.metrics,
-                                   np.asarray(vs.scores, np.float64))]
+               in self.inner._eval(vs.name, vs.metrics, vs.scores)]
         return self._add_feval(res, name, feval, vs.scores, data)
 
     def reset_parameter(self, params: Dict[str, Any]) -> "Booster":

@@ -54,6 +54,21 @@ class NonFiniteError(RuntimeError):
     ``nonfinite_policy`` could not (or was asked not to) recover."""
 
 
+@jax.jit
+def _route_update_score(scores_k, bins, split_feature, threshold_bin,
+                        default_left, left_child, right_child, feat_info,
+                        is_cat, cat_bins, leaf_values, lr):
+    """Route rows through a fresh device-side tree and add its (shrunk) leaf
+    values to their scores, as one program under the ``score_update`` scope
+    (entered inside the traced function, so it is in the HLO whatever the
+    cache holds)."""
+    with jax.named_scope("score_update"):
+        row_leaf = predict_binned_leaf(
+            bins, split_feature, threshold_bin, default_left, left_child,
+            right_child, feat_info, is_cat, cat_bins)
+        return scores_k + lr * leaf_values[row_leaf]
+
+
 class _ValidSet:
     def __init__(self, data: TrainingData, name: str, num_class: int,
                  metrics: List[Metric]):
@@ -117,6 +132,7 @@ class GBDT:
         self.timers = PhaseTimers()   # TIMETAG analogue (gbdt.cpp:22-64)
         self.iter_ = 0
         self._last_iter_leaves = 0
+        self._hbm_gauged = False   # allocator peaks recorded after tree 1
         self.num_init_iteration = 0
         self.boost_from_average_ = False
         self.best_iteration = -1
@@ -162,16 +178,19 @@ class GBDT:
             keep = 0            # everything still pending must be reverted
         while self._pending and len(self._pending) > keep:
             rec = self._pending.pop(0)
+            it = int(rec["iter"])
             # the non-finite flags ride the SAME batched device_get the
             # drain already does — no extra host<->device synchronization
-            host, nf_ok, gh_ok = jax.device_get(
-                (rec["arrays"], rec["nf_ok"], rec["gh_ok"]))
+            with self.timers.phase("tree.wait", iteration=it):
+                host, nf_ok, gh_ok = jax.device_get(
+                    (rec["arrays"], rec["nf_ok"], rec["gh_ok"]))
             if not bool(nf_ok):
-                self._nonfinite_at_drain(int(rec["iter"]), bool(gh_ok))
-            tree = Tree.from_arrays(host, self.train_set.used_features,
-                                    self.train_set.bin_mappers,
-                                    self._num_bin_host)
-            tree.shrink(rec["lr"])
+                self._nonfinite_at_drain(it, bool(gh_ok))
+            with self.timers.phase("tree.host", iteration=it):
+                tree = Tree.from_arrays(host, self.train_set.used_features,
+                                        self.train_set.bin_mappers,
+                                        self._num_bin_host)
+                tree.shrink(rec["lr"])
             if self._stopped_no_split:
                 # trained past a (lately discovered) no-split iteration:
                 # discard, undoing any score contribution it made
@@ -182,7 +201,7 @@ class GBDT:
             # materialized host arrays — data this drain fetched anyway,
             # so the armed plane adds zero device syncs (pinned)
             obs_model_quality.get_tracker().observe_tree(
-                int(rec["iter"]), len(self._models) - 1, tree)
+                it, len(self._models) - 1, tree)
             if tree.num_leaves > 1:
                 self._iter_had_split = True
             if rec["k"] == self.num_class - 1:
@@ -214,6 +233,10 @@ class GBDT:
     # ------------------------------------------------------------------ setup
 
     def _setup_device(self, train: TrainingData) -> None:
+        with obs_trace.phase("setup.device"):
+            self._setup_device_inner(train)
+
+    def _setup_device_inner(self, train: TrainingData) -> None:
         cfg = self.config
         # host-side for now; _setup_grower owns device placement (multi-
         # process mode shards this globally instead of uploading it whole)
@@ -279,7 +302,8 @@ class GBDT:
             # run interpreted; on a TPU backend it compiles or raises
             hist_interpret=not on_tpu(),
             split_find=cfg.split_find)
-        self._setup_grower(cfg, train)
+        with obs_trace.phase("setup.grower"):
+            self._setup_grower(cfg, train)
         # rollback must act BEFORE the next iteration trains on poisoned
         # scores, so it forces synchronous tree materialization; the cheap
         # default (raise) keeps the pipeline and detects at drain time
@@ -293,7 +317,17 @@ class GBDT:
 
         self.objective.init(train.metadata, n)
         self.num_class = self.objective.num_tree_per_iteration
-        self._grad_fn = jax.jit(self.objective.get_gradients)
+        objective = self.objective
+
+        # scopes are entered INSIDE the traced functions: one around the
+        # call of a jitted function is baked into the HLO or not by cache
+        # luck.  ``objective`` and ``score_update`` name the loop's own
+        # device programs in a capture, beside the grower's scopes.
+        def get_gradients(scores):
+            with jax.named_scope("objective"):
+                return objective.get_gradients(scores)
+
+        self._grad_fn = jax.jit(get_gradients)
         self.scores = jnp.zeros((self.num_class, n), jnp.float32)
         self._has_init_score = train.metadata.init_score is not None
         if self._has_init_score:
@@ -312,7 +346,8 @@ class GBDT:
 
         @jax.jit
         def _update_score(scores_k, leaf_values, row_leaf, lr):
-            return scores_k + lr * leaf_values[row_leaf]
+            with jax.named_scope("score_update"):
+                return scores_k + lr * leaf_values[row_leaf]
 
         self._update_score = _update_score
 
@@ -329,18 +364,14 @@ class GBDT:
         self._memory_preflight(cfg, train)
 
     def _metrics_samples(self) -> list:
-        """Live ``/metrics`` samples of this booster: per-phase totals and
+        """Live ``/metrics`` samples of this booster: per-phase
         steady-state means (first, compile-inclusive firing excluded — the
         obs/report.py compile⚠ rule applied to the live view) plus the
         iteration gauge.  Pure host-side dict reads; snapshot via ``list``
         so a concurrent scrape never races the training thread's inserts."""
         out = [("train_iterations", {}, float(self.iter_), "gauge")]
-        counts = dict(self.timers.counts)
-        for name, total in list(self.timers.seconds.items()):
-            labels = {"phase": name}
-            out.append(("phase_seconds", labels, float(total), "counter"))
-            out.append(("phase_iterations", labels,
-                        float(counts.get(name, 0)), "counter"))
+        # phase_seconds / phase_calls come from the process-wide registry
+        # (obs/trace.phase counts them there, once)
         for name, mean in self.timers.steady_means().items():
             out.append(("phase_steady_ms", {"phase": name},
                         float(mean) * 1e3, "gauge"))
@@ -1200,9 +1231,15 @@ class GBDT:
         fl = obs_flight.get_flight()
         dp = obs_devprof.get_devprof()
         t0 = time.perf_counter() if fl.enabled else 0.0
-        with obs_trace.get_tracer().span("iteration", index=int(self.iter_)), \
+        with obs_trace.phase("iteration", index=int(self.iter_)), \
                 dp.iteration(int(self.iter_)):
             stop = self._train_one_iter_inner(grad, hess)
+        if not self._hbm_gauged:
+            # once, after the first tree: the grower's temporaries are now
+            # reserved, which predict_hbm (gauge hbm_predicted_peak_bytes)
+            # does not count — the two gauges beside it record the gap
+            self._hbm_gauged = True
+            obs_memory.gauge_hbm_peaks()
         # per-iteration device-memory gauge (no-op singleton when memory
         # observability is off; armed it is a host-side read — it rides
         # the fetches the loop already does, adding no syncs of its own)
@@ -1255,6 +1292,7 @@ class GBDT:
         # path only — pipelined trees drain later); the flight recorder's
         # ms/leaf field rides it
         self._last_iter_leaves = 0
+        it = int(self.iter_)     # ties this iteration's spans together
         if (self.iter_ == 0 and self.num_init_iteration == 0
                 and self.allow_boost_from_average
                 and self.objective is not None
@@ -1289,7 +1327,7 @@ class GBDT:
             if self._stopped_no_split:
                 self._stopped_no_split = False
                 return True
-        with self.timers.phase("boosting"):
+        with self.timers.phase("boosting", iteration=it):
             if grad is None or hess is None:
                 g, h = self._grad_fn(self.scores)
             else:
@@ -1310,7 +1348,7 @@ class GBDT:
                 h = jnp.where(jnp.isfinite(h), h, 1.0)
             if not pipeline:
                 jax.block_until_ready((g, h))
-        with self.timers.phase("bagging"):
+        with self.timers.phase("bagging", iteration=it):
             g, h, cnt = self._sample(self.iter_, g, h)
             if not pipeline:
                 jax.block_until_ready((g, h, cnt))
@@ -1326,7 +1364,7 @@ class GBDT:
                     [feat_mask, np.zeros(self._feat_pad, dtype=bool)])
             if not self._multiproc:   # multiproc: host arrays auto-replicate
                 feat_mask = jnp.asarray(feat_mask)
-            with self.timers.phase("tree"):
+            with self.timers.phase("tree", iteration=it):
                 if self._subset_state is not None:
                     # compact bagged matrix: tree cost is O(bagged rows)
                     sbins, sidx, sw, scnt, shist = self._subset_state
@@ -1352,64 +1390,65 @@ class GBDT:
                         lambda a: getattr(a, "copy_to_host_async",
                                           lambda: None)(), arrays)
                 else:
-                    if self._multiproc:
-                        # tree arrays are replicated — pull to host once so
-                        # the local scoring/predict paths see process-local
-                        # data
-                        arrays = jax.tree.map(np.asarray, arrays)
-                        num_leaves = int(arrays.num_leaves)
-                        nf_ok_h = bool(np.asarray(nf_ok))
-                        gh_ok_h = bool(np.asarray(gh_ok))
-                    else:
-                        # ONE fetch for the split count AND the guard flags
-                        # (the sync the loop was already paying)
-                        num_leaves, nf_ok_h, gh_ok_h = jax.device_get(
-                            (arrays.num_leaves, nf_ok, gh_ok))
-                        num_leaves = int(num_leaves)
+                    # the wait for the grower apart from the host's tree
+                    # building: the phase's self time is then the dispatch
+                    with self.timers.phase("tree.wait", iteration=it):
+                        if self._multiproc:
+                            # tree arrays are replicated — pull to host once
+                            # so the local scoring/predict paths see
+                            # process-local data
+                            arrays = jax.tree.map(np.asarray, arrays)
+                            num_leaves = int(arrays.num_leaves)
+                            nf_ok_h = bool(np.asarray(nf_ok))
+                            gh_ok_h = bool(np.asarray(gh_ok))
+                        else:
+                            # ONE fetch for the split count AND the guard
+                            # flags (the sync the loop was already paying)
+                            num_leaves, nf_ok_h, gh_ok_h = jax.device_get(
+                                (arrays.num_leaves, nf_ok, gh_ok))
+                            num_leaves = int(num_leaves)
                     if not bool(nf_ok_h) \
                             and self._handle_nonfinite(k, bool(gh_ok_h)):
                         return False    # iteration rolled back; retry next
                     self._last_iter_leaves += max(0, num_leaves - 1)
-                    tree = Tree.from_arrays(
-                        arrays, self.train_set.used_features,
-                        self.train_set.bin_mappers, self._num_bin_host)
-                    tree.shrink(lr)
-                    self._models.append(tree)
-                    # split audit over the arrays this sync path already
-                    # fetched — zero added device traffic (pinned)
-                    obs_model_quality.get_tracker().observe_tree(
-                        int(self.iter_), len(self._models) - 1, tree)
+                    with self.timers.phase("tree.host", iteration=it):
+                        tree = Tree.from_arrays(
+                            arrays, self.train_set.used_features,
+                            self.train_set.bin_mappers, self._num_bin_host)
+                        tree.shrink(lr)
+                        self._models.append(tree)
+                        # split audit over the arrays this sync path
+                        # already fetched — zero added device traffic
+                        # (pinned)
+                        obs_model_quality.get_tracker().observe_tree(
+                            it, len(self._models) - 1, tree)
             # pipelined: the split/no-split outcome is unknown on host, but
             # a no-split tree's leaf_value is all zeros so the score update
             # is a provable no-op — dispatch it unconditionally
             if pipeline or num_leaves > 1:
                 any_split = True
-                with self.timers.phase("score"):
+                with self.timers.phase("score", iteration=it):
+                    lr_dev = jnp.asarray(lr, jnp.float32)
                     if self._subset_state is not None:
                         # out-of-bag rows need scores too (UpdateScoreOutOfBag,
                         # gbdt.cpp:452-463): route ALL rows through the fresh
                         # device-side tree — no host round-trip
-                        row_leaf = predict_binned_leaf(
-                            self.bins, arrays.split_feature,
-                            arrays.threshold_bin, arrays.default_left,
-                            arrays.left_child, arrays.right_child,
-                            self.feat_info, arrays.is_cat, arrays.cat_bins)
-                    self.scores = self.scores.at[k].set(self._update_score(
-                        self.scores[k], arrays.leaf_value, row_leaf,
-                        jnp.asarray(lr, jnp.float32)))
+                        self.scores = self.scores.at[k].set(
+                            self._routed_score(self.scores[k], self.bins,
+                                               arrays, lr_dev))
+                    else:
+                        self.scores = self.scores.at[k].set(
+                            self._update_score(self.scores[k],
+                                               arrays.leaf_value, row_leaf,
+                                               lr_dev))
                     # valid sets are scored from the DEVICE-side TreeArrays —
                     # no host tree conversion or per-tree jit re-entry in the
                     # hot loop (weak-spot fix: tree_scores_binned stays for
                     # replay/rollback/DART paths only)
                     for vs in self.valid_sets:
-                        vleaf = predict_binned_leaf(
-                            vs.bins, arrays.split_feature,
-                            arrays.threshold_bin, arrays.default_left,
-                            arrays.left_child, arrays.right_child,
-                            self.feat_info, arrays.is_cat, arrays.cat_bins)
-                        vs.scores = vs.scores.at[k].set(self._update_score(
-                            vs.scores[k], arrays.leaf_value, vleaf,
-                            jnp.asarray(lr, jnp.float32)))
+                        vs.scores = vs.scores.at[k].set(
+                            self._routed_score(vs.scores[k], vs.bins, arrays,
+                                               lr_dev))
                     if not pipeline:
                         jax.block_until_ready(self.scores)
             if pipeline:
@@ -1419,7 +1458,7 @@ class GBDT:
         self._after_iter()
         self.iter_ += 1
         if pipeline:
-            with self.timers.phase("tree"):
+            with self.timers.phase("tree", iteration=it):
                 self._drain_pending(keep_iters=self._pipeline_depth)
             if self._stopped_no_split:
                 # one-shot, like the sync path: a later call retries (a
@@ -1436,6 +1475,16 @@ class GBDT:
             self.iter_ -= 1
             return True
         return False
+
+    def _routed_score(self, scores_k, bins, arrays, lr):
+        """Scores of rows the grower did not place (out-of-bag, held-out):
+        routed through the fresh device-side tree and updated in one
+        program, under the ``score_update`` scope."""
+        return _route_update_score(
+            scores_k, bins, arrays.split_feature, arrays.threshold_bin,
+            arrays.default_left, arrays.left_child, arrays.right_child,
+            self.feat_info, arrays.is_cat, arrays.cat_bins,
+            arrays.leaf_value, lr)
 
     def _sample(self, it, g, h):
         """Row sampling hook: bagging for GBDT, overridden by GOSS/RF."""
@@ -1730,25 +1779,33 @@ class GBDT:
     # ------------------------------------------------------------------- eval
 
     def eval_train(self) -> List[Tuple[str, str, float, bool]]:
-        return self._eval("training", self.train_metrics,
-                          np.asarray(self.scores, np.float64))
+        return self._eval("training", self.train_metrics, self.scores)
 
     def eval_valid(self) -> List[Tuple[str, str, float, bool]]:
         out = []
         for vs in self.valid_sets:
-            out.extend(self._eval(vs.name, vs.metrics,
-                                  np.asarray(vs.scores, np.float64)))
+            out.extend(self._eval(vs.name, vs.metrics, vs.scores))
         return out
 
     def _eval(self, name, metrics, scores) -> List[Tuple[str, str, float, bool]]:
-        with self.timers.phase("metric"):
-            return self._eval_inner(name, metrics, scores)
+        """``scores`` is the device array: its fetch is the evaluation's
+        first cost (84 MB at 10.5M rows), so it lies inside the phase."""
+        it = max(int(self.iter_) - 1, 0)    # the tree just built
+        with self.timers.phase("metric", iteration=it, data=name):
+            with self.timers.phase("metric.fetch", iteration=it):
+                host = self._eval_scores(scores)
+            return self._eval_inner(name, metrics, host, it)
 
-    def _eval_inner(self, name, metrics, scores) -> List[Tuple[str, str, float, bool]]:
+    def _eval_scores(self, scores) -> np.ndarray:
+        return np.asarray(scores, np.float64)
+
+    def _eval_inner(self, name, metrics, scores,
+                    it) -> List[Tuple[str, str, float, bool]]:
         results = []
         mq = obs_model_quality.get_tracker()
         for m in metrics:
-            vals = m.eval(scores, self.objective)
+            with self.timers.phase("metric." + m.name, iteration=it):
+                vals = m.eval(scores, self.objective)
             for mn, v in zip(m.names(), vals):
                 results.append((name, mn, float(v), m.is_higher_better))
                 # stash for the NEXT progress record (the engine loop
@@ -2243,9 +2300,8 @@ class RF(GBDT):
     def _shrinkage_rate(self) -> float:
         return 1.0
 
-    def _eval(self, name, metrics, scores):
-        it = max(self.iter_, 1)
-        return super()._eval(name, metrics, scores / it)
+    def _eval_scores(self, scores):
+        return super()._eval_scores(scores) / max(self.iter_, 1)
 
 
 def create_boosting(config: Config, train_set: Optional[TrainingData] = None,

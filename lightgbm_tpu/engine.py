@@ -55,22 +55,24 @@ def train(params: Dict[str, Any], train_set: Dataset,
     # structured telemetry (lightgbm_tpu.obs): trace_path writes a
     # Chrome-trace span file; telemetry=true enables counters/spans without
     # a file.  The counter registry is reset per training so two runs in
-    # one process never blur their kernel-identity evidence.
+    # one process never blur their kernel-identity evidence; the phase and
+    # compile counters describe the process (a Dataset binned before this
+    # call, a program an earlier booster compiled) and are kept.
     from .obs import devprof as obs_devprof
     from .obs import memory as obs_memory
     from .obs import trace as obs_trace
-    from .obs.counters import counters as obs_counters
+    from .obs.counters import PROCESS_COUNTERS, counters as obs_counters
     trace_path = str(params.get("trace_path", "") or "")
-    # device-time attribution (obs/devprof.py): implies telemetry — the
-    # attributor needs the TraceAnnotation phase windows the tracer mirrors
-    # into every profiler capture
+    # device-time attribution (obs/devprof.py): implies telemetry — its
+    # device_profile block rides the trace file (the lgb: phase windows it
+    # attributes by are in every profiler capture, switch or no switch)
     devprof_on = str(params.get("device_profile", "")).strip().lower() \
         in ("true", "1", "yes", "on", "+")
     telemetry_on = bool(trace_path) or devprof_on or str(
         params.get("telemetry", "")).strip().lower() in ("true", "1", "yes",
                                                          "on", "+")
     if telemetry_on:
-        obs_counters.reset()
+        obs_counters.reset(keep=PROCESS_COUNTERS)
         obs_trace.start(trace_path or None)
         # device-memory accounting rides the same switch: per-iteration /
         # per-phase samples are host-side reads (memory_stats on TPU, a
@@ -409,8 +411,7 @@ def train(params: Dict[str, Any], train_set: Dataset,
         if heartbeat is not None:
             heartbeat.stamp(iteration)
 
-    train_span = obs_trace.get_tracer().span(
-        "train", num_boost_round=num_boost_round)
+    train_span = obs_trace.phase("train", num_boost_round=num_boost_round)
     try:
         with profile_ctx, train_span:
             for i in range(start_iter, num_boost_round):

@@ -43,7 +43,6 @@ from jax import lax
 from .data.packing import (PACK_JOINT_BINS, pack_fused_panel,
                            pack_gather_words, unfold_packed_hist,
                            unpack_gather_words)
-from .obs import trace as obs_trace
 from .obs.counters import counters as obs_counters
 from .ops.histogram import (on_tpu, subset_histogram, subset_histogram_flat,
                             subset_histogram_fused)
@@ -663,19 +662,16 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                        for w in (gw_pad, hw_pad, cw_pad)], axis=1)
                 n_words = hwords_pad.shape[1]
 
-        # telemetry: host spans below fire at TRACE time (once per
-        # compilation); the jax.named_scope twins are baked into the HLO so
-        # XProf attributes the per-split kernels to the same names on-chip
-        tracer = obs_trace.get_tracer()
+        # the jax.named_scope names below are baked into the HLO: a device
+        # trace attributes the per-split kernels to them (a host span here
+        # would fire once, while jit traces)
 
         def find(hist, pg, ph, pc, feat_ok):
             # trace-time identity evidence (the hist_dispatch discipline):
             # bench rungs / decide_flips verify the split_find label
             # against this counter
             obs_counters.inc("split_find_dispatch", impl=cfg.split_find)
-            with tracer.span("split_find", traced=True,
-                             impl=cfg.split_find), \
-                    jax.named_scope("split_find"):
+            with jax.named_scope("split_find"):
                 return strategy.find(ctx, hist, pg, ph, pc, feat_ok)
 
         def hist_subset(rows, g_, h_, c_, site="split"):
@@ -934,8 +930,7 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
             ow0 = jnp.zeros((0, 0), dtype)
         num_logical = meta.num_bin.shape[0]
         feat_ok_all = jnp.ones((num_logical,), bool)
-        with tracer.span("histogram", site="root", traced=True), \
-                jax.named_scope("histogram"):
+        with jax.named_scope("histogram"):
             if use_fused:
                 # the fused rung is SELF-CONTAINED: the root histogram goes
                 # through the fused kernel too (static grid over the
@@ -1012,8 +1007,7 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
             kp = _bucket_index(cnt, bsizes)
             cat_args = ((state.scat[l], state.scatb[l])
                         if cfg.has_categorical else ())
-            with tracer.span("partition", traced=True), \
-                    jax.named_scope("partition"):
+            with jax.named_scope("partition"):
                 order, obins, ow, nl = lax.switch(
                     kp, pbranches,
                     (state.order, state.obins, state.ow, start, cnt,
@@ -1070,8 +1064,7 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
             small_left = frow[2] <= frow[5]
             sstart = jnp.where(small_left, start, start + nl)
             scnt = jnp.where(small_left, nl, nr)   # LOCAL count of that child
-            with tracer.span("histogram", site="split", traced=True), \
-                    jax.named_scope("histogram"):
+            with jax.named_scope("histogram"):
                 if use_fused:
                     # the kernel gathers the window rows itself from the
                     # fused panel — no bucket switch, no staging buffer

@@ -315,8 +315,7 @@ def _eval_results(bst, data_idx):
         raise IndexError(f"data_idx {data_idx} out of range "
                          f"({len(sets)} valid sets)")
     vs = sets[data_idx - 1]
-    return bst.inner._eval(vs.name, vs.metrics,
-                           np.asarray(vs.scores, np.float64))
+    return bst.inner._eval(vs.name, vs.metrics, vs.scores)
 
 
 def booster_eval_counts(bst) -> int:

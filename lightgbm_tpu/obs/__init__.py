@@ -3,10 +3,13 @@ device memory.
 
 Four pillars (see docs/OBSERVABILITY.md):
 
-* :mod:`.trace` — nested-span tracer; no-op when disabled, Chrome-trace
-  JSON/JSONL + ``jax.profiler.TraceAnnotation`` mirroring when enabled;
+* :mod:`.trace` — ``phase()``, the always-on span (an ``lgb:<name>``
+  ``jax.profiler.TraceAnnotation`` in every profiler capture plus the
+  ``phase_seconds`` counter), and the nested-span tracer that records the
+  same spans as Chrome-trace JSON/JSONL when enabled;
 * :mod:`.counters` — process-wide counters/events (histogram-kernel
-  dispatch identity, layout downgrades, collective bytes);
+  dispatch identity, layout downgrades, collective bytes, phase seconds,
+  compile seconds per function and stage);
 * :mod:`.memory` — device-memory observability: live HBM accounting
   (``memory_stats`` / tagged live-array census), compiled-executable
   ``memory_analysis`` capture, the ``predict_hbm`` fit-predictor and the

@@ -27,7 +27,17 @@ users:
   age vs the effective hang timeout), ``group_restart`` (attempt, resume
   iteration, backoff), ``restart_budget_exhausted``, ``crash_report``
   (a rank left one behind), and ``stale_sweep`` (startup hygiene
-  removals) — an unattended recovery is never an unexplained one.
+  removals) — an unattended recovery is never an unexplained one;
+* **phase and compile accounting** — ``obs/trace.phase`` adds
+  ``phase_seconds`` / ``phase_calls`` tagged ``phase=`` for every boundary
+  of the boosting loop and of set-up, and
+  :func:`install_compile_listener` adds ``compile_seconds`` tagged
+  ``fun=``, ``stage=trace|lower|backend``, ``compile_calls`` tagged
+  ``fun=`` and ``compile_cache_hits`` from jax's own monitoring events:
+  "which step recompiled, and what it cost".  These are the
+  :data:`PROCESS_COUNTERS`: they describe the process (a data set binned
+  before ``train()``, a program compiled by an earlier booster), so the
+  per-training reset keeps them.
 
 Counts recorded from inside jit tracing are TRACE-time counts (once per
 compiled call site), which is exactly the "per call site" identity the
@@ -36,8 +46,14 @@ honesty checks need — a recompile shows up as a fresh increment.
 from __future__ import annotations
 
 import collections
+import re
 import threading
-from typing import Any, Dict, List, Optional
+import time
+from typing import Any, Dict, Iterable, List, Optional
+
+# families that outlive a training (see the module docstring)
+PROCESS_COUNTERS = ("phase_seconds", "phase_calls", "compile_seconds",
+                    "compile_calls", "compile_cache_hits")
 
 
 def _tag_key(tags: Dict[str, Any]) -> str:
@@ -106,9 +122,13 @@ class CounterRegistry:
             if fn in self._sinks:
                 self._sinks.remove(fn)
 
-    def reset(self) -> None:
+    def reset(self, keep: Iterable[str] = ()) -> None:
+        """Drop everything but the counter families named in ``keep``."""
         with self._lock:
+            kept = {n: self._counters[n] for n in keep
+                    if n in self._counters}
             self._counters.clear()
+            self._counters.update(kept)
             self._gauges.clear()
             self._events.clear()
             self._events_dropped = 0
@@ -169,3 +189,66 @@ class CounterRegistry:
 
 
 counters = CounterRegistry()
+
+
+# ------------------------------------------------------ compile accounting
+
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # on a persistent-cache hit this is the load time: what set-up pays
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_WRAPPED_NAME = re.compile(r"^\w+\((.*)\)$")
+_compile_listener_installed = False
+# per thread, the traces that ended and may lie inside one still running:
+# jit traces nest (a jnp function traced inside ``grow_tree`` reports its
+# own duration first), and each second is charged once, to the innermost
+_open_traces = threading.local()
+
+
+def _fun_tag(fun_name: Any) -> str:
+    """``jit(get_gradients)`` and ``get_gradients`` are one function."""
+    name = str(fun_name or "?")
+    m = _WRAPPED_NAME.match(name)
+    return re.sub(r"[,=]", "_", m.group(1) if m else name)
+
+
+def _on_compile_duration(event: str, duration_secs: float, **kw) -> None:
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    fun = _fun_tag(kw.get("fun_name"))
+    seconds = float(duration_secs)
+    if stage == "trace":
+        end = time.perf_counter()
+        start = end - seconds
+        done = getattr(_open_traces, "done", None)
+        if done is None:
+            done = _open_traces.done = []
+        while done and done[-1][0] >= start:
+            seconds -= done.pop()[1]
+        del done[:-64]          # top-level traces are never claimed
+        done.append((start, float(duration_secs)))
+        seconds = max(seconds, 0.0)
+    elif stage == "backend":
+        counters.inc("compile_calls", 1, fun=fun)
+    counters.inc("compile_seconds", seconds, fun=fun, stage=stage)
+
+
+def _on_compile_event(event: str, **kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        counters.inc("compile_cache_hits", 1)
+
+
+def install_compile_listener() -> None:
+    """Listen, once per process, to jax's compile events.  Nothing here
+    runs unless jax traces, lowers, compiles or loads a program."""
+    global _compile_listener_installed
+    if _compile_listener_installed:
+        return
+    from jax import monitoring
+    monitoring.register_event_duration_secs_listener(_on_compile_duration)
+    monitoring.register_event_listener(_on_compile_event)
+    _compile_listener_installed = True

@@ -8,9 +8,9 @@ capture windows over steady-state boosting iterations, parses the emitted
 trace-event artifacts on the host, and attributes device op time to the
 ``jax.named_scope`` phase twins the kernels already carry (``histogram``
 root/split, ``split_find``, ``partition``, ``fused_panel``, the serving
-``traverse``) — falling back to the host ``TraceAnnotation`` phase
-windows (``boosting``/``bagging``/``tree``/``score``/...) that
-``obs/trace.py`` mirrors into every capture.
+``traverse``, ``objective``, ``score_update``) — falling back to the host
+``TraceAnnotation`` phase windows (``lgb:boosting`` / ``lgb:tree`` /
+``lgb:score`` / ...) that ``obs/trace.phase`` puts into every capture.
 
 Capture discipline follows the PhaseTimers convention: the FIRST firing
 seen is the compile and is never captured; the next ``profile_iters``
@@ -44,20 +44,23 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from ..utils import log
 from .counters import counters
+from .trace import ANNOTATION_PREFIX
 
 SCHEMA_VERSION = 1
 
 # device-side named_scope twins baked into the lowered HLO; XProf-style
 # artifacts carry them in op names / tf_op metadata ("scope attribution")
 SCOPE_PHASES = ("histogram", "split_find", "partition", "fused_panel",
-                "traverse")
-# host-side TraceAnnotation windows obs/trace.py mirrors into captures
+                "traverse", "objective", "score_update")
+# host-side TraceAnnotation windows obs/trace.phase puts into captures
 # ("window attribution" — the CPU/sync fallback when scope names are
-# fused away or the backend does not label ops)
-HOST_PHASES = ("histogram", "split_find", "partition", "fused_panel",
-               "boosting", "bagging", "tree", "score", "metric",
-               "predict_bin", "predict_traverse", "predict_margin",
-               "serving_batch")
+# fused away or the backend does not label ops).  Leaf phases only: a
+# window that holds others (lgb:iteration, lgb:tree over tree.wait) would
+# still resolve to its innermost child, so listing it adds nothing.
+HOST_PHASES = tuple(ANNOTATION_PREFIX + p for p in (
+    "boosting", "bagging", "tree", "tree.wait", "tree.host", "score",
+    "metric", "metric.fetch", "predict_bin", "predict_traverse",
+    "predict_margin", "serving_batch"))
 
 _SCOPE_RE = re.compile(
     r"(?:^|[/ .])(" + "|".join(SCOPE_PHASES) + r")(?:[/ .\d]|$)")
@@ -138,18 +141,27 @@ def op_events(events: List[dict]) -> List[dict]:
     return out
 
 
+def host_phase(ev: dict) -> Optional[str]:
+    """The phase (prefix taken off) of a ``lgb:`` TraceAnnotation window,
+    else None.  The profiler's Chrome-trace export shortens the name of an
+    annotation that carries arguments to what follows the colon and keeps
+    the whole in ``long_name``."""
+    if ev.get("ph") != "X":
+        return None
+    name = str((ev.get("args") or {}).get("long_name") or ev.get("name", ""))
+    return name[len(ANNOTATION_PREFIX):] if name in HOST_PHASES else None
+
+
 def phase_windows(events: List[dict]) -> List[Tuple[float, float, str]]:
-    """Host phase windows ``(ts, end, phase)`` from the TraceAnnotation
-    mirror of obs tracer spans, sorted by start time."""
+    """Host phase windows ``(ts, end, phase)`` from the ``lgb:``
+    TraceAnnotations of ``obs/trace.phase``, sorted by start time."""
     wins = []
     for ev in events:
-        if ev.get("ph") != "X":
-            continue
-        name = str(ev.get("name", ""))
-        if name in HOST_PHASES:
+        phase = host_phase(ev)
+        if phase is not None:
             ts = float(ev.get("ts", 0.0))
             dur = float(ev.get("dur", 0.0))
-            wins.append((ts, ts + dur, name))
+            wins.append((ts, ts + dur, phase))
     wins.sort()
     return wins
 
@@ -392,8 +404,7 @@ class DeviceProfiler:
         self._last_gap = gap
         self._ops.extend(ops)
         self._host_events.extend(
-            ev for ev in events
-            if ev.get("ph") == "X" and str(ev.get("name")) in HOST_PHASES)
+            ev for ev in events if host_phase(ev) is not None)
         self.iterations.append({
             "iteration": int(index),
             "host_ms": round(host_s * 1e3, 4),

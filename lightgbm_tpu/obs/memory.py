@@ -72,10 +72,24 @@ def device_memory_stats(device=None) -> Optional[Dict[str, int]]:
         return None
     out = {}
     for key in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit",
-                "largest_free_block_bytes", "num_allocs"):
+                "peak_bytes_reserved", "largest_free_block_bytes",
+                "num_allocs"):
         if key in stats:
             out[key] = int(stats[key])
     return out or None
+
+
+def gauge_hbm_peaks(device=None) -> None:
+    """Gauges ``hbm_in_use_peak_bytes`` / ``hbm_reserved_peak_bytes``: what
+    the allocator held and what the runtime reserved for compiled
+    programs' temporaries, to be read beside ``hbm_predicted_peak_bytes``
+    (the pre-flight's ``predict_hbm``).  Nothing off the chip."""
+    stats = device_memory_stats(device)
+    if stats:
+        counters.gauge("hbm_in_use_peak_bytes",
+                       stats.get("peak_bytes_in_use", 0))
+        counters.gauge("hbm_reserved_peak_bytes",
+                       stats.get("peak_bytes_reserved", 0))
 
 
 # Owner-tag providers: each is a (weakly referenced) zero-arg callable

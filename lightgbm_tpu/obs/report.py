@@ -81,28 +81,22 @@ def summary_payload(events: List[dict], kind: str) -> Optional[dict]:
 
 
 def phase_table(events: List[dict],
-                traced: Optional[bool] = None) -> List[Dict[str, Any]]:
+                steady: bool = False) -> List[Dict[str, Any]]:
     """Aggregate complete ("X") spans by name: count/total/mean/max (ms).
 
-    ``traced`` filters on the span's ``traced`` arg: True keeps only
-    TRACE-TIME spans (emitted from inside jit — they fire once per
-    compilation and their durations include tracing/compile work), False
-    keeps only host wall-clock spans, None keeps everything (the --json
-    CLI view).  Host rows additionally carry ``first_ms`` (the
+    With ``steady`` the rows additionally carry ``first_ms`` (the
     chronologically first firing) and ``steady_mean_ms`` (mean of the
     rest): a first firing that dwarfs the steady state is the compile —
     totals that mix the two mislead (observed: a ``score`` phase showing
     11.2 s total of which 10.8 s was the first, compile-inclusive
-    firing)."""
+    firing).  What each compile cost is in the ``compile_seconds``
+    counters."""
     agg: Dict[str, List[tuple]] = {}
     peak: Dict[str, int] = {}
     for ev in events:
         if ev.get("ph") != "X":
             continue
         args = ev.get("args", {})
-        is_traced = bool(args.get("traced"))
-        if traced is not None and is_traced != traced:
-            continue
         agg.setdefault(ev["name"], []).append(
             (float(ev.get("ts", 0)), float(ev.get("dur", 0)) / 1e3))
         if "peak_bytes" in args:    # memory monitor phase annotation
@@ -118,7 +112,7 @@ def phase_table(events: List[dict],
                "max_ms": max(durs)}
         if name in peak:
             row["peak_bytes"] = peak[name]
-        if traced is False:
+        if steady:
             rest = durs[1:]
             row["first_ms"] = durs[0]
             row["steady_mean_ms"] = (sum(rest) / len(rest)) if rest \
@@ -144,6 +138,25 @@ def kernel_table(counters: Dict[str, Dict[str, float]]) -> List[Dict[str, Any]]:
                          "site": tags.get("site", "-"),
                          "traced_calls": int(v)})
     return rows
+
+
+def compile_table(counters: Dict[str, Dict[str, float]]
+                  ) -> List[Dict[str, Any]]:
+    """Per function: compile calls and trace / lower / backend seconds
+    (the ``compile_seconds`` / ``compile_calls`` counters)."""
+    rows: Dict[str, Dict[str, Any]] = {}
+    for key, v in counters.get("compile_seconds", {}).items():
+        tags = _split_tags(key)
+        row = rows.setdefault(tags.get("fun", "?"), {
+            "fun": tags.get("fun", "?"), "calls": 0, "trace": 0.0,
+            "lower": 0.0, "backend": 0.0})
+        row[tags.get("stage", "backend")] += float(v)
+    for key, v in counters.get("compile_calls", {}).items():
+        fun = _split_tags(key).get("fun", "?")
+        if fun in rows:
+            rows[fun]["calls"] = int(v)
+    return sorted(rows.values(),
+                  key=lambda r: -(r["trace"] + r["lower"] + r["backend"]))
 
 
 def observed_kernel(counters: Dict[str, Dict[str, float]]) -> Optional[str]:
@@ -389,7 +402,7 @@ def render(path) -> str:
               "whose FIRST firing dwarfs its steady state (marked "
               "`compile⚠`) included jit compilation — judge throughput "
               "by `steady mean`, not `total`.", ""]
-    prows = phase_table(events, traced=False)
+    prows = phase_table(events, steady=True)
     if prows:
         with_peak = any("peak_bytes" in r for r in prows)
         headers = ["span", "count", "total ms", "first ms",
@@ -405,17 +418,17 @@ def render(path) -> str:
              + ["compile⚠" if r["compile_skewed"] else ""] for r in prows])
     else:
         lines.append("(no spans recorded)")
-    trows = phase_table(events, traced=True)
-    if trows:
-        lines += ["", "## Trace-time spans (compile-inclusive)", "",
-                  "Spans emitted from INSIDE jitted code fire once per "
-                  "compilation — durations measure tracing/compile work, "
-                  "never steady-state execution (the on-device twin is "
-                  "the `jax.named_scope` XProf attribution).", ""]
+    crows = compile_table(counters)
+    if crows:
+        lines += ["", "## Compiles (jax.monitoring)", "",
+                  "Seconds jax spent tracing, lowering and compiling (or "
+                  "loading from the persistent cache: `backend`) each "
+                  "function in this process, most expensive first.", ""]
         lines += _md_table(
-            ["span", "count", "total ms", "mean ms", "max ms"],
-            [[r["span"], r["count"], f"{r['total_ms']:.3f}",
-              f"{r['mean_ms']:.3f}", f"{r['max_ms']:.3f}"] for r in trows])
+            ["function", "calls", "trace s", "lower s", "backend s"],
+            [[r["fun"], r["calls"], f"{r['trace']:.3f}",
+              f"{r['lower']:.3f}", f"{r['backend']:.3f}"]
+             for r in crows[:12]])
     lines += ["", "## Per-kernel dispatch identity", ""]
     krows = kernel_table(counters)
     if krows:

@@ -6,13 +6,19 @@ library itself.  One process-wide active tracer (module functions
 :func:`start` / :func:`stop` / :func:`get_tracer`):
 
 * **disabled** (the default) it is a :class:`NullTracer` whose ``span()``
-  returns ONE shared no-op context manager — the hot-loop cost of an
-  instrumented phase is a dict lookup and two no-op calls, no allocation
+  returns ONE shared no-op context manager
   (pinned by ``tests/test_obs.py::test_disabled_tracer_is_allocation_free``);
 * **enabled** it records wall-clock spans as Chrome trace events
-  (``ph: "X"``, microsecond ``ts``/``dur``) and mirrors every span into
-  ``jax.profiler.TraceAnnotation`` so host spans line up with XProf
-  captures taken via ``profile_dir`` on-chip.
+  (``ph: "X"``, microsecond ``ts``/``dur``).
+
+:func:`phase` is the primitive every boundary of the boosting loop and of
+set-up goes through, tracer on or off: ONE ``jax.profiler.TraceAnnotation``
+named ``lgb:<name>`` (so any profiler capture — ``profile_dir``, the
+benchmark's ``--trace 1`` — holds the program's spans on the device
+trace's clock with no telemetry switch), plus ``phase_seconds{phase=}`` /
+``phase_calls{phase=}`` in the process-wide counter registry, plus the
+Chrome event when the tracer records.  ``utils/timer.PhaseTimers`` keeps
+its per-booster totals from the same measurement.
 
 Output format follows the Chrome Trace Event spec: a ``*.jsonl`` path gets
 one event object per line (append-friendly, crash-tolerant — a killed
@@ -22,10 +28,10 @@ payloads (the :mod:`lightgbm_tpu.obs.counters` snapshot, phase-timer
 totals) are embedded as instant events named ``telemetry.summary`` so one
 file carries the whole story; ``obs/report.py`` renders it.
 
-Spans emitted from inside jitted code (the grower) fire at TRACE time —
-once per compilation, not per execution; their on-device counterpart is
-the ``jax.named_scope`` annotation baked into the lowered HLO, which XProf
-attributes per kernel launch.  ``obs/report.py`` labels them accordingly.
+Jitted code carries no host span (one would fire once, while jit traces):
+its device time is read from the ``jax.named_scope`` names baked into the
+lowered HLO, and its trace/compile cost from the ``compile_seconds``
+counters (``obs/counters.install_compile_listener``).
 """
 from __future__ import annotations
 
@@ -35,6 +41,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from .counters import counters    # (counters imports this module lazily)
 
 # resolved lazily; False once probing failed (jax absent / too old)
 _TraceAnnotation: Any = None
@@ -55,7 +63,14 @@ def process_index() -> int:
     return _PROC
 
 
-def _jax_annotation(name: str):
+# every host span of the program rides profiler captures under this prefix
+# (benchmarks/harness/program_spans.py keeps exactly these)
+ANNOTATION_PREFIX = "lgb:"
+
+
+def _jax_annotation(name: str, args: dict):
+    """``TraceAnnotation("lgb:<name>", **args)``: well under a microsecond
+    with no capture open; inside one it is the span on the trace's clock."""
     global _TraceAnnotation
     if _TraceAnnotation is None:
         try:
@@ -63,7 +78,9 @@ def _jax_annotation(name: str):
             _TraceAnnotation = ta
         except Exception:  # pragma: no cover - jax is a hard dep here
             _TraceAnnotation = False
-    return _TraceAnnotation(name) if _TraceAnnotation else None
+    if not _TraceAnnotation:
+        return None
+    return _TraceAnnotation(ANNOTATION_PREFIX + name, **args)
 
 
 class _NullSpan:
@@ -114,7 +131,7 @@ class _Span:
         self._jax = None
 
     def __enter__(self):
-        ann = _jax_annotation(self._name)
+        ann = _jax_annotation(self._name, self._args)
         if ann is not None:
             ann.__enter__()
             self._jax = ann
@@ -182,8 +199,7 @@ class Tracer:
         final summary event carrying the current counter-registry snapshot
         so the trace file is self-contained."""
         path = path or self.path
-        from .counters import counters  # lazy: avoid import cycles
-        from . import metrics as obs_metrics
+        from . import metrics as obs_metrics  # lazy: avoid import cycles
         # the live-scrape view rides along so obs_diff can compare two
         # traces at the metrics level without a /metrics endpoint
         self.summary("metrics", obs_metrics.snapshot())
@@ -203,6 +219,48 @@ class Tracer:
 
 
 _active: Any = NULL_TRACER
+
+
+class _Phase:
+    """One measured boundary (see :func:`phase`).  ``seconds`` holds the
+    duration once the block has exited; ``span`` is the recording tracer
+    span while it runs (``NULL_SPAN`` with the tracer off)."""
+    __slots__ = ("name", "_args", "_ann", "span", "_t0", "seconds")
+
+    def __init__(self, name: str, args: dict):
+        self.name = name
+        self._args = args
+        self._ann = None
+        self.span = NULL_SPAN
+        self._t0 = 0.0
+        self.seconds = 0.0
+
+    def __enter__(self):
+        tr = _active
+        if tr.enabled:
+            # the recording span opens the one annotation itself
+            self.span = self._ann = _Span(tr, self.name, self._args)
+        else:
+            self._ann = _jax_annotation(self.name, self._args)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.seconds = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        counters.inc("phase_seconds", self.seconds, phase=self.name)
+        counters.inc("phase_calls", 1, phase=self.name)
+        return False
+
+
+def phase(name: str, **args) -> _Phase:
+    """``with phase("tree", iteration=i):`` — the always-on span: one
+    ``lgb:<name>`` profiler annotation, the ``phase_seconds`` /
+    ``phase_calls`` counters, and the Chrome event when the tracer is on."""
+    return _Phase(name, args)
 
 
 def get_tracer():

@@ -76,7 +76,6 @@ from ..data.packing import (PACK_JOINT_BINS, pack_fused_panel,
 from ..grower import (FeatureMeta, GrowerConfig, _depth_gate,
                       expand_bundle_hist, make_expand_maps, pool_rows,
                       route_goes_left, unpack_tree)
-from ..obs import trace as obs_trace
 from ..obs.counters import counters as obs_counters
 from ..ops.histogram import subset_histogram_flat, subset_histogram_fused_local
 from ..ops.split import best_split, leaf_output, make_fused_ctx
@@ -132,13 +131,10 @@ def make_gspmd_grower(cfg: GrowerConfig, mesh: Mesh,
         num_logical = meta.num_bin.shape[0]
         fh = (pack_plan.num_phys_cols if pack_plan is not None
               else hist_src.shape[1])
-        tracer = obs_trace.get_tracer()
 
         def find(hist, pg, ph, pc, feat_ok):
             obs_counters.inc("split_find_dispatch", impl=cfg.split_find)
-            with tracer.span("split_find", traced=True,
-                             impl=cfg.split_find), \
-                    jax.named_scope("split_find"):
+            with jax.named_scope("split_find"):
                 if maps is not None:
                     hist = expand_bundle_hist(hist, pg, ph, pc, maps)
                 return best_split(hist, pg, ph, pc, meta.num_bin,
@@ -244,8 +240,7 @@ def make_gspmd_grower(cfg: GrowerConfig, mesh: Mesh,
         root_c = jnp.sum(cw)
         feat_ok_all = jnp.ones((num_logical,), bool)
         row_leaf0 = cstr(jnp.zeros((n,), jnp.int32), P(BATCH_AXIS))
-        with tracer.span("histogram", site="root", traced=True), \
-                jax.named_scope("histogram"):
+        with jax.named_scope("histogram"):
             hist_root = measure(row_leaf0, jnp.asarray(0, jnp.int32),
                                 gw, hw, cw, site="root")
         res_root, root_feat_ok = find(hist_root, root_g, root_h, root_c,
@@ -305,8 +300,7 @@ def make_gspmd_grower(cfg: GrowerConfig, mesh: Mesh,
             binf = lax.dynamic_index_in_dim(
                 bins, col_idx, axis=1, keepdims=False).astype(jnp.int32)
             cat_args = ((scat[l], scatb[l]) if cfg.has_categorical else ())
-            with tracer.span("partition", traced=True), \
-                    jax.named_scope("partition"):
+            with jax.named_scope("partition"):
                 goes_left = route_goes_left(
                     binf, meta, feat, thr, dleft,
                     has_categorical=cfg.has_categorical,
@@ -352,8 +346,7 @@ def make_gspmd_grower(cfg: GrowerConfig, mesh: Mesh,
             # --- smaller-child histogram + parent subtraction ------------
             small_left = frow[2] <= frow[5]
             small_id = jnp.where(small_left, l, new_leaf)
-            with tracer.span("histogram", site="split", traced=True), \
-                    jax.named_scope("histogram"):
+            with jax.named_scope("histogram"):
                 if use_fused:
                     hist_small = measure(row_leaf, small_id, gw, hw, cw,
                                          site="split")

@@ -17,7 +17,11 @@ _DEFAULT = os.path.join(
 
 def enable_persistent_cache() -> str:
     """Return the compile-cache directory in use, pointing jax at the
-    checkout default first when the environment names none."""
+    checkout default first when the environment names none.  Whoever
+    enables the cache is about to compile, so the compile counters
+    (``obs/counters.install_compile_listener``) start listening here."""
+    from ..obs.counters import install_compile_listener
+    install_compile_listener()
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if path:
         return path

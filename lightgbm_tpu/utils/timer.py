@@ -2,11 +2,11 @@
 
 The reference accumulates per-phase ``std::chrono`` counters under
 ``#ifdef TIMETAG`` (``serial_tree_learner.cpp:10-37``, ``gbdt.cpp:22-64``)
-and dumps them at destruction.  Here the counters are always on (the cost is
-one clock read per phase) and reported through the logger; each phase is
-additionally mirrored into the telemetry tracer (``lightgbm_tpu.obs``) —
-a shared no-op when telemetry is disabled, a Chrome-trace span (plus
-``jax.profiler.TraceAnnotation`` for XProf correlation) when enabled.
+and dumps them at destruction.  Here the counters are always on and
+reported through the logger.  Each phase is measured once, by
+``obs.trace.phase`` (the ``lgb:<name>`` profiler annotation, the
+process-wide ``phase_seconds`` counter, the Chrome-trace span when the
+tracer records); this class keeps the per-owner totals of that measurement.
 Deep kernel-level profiles come from ``jax.profiler`` instead (see
 ``engine.train``'s ``profile_dir`` parameter).
 """
@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import collections
 import contextlib
-import time
 from typing import Dict
 
 from ..obs import memory as obs_memory
@@ -35,19 +34,18 @@ class PhaseTimers:
         self.first: Dict[str, float] = {}
 
     @contextlib.contextmanager
-    def phase(self, name: str):
-        t0 = time.perf_counter()
-        span = obs_trace.get_tracer().span(name)
-        span.__enter__()
+    def phase(self, name: str, **args):
+        ph = obs_trace.phase(name, **args)
+        ph.__enter__()
         try:
             yield
         finally:
             # attach the phase's peak device bytes to the span it already
             # emits (both singletons: a no-op unless the tracer AND the
             # memory monitor are armed; the sample is a host-side read)
-            obs_memory.get_memory().annotate(span)
-            span.__exit__(None, None, None)
-            self.add(name, time.perf_counter() - t0)
+            obs_memory.get_memory().annotate(ph.span)
+            ph.__exit__(None, None, None)
+            self.add(name, ph.seconds)
 
     def add(self, name: str, seconds: float) -> None:
         self.seconds[name] += seconds

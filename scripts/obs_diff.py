@@ -196,7 +196,7 @@ def compare_bench(a, b, thresholds):
 def _phase_steady(events):
     from lightgbm_tpu.obs.report import phase_table
     return {r["span"]: r["steady_mean_ms"]
-            for r in phase_table(events, traced=False)}
+            for r in phase_table(events, steady=True)}
 
 
 def _trace_kernel(events):

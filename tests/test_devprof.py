@@ -70,22 +70,29 @@ def _cpu_fixture():
     containment, innermost wins; a trailing op falls back to the last
     window dispatched before it)."""
     return [
-        # nested host windows: boosting wraps histogram
-        {"ph": "X", "pid": 1, "tid": 2, "name": "boosting",
+        # nested host windows (obs/trace.phase annotations): tree wraps
+        # tree.wait
+        {"ph": "X", "pid": 1, "tid": 2, "name": "lgb:tree",
          "ts": 0.0, "dur": 1000.0, "args": {}},
-        {"ph": "X", "pid": 1, "tid": 2, "name": "histogram",
+        {"ph": "X", "pid": 1, "tid": 2, "name": "lgb:tree.wait",
          "ts": 100.0, "dur": 400.0, "args": {}},
-        {"ph": "X", "pid": 1, "tid": 2, "name": "split_find",
-         "ts": 600.0, "dur": 300.0, "args": {}},
-        # midpoint 250 inside both -> innermost (histogram)
+        # an annotation with arguments, as the profiler's Chrome-trace
+        # export writes it: short name, the whole in long_name
+        {"ph": "X", "pid": 1, "tid": 2, "name": "score",
+         "ts": 600.0, "dur": 300.0,
+         "args": {"iteration": "0", "long_name": "lgb:score"}},
+        # a bare name is no window of the program's
+        {"ph": "X", "pid": 1, "tid": 2, "name": "tree",
+         "ts": 0.0, "dur": 2000.0, "args": {}},
+        # midpoint 250 inside both -> innermost (tree.wait)
         {"ph": "X", "pid": 1, "tid": 3, "name": "convolution.1",
          "ts": 150.0, "dur": 200.0, "args": {"hlo_op": "convolution.1"}},
-        # midpoint 700 -> split_find
+        # midpoint 700 -> score (and inside tree: innermost wins)
         {"ph": "X", "pid": 1, "tid": 3, "name": "reduce.2",
          "ts": 650.0, "dur": 100.0, "args": {"hlo_op": "reduce.2"}},
         # starts after every window closed -> last-before fallback
         # (async dispatch ordering) -> the most recently STARTED window,
-        # split_find
+        # score
         {"ph": "X", "pid": 1, "tid": 3, "name": "add.3",
          "ts": 1100.0, "dur": 100.0, "args": {"hlo_op": "add.3"}},
         # an untagged host event is not an op
@@ -116,11 +123,13 @@ def test_tpu_scope_attribution_roundtrip():
 def test_cpu_window_attribution_roundtrip():
     out = obs_devprof.attribute(_cpu_fixture())
     assert out["op_count"] == 3
-    # innermost containment beats the outer boosting window
-    assert out["phase_device_ms"]["histogram"] == pytest.approx(0.2)
-    # split_find's contained op + the trailing op that falls back to the
-    # most recently started window
-    assert out["phase_device_ms"]["split_find"] == pytest.approx(0.2)
+    # innermost containment beats the outer tree window; phases are named
+    # without the annotation prefix
+    assert out["phase_device_ms"]["tree.wait"] == pytest.approx(0.2)
+    # score's contained op + the trailing op that falls back to the most
+    # recently started window
+    assert out["phase_device_ms"]["score"] == pytest.approx(0.2)
+    assert set(out["phase_device_ms"]) == {"tree.wait", "score"}
     assert out["attributed_fraction"] == pytest.approx(1.0)
 
 

@@ -122,25 +122,44 @@ def test_phase_timers_feed_the_tracer_sink():
 # ------------------------------------------------------------ training spans
 
 
-def test_cpu_training_emits_iteration_and_split_span_tree(traced_training):
-    path, _ = traced_training
+def test_cpu_training_emits_iteration_phase_tree_and_compile_counter(
+        traced_training):
+    path, snap = traced_training
     events = obs_report.load_events(path)
-    x_names = [e["name"] for e in events if e.get("ph") == "X"]
-    # per-iteration spans from boosting, per-phase from the timers sink,
-    # per-split (trace-time) spans from the grower
-    for name in ("train", "iteration", "boosting", "tree", "score",
-                 "histogram", "split_find", "partition"):
+    spans = [e for e in events if e.get("ph") == "X"]
+    x_names = [e["name"] for e in spans]
+    # set-up boundaries, per-iteration spans, the phases inside them and
+    # the children that make the tree phase's self time mean something
+    for name in ("dataset.construct", "setup.device", "setup.grower",
+                 "train", "iteration", "boosting", "bagging", "tree",
+                 "tree.wait", "tree.host", "score"):
         assert name in x_names, f"missing span {name!r} in {sorted(set(x_names))}"
     assert x_names.count("iteration") == 2
-    # iteration spans nest inside the train span
-    train_ev = next(e for e in events if e["name"] == "train")
-    for it in (e for e in events if e["name"] == "iteration"):
-        assert train_ev["ts"] <= it["ts"] + 1e-3
-        assert it["ts"] + it["dur"] <= train_ev["ts"] + train_ev["dur"] + 1e-3
-    # the grower's split spans carry the call-site tag
-    hist_sites = {e.get("args", {}).get("site")
-                  for e in events if e["name"] == "histogram"}
-    assert {"root", "split"} <= hist_sites
+    # jitted code opens no host span: it would fire once, at trace time
+    assert not {"histogram", "split_find", "partition"} & set(x_names)
+    assert not any("traced" in e.get("args", {}) for e in spans)
+
+    def inside(child, parent):
+        return (parent["ts"] <= child["ts"] + 1e-3 and child["ts"]
+                + child["dur"] <= parent["ts"] + parent["dur"] + 1e-3)
+
+    train_ev = next(e for e in spans if e["name"] == "train")
+    iters = [e for e in spans if e["name"] == "iteration"]
+    assert [e["args"]["index"] for e in iters] == [0, 1]
+    assert all(inside(it, train_ev) for it in iters)
+    # every phase lies in the iteration whose index it carries
+    for name in ("boosting", "bagging", "tree", "score"):
+        for ev in (e for e in spans if e["name"] == name):
+            assert inside(ev, iters[ev["args"]["iteration"]]), name
+    grower = next(e for e in spans if e["name"] == "setup.grower")
+    assert inside(grower, next(e for e in spans
+                               if e["name"] == "setup.device"))
+    # what the trace-time spans used to hint at, measured: the grower's
+    # trace / lower / compile seconds, and one compile call
+    secs = snap["counters"]["compile_seconds"]
+    for stage in ("trace", "lower", "backend"):
+        assert secs[f"fun=grow_tree,stage={stage}"] > 0, sorted(secs)
+    assert snap["counters"]["compile_calls"]["fun=grow_tree"] >= 1
 
 
 def test_report_renders_phase_and_kernel_tables(traced_training):
@@ -169,6 +188,266 @@ def test_cli_round_trips_a_training_trace(traced_training):
     assert r2.returncode == 0
     doc = json.loads(r2.stdout)
     assert any(p["span"] == "iteration" for p in doc["phases"])
+
+
+# ------------------------------------- always-on spans, scopes and counters
+
+
+def _capture_spans(tmp_path, params, rounds=2, valid=True):
+    """A plain ``lgb.train`` under ``jax.profiler.start_trace``; returns the
+    host-plane events of the capture as (name, start ns, end ns, stats)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    X, y = _make_xy()
+    ds = lgb.Dataset(X, label=y, free_raw_data=False)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    log_dir = str(tmp_path / "capture")
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    try:
+        lgb.train(dict({"objective": "binary", "num_leaves": 7,
+                        "min_data_in_leaf": 5, "verbose": -1,
+                        "pipeline_trees": False,
+                        "metric": ["auc", "binary_logloss"]}, **params),
+                  ds, num_boost_round=rounds, verbose_eval=False,
+                  valid_sets=[ds] if valid else None)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.append((ev.name, ev.start_ns, ev.start_ns
+                            + ev.duration_ns, dict(ev.stats)))
+    return out
+
+
+@pytest.mark.parametrize("tracer", ["off", "trace_path"])
+def test_profiler_capture_holds_program_spans_without_telemetry(
+        tmp_path, tracer):
+    """A profiler capture needs no telemetry switch: ``lgb:iteration`` and
+    its phases are in it, ONE annotation per span in either tracer state,
+    and no host span of the program goes without the prefix."""
+    params = {} if tracer == "off" else {
+        "trace_path": str(tmp_path / "t.json")}
+    events = _capture_spans(tmp_path, params, rounds=2)
+    spans = [e for e in events if e[0].startswith("lgb:")]
+    count = {}
+    for name, *_ in spans:
+        count[name] = count.get(name, 0) + 1
+    # one per span: 2 iterations, one tree each, evaluated once each
+    for name, n in (("lgb:train", 1), ("lgb:iteration", 2),
+                    ("lgb:boosting", 2), ("lgb:bagging", 2),
+                    ("lgb:tree", 2), ("lgb:tree.wait", 2),
+                    ("lgb:tree.host", 2), ("lgb:score", 2),
+                    ("lgb:metric", 2), ("lgb:metric.fetch", 2),
+                    ("lgb:metric.auc", 2), ("lgb:metric.binary_logloss", 2),
+                    ("lgb:dataset.construct", 1), ("lgb:setup.device", 1),
+                    ("lgb:setup.grower", 1)):
+        assert count.get(name) == n, (name, count)
+    bare = {"train", "iteration", "boosting", "bagging", "tree", "score",
+            "metric", "histogram", "split_find", "partition"}
+    assert not bare & {e[0] for e in events}
+
+    def inside(child, parent):
+        return parent[1] <= child[1] and child[2] <= parent[2]
+
+    iters = sorted((e for e in spans if e[0] == "lgb:iteration"),
+                   key=lambda e: e[1])
+    assert [int(e[3]["index"]) for e in iters] == [0, 1]
+    for name in ("lgb:boosting", "lgb:bagging", "lgb:tree", "lgb:score"):
+        for ev in (e for e in spans if e[0] == name):
+            assert inside(ev, iters[int(ev[3]["iteration"])]), name
+    trees = {int(e[3]["iteration"]): e for e in spans if e[0] == "lgb:tree"}
+    for name in ("lgb:tree.wait", "lgb:tree.host"):
+        for ev in (e for e in spans if e[0] == name):
+            assert inside(ev, trees[int(ev[3]["iteration"])]), name
+    # the metric span covers the fetch of the scores and each metric
+    metrics = sorted((e for e in spans if e[0] == "lgb:metric"),
+                     key=lambda e: e[1])
+    for name in ("lgb:metric.fetch", "lgb:metric.auc",
+                 "lgb:metric.binary_logloss"):
+        kids = sorted((e for e in spans if e[0] == name), key=lambda e: e[1])
+        assert all(inside(k, m) for k, m in zip(kids, metrics)), name
+    assert {e[3]["data"] for e in metrics} == {"training"}
+
+
+def test_metric_phase_covers_the_score_fetch(tmp_path, monkeypatch):
+    """``eval_*`` hand ``_eval`` the DEVICE scores: the host copy is made
+    inside the metric phase, under ``metric.fetch``."""
+    from lightgbm_tpu.boosting import GBDT
+    seen = []
+    real = GBDT._eval_scores
+
+    def spy(self, scores):
+        seen.append(type(scores).__module__.split(".")[0])
+        return real(self, scores)
+
+    monkeypatch.setattr(GBDT, "_eval_scores", spy)
+    path = str(tmp_path / "t.json")
+    X, y = _make_xy()
+    ds = lgb.Dataset(X, label=y, free_raw_data=False)
+    lgb.train({"objective": "binary", "num_leaves": 7, "verbose": -1,
+               "min_data_in_leaf": 5, "metric": "auc", "trace_path": path},
+              ds, num_boost_round=1, verbose_eval=False, valid_sets=[ds])
+    assert seen and "numpy" not in seen, seen
+    events = obs_report.load_events(path)
+    metric = next(e for e in events if e["name"] == "metric")
+    for name in ("metric.fetch", "metric.auc"):
+        kid = next(e for e in events if e["name"] == name)
+        assert metric["ts"] <= kid["ts"]
+        assert kid["ts"] + kid["dur"] <= metric["ts"] + metric["dur"] + 1e-3
+    assert metric["args"]["iteration"] == 0      # the tree it evaluates
+    assert metric["args"]["data"] == "training"
+
+
+def test_phase_primitive_counts_with_the_tracer_off():
+    """Disabled tracer: still the shared NULL_SPAN (nothing recorded), and
+    the phase still lands in the process-wide registry and the timers."""
+    from lightgbm_tpu.utils.timer import PhaseTimers
+    obs_trace.stop()
+    assert obs_trace.get_tracer().span("zz") is obs_trace.NULL_SPAN
+    before = counters.get("phase_calls").get("phase=zz_off", 0)
+    ph = obs_trace.phase("zz_off", iteration=3)
+    with ph:
+        assert ph.span is obs_trace.NULL_SPAN
+    t = PhaseTimers()
+    with t.phase("zz_off"):
+        pass
+    assert counters.get("phase_calls")["phase=zz_off"] == before + 2
+    assert counters.get("phase_seconds")["phase=zz_off"] >= ph.seconds > 0
+    # the timers keep their own totals from the same measurement
+    assert t.counts["zz_off"] == 1 and t.seconds["zz_off"] > 0
+    assert obs_trace.get_tracer().events() == []
+
+
+def test_phase_and_compile_counters_after_plain_training():
+    """No telemetry parameter: the registry still says where set-up and
+    the loop spent their seconds, and which function compiled."""
+    calls0 = counters.get("phase_calls")
+    _train(rounds=2)
+    secs, calls = counters.get("phase_seconds"), counters.get("phase_calls")
+    for phase, n in (("dataset.construct", 1), ("setup.device", 1),
+                     ("setup.grower", 1), ("train", 1), ("iteration", 2),
+                     ("boosting", 2), ("tree", 2), ("score", 2)):
+        key = f"phase={phase}"
+        assert secs[key] > 0, key
+        assert calls[key] - calls0.get(key, 0) >= n, key
+    comp = counters.get("compile_seconds")
+    for stage in ("trace", "lower", "backend"):
+        assert comp[f"fun=get_gradients,stage={stage}"] > 0, sorted(comp)
+    # the labels are closed over as a constant, so a second data set of
+    # the SAME shape compiles the gradient program again: pinned here for
+    # the perf_opt PR that passes them as an argument to undo
+    n1 = counters.get("compile_calls")["fun=get_gradients"]
+    X, y = _make_xy(seed=1)
+    lgb.train({"objective": "binary", "num_leaves": 7, "verbose": -1,
+               "min_data_in_leaf": 5}, lgb.Dataset(X, label=y),
+              num_boost_round=1, verbose_eval=False)
+    assert counters.get("compile_calls")["fun=get_gradients"] == n1 + 1
+
+
+def test_training_reset_keeps_what_was_counted_before_it():
+    """``Dataset.construct`` may run before ``train()`` (the benchmark's
+    driver does): the per-training reset keeps the phase and compile
+    counters and still clears the kernel-identity evidence."""
+    X, y = _make_xy()
+    ds = lgb.Dataset(X, label=y, free_raw_data=False)
+    counters.reset()
+    ds.construct()
+    binned = counters.get("phase_seconds")["phase=dataset.construct"]
+    assert binned > 0
+    counters.inc("hist_dispatch", 7, method="einsum", site="stale")
+    lgb.train({"objective": "binary", "num_leaves": 7, "verbose": -1,
+               "min_data_in_leaf": 5, "telemetry": True}, ds,
+              num_boost_round=1, verbose_eval=False)
+    assert counters.get("phase_seconds")["phase=dataset.construct"] == binned
+    assert counters.get("phase_calls")["phase=dataset.construct"] == 1
+    assert "method=einsum,site=stale" not in counters.get("hist_dispatch")
+
+
+def test_compile_listener_charges_nested_traces_once():
+    """jit traces nest and each reports its whole duration: the listener
+    charges every second to the innermost function only."""
+    # (the package attribute ``obs.counters`` is the registry instance)
+    counters_mod = importlib.import_module("lightgbm_tpu.obs.counters")
+    ev = "/jax/core/compile/jaxpr_trace_duration"
+
+    def seconds(fun):
+        return counters.get("compile_seconds").get(
+            f"fun={fun},stage=trace", 0.0)
+
+    inner0, outer0 = seconds("zz_inner"), seconds("zz_outer")
+    # made-up durations would claim the real traces of the last second
+    counters_mod._open_traces.done = []
+    counters_mod._on_compile_duration(ev, 0.25, fun_name="zz_inner")
+    counters_mod._on_compile_duration(ev, 1.0, fun_name="zz_outer")
+    assert seconds("zz_inner") - inner0 == pytest.approx(0.25)
+    assert seconds("zz_outer") - outer0 == pytest.approx(0.75)
+    # lower and backend are one function's own; jit(f) and f are one name
+    calls0 = counters.get("compile_calls").get("fun=zz_outer", 0)
+    counters_mod._on_compile_duration(
+        "/jax/core/compile/backend_compile_duration", 0.5,
+        fun_name="jit(zz_outer)")
+    assert counters.get("compile_seconds")[
+        "fun=zz_outer,stage=backend"] == pytest.approx(0.5)
+    assert counters.get("compile_calls")["fun=zz_outer"] == calls0 + 1
+    hits0 = counters.total("compile_cache_hits")
+    counters_mod._on_compile_event("/jax/compilation_cache/cache_hits")
+    counters_mod._on_compile_event("/jax/compilation_cache/cache_misses")
+    assert counters.total("compile_cache_hits") == hits0 + 1
+
+
+def test_loop_programs_carry_their_scopes():
+    """``objective`` and ``score_update`` are entered inside the traced
+    functions, so they are in the lowered program whatever the cache
+    holds: a device trace attributes the loop's own programs by them."""
+    bst = _train(rounds=1)
+    gbdt = bst.inner
+    grad = gbdt._grad_fn.lower(gbdt.scores).as_text(debug_info=True)
+    assert "jit(get_gradients)/objective/" in grad
+    k, lr = gbdt.scores[0], np.float32(0.1)
+    leaf = np.zeros(gbdt.num_data, np.int32)
+    upd = gbdt._update_score.lower(
+        k, np.zeros(7, np.float32), leaf, lr).as_text(debug_info=True)
+    assert "jit(_update_score)/score_update/" in upd
+    nodes = np.full(6, -1, np.int32)
+    from lightgbm_tpu.boosting import _route_update_score
+    routed = _route_update_score.lower(
+        k, gbdt.bins, nodes, nodes, np.zeros(6, bool), nodes, nodes,
+        gbdt.feat_info, np.zeros(6, bool), np.zeros((6, 1), bool),
+        np.zeros(7, np.float32), lr).as_text(debug_info=True)
+    assert "/score_update/" in routed and "predict_binned_leaf" in routed
+    # neither token is part of another scope's or a source file's name
+    assert "objective" not in upd and "score_update" not in grad
+
+
+def test_hbm_gauges_record_the_allocators_peaks():
+    """After the first tree the allocator's two peaks are gauged, to be
+    read beside ``hbm_predicted_peak_bytes``; off the chip there are no
+    allocator statistics and no gauges."""
+    from lightgbm_tpu.obs import memory as obs_memory
+
+    class Chip:
+        def memory_stats(self):
+            return {"peak_bytes_in_use": 1290, "peak_bytes_reserved": 10760,
+                    "bytes_limit": 16000}
+
+    _train(rounds=1)
+    gauges = counters.snapshot()["gauges"]
+    assert gauges["hbm_predicted_peak_bytes"] > 0
+    assert "hbm_reserved_peak_bytes" not in gauges      # CPU: no stats
+    obs_memory.gauge_hbm_peaks(Chip())
+    gauges = counters.snapshot()["gauges"]
+    assert gauges["hbm_in_use_peak_bytes"] == 1290
+    assert gauges["hbm_reserved_peak_bytes"] == 10760
+    counters.reset()
 
 
 # ------------------------------------------------------------------- counters
