@@ -397,9 +397,55 @@ def route_goes_left(binf, meta: FeatureMeta, feat, thr, dleft,
                   | ((mt_f == MISSING_ZERO) & (binf == db_f)))
     goes_left = jnp.where(is_missing, dleft, binf <= thr)
     if has_categorical:
-        cat_go_left = cat_row[jnp.clip(binf, 0, max_bin - 1)]
+        cat_go_left = bin_flags(cat_row, jnp.clip(binf, 0, max_bin - 1))
         goes_left = jnp.where(is_cat_l, cat_go_left, goes_left)
     return goes_left
+
+
+def bin_flags(flags, binf):
+    """``flags[binf]`` for a per-bin ``bool[B]`` table and in-range bins of
+    any shape, without a gather per element: the table packed into ``B / 32``
+    words, the word picked by a chain of selects, the bit by a shift.  The
+    partition routes all N rows of the split column, and on the v5e the
+    gather costs 7.8 ns a row even from 255 entries (82.1 ms a split at
+    10.5M rows, against 0.24 for this chain of 8: PERF.md section 5)."""
+    nb = flags.shape[0]
+    nw = -(-nb // 32)
+    bits = jnp.pad(flags, (0, 32 * nw - nb)).reshape(nw, 32)
+    words = jnp.sum(bits.astype(jnp.uint32)
+                    << jnp.arange(32, dtype=jnp.uint32), axis=1)
+    hi = binf >> 5
+    word = jnp.broadcast_to(words[0], binf.shape)
+    for k in range(1, nw):
+        word = jnp.where(hi == k, words[k], word)
+    return ((word >> (binf & 31).astype(jnp.uint32)) & 1).astype(bool)
+
+
+def pack_row_bits(flags):
+    """``bool[N]`` -> ``uint32[M]``: row ``i``'s flag is bit ``i >> log2 M``
+    of word ``i & (M - 1)``, ``M`` the power of two that makes 32 planes
+    cover ``N``.  A plane is a contiguous run of rows, so
+    packing is one aligned slice per plane that holds rows, shifted and
+    or-ed: elementwise, no relayout.  The flags are widened to words FIRST:
+    sliced as bytes, the v5e's compiler computes them twice (its program
+    for this, compiled here: 2.2M estimated cycles against 1.1M)."""
+    n = flags.shape[0]
+    m = 1 << (-(-n // 32) - 1).bit_length()
+    planes = -(-n // m)
+    flags = jnp.pad(flags.astype(jnp.uint32), (0, planes * m - n))
+    words = flags[:m]
+    for k in range(1, planes):
+        words = words | (flags[k * m:(k + 1) * m] << k)
+    return words
+
+
+def take_row_bits(words, rows):
+    """The flags of ``rows`` (in-bounds row ids) out of
+    :func:`pack_row_bits`' table: one 32-bit gather and a shift."""
+    m = words.shape[0]
+    w = words.at[rows & (m - 1)].get(mode="promise_in_bounds")
+    plane = rows >> (m.bit_length() - 1)
+    return ((w >> plane.astype(jnp.uint32)) & 1).astype(bool)
 
 
 def pool_rows(res: SplitResult, axis: int):
@@ -565,6 +611,11 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
         use_ordered = cfg.ordered_bins == "on" and pack_plan is None
         route_from_obins = (use_ordered and hbins is hist_src
                             and hist_src is bins)
+        if not route_from_obins:
+            # column-major copy of the routing matrix, made once per tree
+            # outside the split loop: each partition branch slices its
+            # split column out of it
+            bins_cm = bins.T
         if use_ordered:
             if cfg.gather_words == "on":
                 log.warning("gather_words=on ignored: ordered_bins=on "
@@ -759,6 +810,15 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                 valid = j < cnt
                 idx = jnp.where(valid, win, n)
                 col_idx = feat if meta.col is None else meta.col[feat]
+
+                def route(binf):
+                    return route_goes_left(
+                        binf, meta, feat, thr, dleft,
+                        has_categorical=cfg.has_categorical,
+                        is_cat_l=is_cat_l if cfg.has_categorical else None,
+                        cat_row=cat_row if cfg.has_categorical else None,
+                        max_bin=cfg.max_bin)
+
                 if route_from_obins:
                     # the splitting column is a strided (not random) read
                     # of the ordered window — no gather at all
@@ -766,17 +826,26 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                         obins, (start, 0), (size, obins.shape[1]))
                     binf = lax.dynamic_index_in_dim(
                         wb, col_idx, axis=1, keepdims=False).astype(jnp.int32)
+                    goes_left = route(binf)
                 else:
-                    # 2D gather (row, col) — per-dimension indices never
-                    # overflow int32, unlike a flattened N*F index
-                    binf = bins.at[jnp.minimum(idx, n - 1), col_idx].get(
-                        mode="promise_in_bounds").astype(jnp.int32)
-                goes_left = route_goes_left(
-                    binf, meta, feat, thr, dleft,
-                    has_categorical=cfg.has_categorical,
-                    is_cat_l=is_cat_l if cfg.has_categorical else None,
-                    cat_row=cat_row if cfg.has_categorical else None,
-                    max_bin=cfg.max_bin)
+                    # route the WHOLE split column, then read one bit per
+                    # window row: the column is a dense slice of the
+                    # column-major copy, its N decisions are elementwise
+                    # (0.13 ms a split at 10.5M rows, paid by the smallest
+                    # window too), and packed 32 to a word they make a
+                    # table of N/8 bytes, small enough to stay on chip
+                    # while a rank-1 gather reads it by row id: 8.6 ns an
+                    # element on the v5e, where the (row, col) byte gather
+                    # this replaces read 20 from HBM, and the column itself
+                    # as s32[N], which the grow program also keeps in HBM,
+                    # 23.5 (scripts/probe_route_read.py; PERF.md section 5)
+                    obs_counters.inc("partition_route_dispatch",
+                                     read="column")
+                    colv = lax.dynamic_index_in_dim(
+                        bins_cm, col_idx, axis=0, keepdims=False)
+                    goes_left = take_row_bits(
+                        pack_row_bits(route(colv.astype(jnp.int32))),
+                        jnp.minimum(idx, n - 1))
                 goes_left = goes_left & valid
                 use_sort = cfg.partition_impl == "sort"
                 # the Pallas compaction kernel needs 512-row blocks, f32-

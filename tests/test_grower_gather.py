@@ -223,3 +223,145 @@ def test_grow_gather_panel_identical():
         got = lgb.train(dict(base, gather_panel="on"),
                         lgb.Dataset(X, label=y), num_boost_round=4)
         assert ref.model_to_string() == got.model_to_string(), extra
+
+
+# ---- the routing read against a plain numpy router -------------------------
+#
+# The partition slices the split column out of a column-major copy, routes
+# ALL its rows, packs the decisions to bits and gathers one word per window
+# row.  The router below knows nothing of that: it replays
+# the grown tree's splits on ``bins[rows, col]`` with its own copy of the
+# decision rule (tree.h:257-313) and must arrive at every split's left
+# count and at the grower's row -> leaf map.
+
+def _route_case(kind):
+    import lightgbm_tpu as lgb
+    rng = np.random.RandomState(26)
+    n = 4000
+    params = {"max_bin": 31, "verbose": -1}
+    cat = "auto"
+    if kind == "numeric_missing":
+        X = rng.randn(n, 5)
+        X[rng.rand(n, 5) < 0.1] = np.nan
+    elif kind == "categorical":
+        X = np.concatenate(
+            [rng.randn(n, 2),
+             rng.randint(0, 12, size=(n, 2)).astype(np.float64)], axis=1)
+        cat = [2, 3]
+    elif kind == "efb":
+        sparse = np.zeros((n, 10))
+        act = np.where(rng.rand(n) < 0.5)[0]    # mutually exclusive columns
+        sparse[act, rng.randint(0, 10, size=act.size)] = rng.randint(
+            1, 4, size=act.size)
+        X = np.concatenate([rng.randn(n, 3), sparse], axis=1)
+    elif kind == "uint16":
+        X = rng.randn(n, 3)
+        params.update(max_bin=400, min_data_in_bin=1)
+    y = (np.nan_to_num(X[:, 0]) + 0.7 * np.nan_to_num(X[:, -1])
+         + 0.3 * rng.randn(n) > 0.3).astype(np.float64)
+    td = lgb.Dataset(X, label=y, params=params,
+                     categorical_feature=cat).construct().constructed
+    return td.binned, td.feature_meta(), td.max_num_bin(), y
+
+
+def _numpy_route(bins, fm, tree, num_leaves):
+    """Replay the splits in node order: node i splits the leaf that keeps
+    its id on the left and opens leaf i + 1 on the right."""
+    n = bins.shape[0]
+    row_leaf = np.zeros(n, np.int32)
+    left_counts = []
+    for node in range(num_leaves - 1):
+        child = node
+        while child >= 0:               # the split leaf's id: leftmost leaf
+            child = int(tree.left_child[child])
+        leaf = ~child
+        rows = np.where(row_leaf == leaf)[0]
+        feat = int(tree.split_feature[node])
+        col = int(fm["col"][feat]) if "col" in fm else feat
+        b = bins[rows, col].astype(np.int64)
+        nb, db = int(fm["num_bin"][feat]), int(fm["default_bin"][feat])
+        off = int(fm["offset"][feat]) if "offset" in fm else -1
+        if off >= 0:                    # bundle slot -> the feature's own bin
+            local = b - off
+            inside = (local >= 0) & (local < nb - 1)
+            b = np.where(inside, local + (local >= db), db)
+        if tree.is_cat[node]:
+            left = tree.cat_bins[node][np.clip(b, 0, tree.cat_bins.shape[1] - 1)]
+        else:
+            mt = int(fm["missing_type"][feat])
+            missing = (b == nb - 1) if mt == 2 else (b == db) if mt == 1 \
+                else np.zeros(len(b), bool)
+            left = np.where(missing, bool(tree.default_left[node]),
+                            b <= int(tree.threshold_bin[node]))
+        left_counts.append(int(left.sum()))
+        row_leaf[rows[~left]] = node + 1
+    return left_counts, row_leaf
+
+
+@pytest.mark.parametrize("kind", ["numeric_missing", "categorical", "efb",
+                                  "uint16"])
+def test_grow_routes_like_plain_numpy_router(kind):
+    from lightgbm_tpu.obs.counters import counters
+    bins, fm, max_bin, y = _route_case(kind)
+    assert bins.dtype == (np.uint16 if kind == "uint16" else np.uint8)
+    assert ("col" in fm) == (kind == "efb")
+    n = bins.shape[0]
+    meta = FeatureMeta(**{k: jnp.asarray(v) for k, v in fm.items()})
+    cfg = GrowerConfig(num_leaves=31, min_data_in_leaf=5, max_bin=max_bin,
+                       hist_method="segment", bucket_min_log2=6,
+                       has_categorical=bool(fm["is_categorical"].any()),
+                       has_missing=bool((fm["missing_type"] != 0).any()))
+    g = jnp.asarray((0.5 - y).astype(np.float32))
+    one = jnp.ones((n,), jnp.float32)
+    before = counters.get("partition_route_dispatch").get("read=column", 0)
+    tree, row_leaf = jax.jit(make_grower(cfg))(
+        jnp.asarray(bins), g, one * 0.25, one, meta,
+        jnp.ones((len(fm["num_bin"]),), bool))
+    # one count per traced partition branch: the grower says which read
+    # it was built with
+    assert counters.snapshot()["counters"]["partition_route_dispatch"][
+        "read=column"] > before
+    tree = jax.tree.map(np.asarray, tree)
+    num_leaves = int(tree.num_leaves)
+    assert num_leaves > 8
+    if kind == "categorical":
+        assert tree.is_cat[:num_leaves - 1].any()
+    if kind == "efb":
+        assert (fm["offset"][tree.split_feature[:num_leaves - 1]] >= 0).any()
+    left_counts, want_leaf = _numpy_route(bins, fm, tree, num_leaves)
+    for node, cnt in enumerate(left_counts):
+        lc = int(tree.left_child[node])
+        got = tree.internal_count[lc] if lc >= 0 else tree.leaf_count[~lc]
+        assert int(got) == cnt, (kind, node, int(got), cnt)
+    assert np.array_equal(np.asarray(row_leaf), want_leaf)
+
+
+# ---- the bit tables the routing read is made of ----------------------------
+
+@pytest.mark.parametrize("n", [1, 31, 32, 33, 1000, 4097, 70001])
+def test_pack_row_bits_round_trip(n):
+    """Every row's flag comes back from the packed table, whatever N is to
+    the 32 planes (the last plane is short, or empty, unless 32 * M == N)."""
+    from lightgbm_tpu.grower import pack_row_bits, take_row_bits
+    flags = np.random.RandomState(n).rand(n) < 0.4
+    words = jax.jit(pack_row_bits)(jnp.asarray(flags))
+    m = words.shape[0]
+    assert words.dtype == jnp.uint32 and m & (m - 1) == 0
+    assert 32 * m >= n and (m == 1 or 16 * m < n)
+    rows = jnp.asarray(np.random.RandomState(1).permutation(n).astype(np.int32))
+    got = take_row_bits(words, rows)
+    assert np.array_equal(np.asarray(got), flags[np.asarray(rows)])
+
+
+@pytest.mark.parametrize("nb", [5, 32, 33, 255, 256, 1024, 1025])
+def test_bin_flags_is_the_table_lookup(nb):
+    """The select chain over packed words reads what ``flags[binf]``
+    reads, whatever B is to the 32 bits of a word."""
+    from lightgbm_tpu.grower import bin_flags
+    rng = np.random.RandomState(nb)
+    flags = rng.rand(nb) < 0.5
+    binf = rng.randint(0, nb, size=(3, 500)).astype(np.int32)
+    binf[0, :2] = (0, nb - 1)
+    got = jax.jit(bin_flags)(jnp.asarray(flags), jnp.asarray(binf))
+    assert got.dtype == jnp.bool_ and got.shape == binf.shape
+    assert np.array_equal(np.asarray(got), flags[binf])
