@@ -570,10 +570,8 @@ class GBDT:
             plan = self._pack_plan
             hw = (max(PACK_JOINT_BINS, self.grower_cfg.max_bin)
                   if plan is not None else self.grower_cfg.max_bin)
-            ncols = (plan.num_storage_cols if plan is not None
-                     else train.binned.shape[1])
             reason = fused_gate_reason(
-                train.binned.dtype, jnp.float32, hw, ncols,
+                train.binned.dtype, jnp.float32, hw,
                 self.grower_cfg.ordered_bins == "on" and plan is None)
             if reason is not None:
                 resolved = fused_fallback_method()
@@ -911,7 +909,7 @@ class GBDT:
             # the mesh plan below): downgrade loudly BEFORE labels are
             # read, per the rung-honesty discipline
             reason = fused_gate_reason(hist_dtype, jnp.float32, hist_width,
-                                       1, False)
+                                       False)
             if reason is not None:
                 log.warning("gspmd_hist=fused unavailable (%s); using the "
                             "flat scatter-add histogram", reason)
@@ -997,14 +995,11 @@ class GBDT:
         if gspmd_hist == "fused":
             # shape-dependent half of the fused gate, now that the mesh
             # extents are known: each device's column slice must be exact
-            # (shard_map even-split) and fit the kernel's column ceiling
+            # (shard_map even-split)
+            reason = None
             if sc_cols % plan.feature != 0:
                 reason = (f"{sc_cols} histogram columns do not split "
                           f"evenly over {plan.feature} feature shards")
-            else:
-                reason = fused_gate_reason(hist_dtype, jnp.float32,
-                                           hist_width,
-                                           sc_cols // plan.feature, False)
             if reason is not None:
                 log.warning("gspmd_hist=fused unavailable (%s); using the "
                             "flat scatter-add histogram", reason)

@@ -31,7 +31,11 @@ import numpy as np
 
 PACK_MAX_BIN = 16          # nibble capacity
 PACK_JOINT_BINS = 256      # joint (lo, hi) index space
-FUSED_COL_GROUP = 8        # fused-kernel feature-group width (8 * 16 lanes)
+FUSED_COL_STEP = 32        # columns the fused kernel contracts per step of
+#                            its loop over a tile: four 128-lane output
+#                            groups of 8 features x 16 lo bins, whose bin
+#                            words are 8 (uint8) or 16 (uint16) whole
+#                            sublanes of the transposed tile
 
 
 def pack_gather_words(mat):
@@ -67,45 +71,66 @@ def unpack_gather_words(words, c: int, per: int):
     return stacked[:, :c].astype(jnp.int32)
 
 
-FUSED_PANEL_LANES = 128    # panel minor dim is padded to this multiple:
-#                            Mosaic DMA row slices must span whole 128-lane
-#                            tiles, so each in-kernel row gather is one
-#                            aligned [1, 128k]-u32 burst (512 B — the HBM
-#                            transaction class a random row read touches
-#                            regardless of how few bytes it keeps)
+FUSED_PANEL_LANES = 128    # one column tile of the panel: Mosaic DMA row
+#                            slices must span whole 128-lane tiles, so a
+#                            tile is one aligned [1, 128]-u32 burst (512 B
+#                            — the HBM transaction class a random row read
+#                            touches regardless of how few bytes it keeps)
+
+
+def fused_col_tiles(n_cols: int, per: int):
+    """(column tiles, columns per tile) of the fused panel for ``n_cols``
+    histogram columns packed ``per`` to a word.
+
+    A tile is FUSED_PANEL_LANES words: its columns' bin words, then the
+    three weight words.  The kernel walks a tile in steps of
+    FUSED_COL_STEP columns, so a tile holds a whole number of steps: at
+    most 480 uint8 columns (120 words).  The columns are spread evenly
+    over the fewest tiles (28 columns: one tile of 32; 2000: five of
+    416)."""
+    cap = (FUSED_PANEL_LANES - 3) * per // FUSED_COL_STEP * FUSED_COL_STEP
+    tiles = max(1, -(-n_cols // cap))
+    even = -(-n_cols // tiles)
+    return tiles, -(-even // FUSED_COL_STEP) * FUSED_COL_STEP
 
 
 def pack_fused_panel(bins_pad, gw_pad, hw_pad, cw_pad):
     """The u32 row layout the fused histogram kernel DMAs per row:
     [N(+1), C] uint8/uint16 bins + three f32 weight columns ->
-    ([N(+1), ceil((W + 3) / 128) * 128] uint32, lanes_per_word).
+    ([tiles, N(+1), 128] uint32, lanes_per_word).
 
-    Columns are zero-padded up to a FUSED_COL_GROUP multiple BEFORE word
-    packing so the kernel's phantom features (its feature loop runs in
-    groups of 8) always read real, provably-zero words; the f32 weights
-    ride as bitcast u32 columns after the words (pure bitcasts — values
-    are bit-identical through the panel); the whole row is then padded to
-    a FUSED_PANEL_LANES multiple (the Mosaic DMA alignment above — HBM
-    footprint 512 B/row at narrow shapes, the price of an aligned
-    single-burst gather).  Callers pass SENTINEL-padded inputs: the last
-    row must carry zero bins and zero weights, because the kernel
-    redirects every past-the-count position to it."""
+    The columns are zero-padded to ``tiles * tile_cols``
+    (:func:`fused_col_tiles`) BEFORE word packing, so the kernel's phantom
+    features always read real, provably-zero words, and cut into tiles of
+    FUSED_PANEL_LANES words each: a tile's bin words, then the f32 weights
+    as bitcast u32 words (pure bitcasts — values are bit-identical through
+    the panel; every tile carries its own copy, so a tile is a whole
+    narrow panel), then zeros (the Mosaic DMA alignment above — 512 B a
+    row and tile, the price of an aligned burst).  Callers pass
+    SENTINEL-padded inputs: the last row must carry zero bins and zero
+    weights, because the kernel redirects every past-the-count position
+    to it."""
     import jax.numpy as jnp
     from jax import lax
     c = bins_pad.shape[1]
-    c_pad = -(-c // FUSED_COL_GROUP) * FUSED_COL_GROUP
-    if c_pad > c:
-        bins_pad = jnp.pad(bins_pad, ((0, 0), (0, c_pad - c)))
+    tiles, tile_cols = fused_col_tiles(c, 4 // bins_pad.dtype.itemsize)
+    if tiles * tile_cols > c:
+        bins_pad = jnp.pad(bins_pad, ((0, 0), (0, tiles * tile_cols - c)))
     words, per = pack_gather_words(bins_pad)
-    panel = jnp.concatenate(
-        [words] + [lax.bitcast_convert_type(w.astype(jnp.float32),
-                                            jnp.uint32)[:, None]
-                   for w in (gw_pad, hw_pad, cw_pad)], axis=1)
-    wp = panel.shape[1]
-    wp_pad = -(-wp // FUSED_PANEL_LANES) * FUSED_PANEL_LANES
-    if wp_pad > wp:
-        panel = jnp.pad(panel, ((0, 0), (0, wp_pad - wp)))
-    return panel, per
+    tile_words = tile_cols // per
+    weights = [lax.bitcast_convert_type(w.astype(jnp.float32),
+                                        jnp.uint32)[:, None]
+               for w in (gw_pad, hw_pad, cw_pad)]
+    # a tile at a time, each the two-dimensional concatenate-and-pad the
+    # one-tile panel has always been: the v5e's compiler fuses that form
+    # into one pass over the rows (built as one [N, tiles, 128]
+    # concatenate it kept four 16-times padded byte planes of the bins
+    # alive: 20 GB at 10.5M rows)
+    panel = [jnp.pad(jnp.concatenate(
+        [words[:, t * tile_words:(t + 1) * tile_words]] + weights, axis=1),
+        ((0, 0), (0, FUSED_PANEL_LANES - tile_words - 3)))
+        for t in range(tiles)]
+    return jnp.stack(panel), per
 
 
 class PackPlan(NamedTuple):

@@ -16,7 +16,7 @@ TPU-native re-design of ``SerialTreeLearner::Train``
   a one-hot MXU matmul (Pallas kernel on TPU); the larger child is obtained
   by parent − smaller subtraction exactly like the reference
   (``serial_tree_learner.cpp:482-488``).  Per-leaf parent histograms live in
-  an HBM pool ``hist_store [L, F, B, 3]`` — the reference's HistogramPool
+  an HBM pool ``hist_store [L, 3 * F * B]`` — the reference's HistogramPool
   (``feature_histogram.hpp:429-597``) without the LRU, since HBM fits all
   leaves;
 * the split loop is a ``lax.while_loop`` with all per-leaf state in fixed
@@ -46,7 +46,7 @@ from .data.packing import (PACK_JOINT_BINS, pack_fused_panel,
 from .obs.counters import counters as obs_counters
 from .ops.histogram import (on_tpu, subset_histogram, subset_histogram_flat,
                             subset_histogram_fused)
-from .ops.pallas_hist import FUSED_MAX_COLS, NIB, fused_idx_fetch
+from .ops.pallas_hist import NIB, fused_idx_fetch
 from .ops.split import (MISSING_NAN, MISSING_ZERO, SplitConfig, SplitResult,
                         best_split, leaf_output, make_fused_ctx)
 from .utils import log
@@ -165,7 +165,7 @@ def decode_bundle_bin(raw, feat, meta: FeatureMeta):
 
 
 def fused_gate_reason(bins_dtype, weights_dtype, hist_width: int,
-                      n_hist_cols: int, use_ordered: bool):
+                      use_ordered: bool):
     """None when the fused-gather kernel can run on this layout, else the
     human-readable reason it cannot.
 
@@ -180,9 +180,6 @@ def fused_gate_reason(bins_dtype, weights_dtype, hist_width: int,
     if hist_width > NIB * NIB:
         return (f"histogram width {hist_width} exceeds the "
                 f"nibble-factorized limit {NIB * NIB}")
-    if n_hist_cols > FUSED_MAX_COLS:
-        return (f"{n_hist_cols} histogram columns exceed the kernel "
-                f"ceiling {FUSED_MAX_COLS}")
     if use_ordered:
         return "ordered_bins=on replaces the row gather entirely"
     return None
@@ -234,7 +231,8 @@ class _LoopState(NamedTuple):
     ow: jnp.ndarray              # [N + maxbuf, 3] leaf-ordered (g, h, c)
     #                              (both [0, 0] dummies unless ordered_bins)
     lsc: jnp.ndarray             # [L, 2] i32: (first position, local count)
-    hist_store: jnp.ndarray      # [L, F, B, 3]: per-leaf histograms
+    hist_store: jnp.ndarray      # [L, 3 * F * B]: per-leaf histograms, a
+    #                              leaf's flat (pool_flat)
     feat_ok: jnp.ndarray         # [L, E] bool: per-leaf is_splittable flags
     sgain: jnp.ndarray           # [L] f32: per-leaf best gain (the heap key)
     sf32: jnp.ndarray            # [L, 8] f32 split pool: left_sum_g,
@@ -446,6 +444,23 @@ def take_row_bits(words, rows):
     w = words.at[rows & (m - 1)].get(mode="promise_in_bounds")
     plane = rows >> (m.bit_length() - 1)
     return ((w >> plane.astype(jnp.uint32)) & 1).astype(bool)
+
+
+def pool_flat(hist):
+    """[..., F, B, 3] histograms -> [..., 3 * F * B] rows of the per-leaf
+    pool, one statistic's [F, B] plane after another.  The pool is carried
+    flat so that its layout is not the compiler's to choose: as
+    ``[L, F, B, 3]`` the v5e's compiler kept it bins-minor in the split
+    loop, wanted the parent's slice features-minor for the subtraction,
+    and relaid the WHOLE pool on every split to get it (its program,
+    compiled here: a 1.56 GB copy a split at 255 x 2000 x 255).  A row of
+    a two-dimensional array can only be relaid as a row."""
+    return jnp.moveaxis(hist, -1, -3).reshape(hist.shape[:-3] + (-1,))
+
+
+def pool_hist(flat, n_cols: int, num_bins: int):
+    """One leaf's :func:`pool_flat` row -> its [F, B, 3] histogram."""
+    return jnp.moveaxis(flat.reshape(3, n_cols, num_bins), 0, -1)
 
 
 def pool_rows(res: SplitResult, axis: int):
@@ -687,7 +702,7 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
         fallback_method = fused_fallback_method()
         if use_fused:
             reason = fused_gate_reason(hbins.dtype, dtype, hist_width,
-                                       n_hist_cols, use_ordered)
+                                       use_ordered)
             if reason is not None:
                 log.warning("hist_method=fused unavailable (%s); using the "
                             "%s reference path", reason, fallback_method)
@@ -1017,8 +1032,8 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                                       feat_ok_all)
         res_root = _depth_gate(res_root, jnp.asarray(0), cfg.max_depth)
 
-        hist_store0 = jnp.zeros((L, fh, cfg.max_bin, 3), dtype)
-        hist_store0 = hist_store0.at[0].set(hist_root)
+        hist_store0 = jnp.zeros((L, 3 * fh * cfg.max_bin), dtype)
+        hist_store0 = hist_store0.at[0].set(pool_flat(hist_root))
         feat_ok_store0 = jnp.zeros((L, num_logical), bool).at[0].set(
             root_feat_ok)
 
@@ -1143,22 +1158,29 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                     hist_small = lax.switch(ki, branches,
                                             (order, obins, ow, sstart, scnt))
                 hist_small = globalize(hist_small)
-            hist_parent = lax.dynamic_index_in_dim(state.hist_store, l, axis=0,
-                                                   keepdims=False)
-            hist_large = hist_parent - hist_small
-            # everything downstream runs in (smaller, larger) order and is
-            # written back through the PERMUTED pair index — the former
-            # [F, B, 3]-wide hist_l/hist_r selects become two scalar-level
-            # index selects (same slots, same values, fewer wide ops).
-            # Both children still land in the store through ONE fused pair
-            # scatter: the round-7 discovery stands — a read-then-double-
-            # dynamic_update_slice chain on the carried pool made XLA:CPU
-            # clone all 22 MB of it twice per split (docs/PERF.md round 7;
-            # pinned by tests/test_grow_jaxpr.py).
-            hist2 = jnp.stack([hist_small, hist_large])
-            pair_sl = jnp.where(small_left, pair_lr, pair_lr[::-1])
-            hist_store = state.hist_store.at[pair_sl].set(
-                hist2, unique_indices=True, mode="promise_in_bounds")
+            # the pool's own work under one name: the parent's read, the
+            # subtraction and the pair write are [F, B, 3] each, which on a
+            # wide data set is most of a split outside the kernel
+            with jax.named_scope("hist_pool"):
+                hist_parent = pool_hist(
+                    lax.dynamic_index_in_dim(state.hist_store, l, axis=0,
+                                             keepdims=False),
+                    fh, cfg.max_bin)
+                hist_large = hist_parent - hist_small
+                # everything downstream runs in (smaller, larger) order and is
+                # written back through the PERMUTED pair index — the former
+                # [F, B, 3]-wide hist_l/hist_r selects become two scalar-level
+                # index selects (same slots, same values, fewer wide ops).
+                # Both children still land in the store through ONE fused pair
+                # scatter: the round-7 discovery stands — a read-then-double-
+                # dynamic_update_slice chain on the carried pool made XLA:CPU
+                # clone all 22 MB of it twice per split (docs/PERF.md round 7;
+                # pinned by tests/test_grow_jaxpr.py).
+                hist2 = jnp.stack([hist_small, hist_large])
+                pair_sl = jnp.where(small_left, pair_lr, pair_lr[::-1])
+                hist_store = state.hist_store.at[pair_sl].set(
+                    pool_flat(hist2), unique_indices=True,
+                    mode="promise_in_bounds")
 
             # children scan only the features the PARENT found splittable
             # (serial_tree_learner.cpp:406-417 pruning heuristic).  Both
@@ -1170,8 +1192,12 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
             lr3 = jnp.stack([lax.slice(frow, (0,), (3,)),
                              lax.slice(frow, (3,), (6,))])   # [2, 3]
             sl3 = jnp.where(small_left, lr3, lr3[::-1])
-            res2, fok2 = jax.vmap(find, in_axes=(0, 0, 0, 0, None))(
-                hist2, sl3[:, 0], sl3[:, 1], sl3[:, 2], fok_parent)
+            # the scope is entered OUTSIDE the vmap too: inside it alone
+            # the children's scan is named ``vmap(split_find)``, which a
+            # trace's scope pattern does not read as ``split_find``
+            with jax.named_scope("split_find"):
+                res2, fok2 = jax.vmap(find, in_axes=(0, 0, 0, 0, None))(
+                    hist2, sl3[:, 0], sl3[:, 1], sl3[:, 2], fok_parent)
             res2 = _depth_gate(res2, child_depth, cfg.max_depth)
             feat_ok = state.feat_ok.at[pair_sl].set(fok2 & fok_parent[None, :],
                                                     unique_indices=True)

@@ -193,7 +193,7 @@ def subset_histogram_fused(order: jnp.ndarray, panel: jnp.ndarray,
     gather pass — the kernel DMAs the indexed panel rows itself.
 
     order [NO] i32 (window at [start, start + cnt); see hist6_fused for
-    the tail-padding contract), panel [N + 1, W + 3] u32
+    the tail-padding contract), panel [tiles, N + 1, 128] u32
     (data/packing.py:pack_fused_panel) -> [n_cols, num_bins, 3] f32 with
     the reference (sum_grad, sum_hess, count) layout; gradients/hessians
     carry the bf16 hi/lo accuracy contract (counts exact)."""
@@ -201,7 +201,8 @@ def subset_histogram_fused(order: jnp.ndarray, panel: jnp.ndarray,
     # dispatch-identity evidence (trace-time, per call site): bench rungs
     # and decide_flips verify the label against this counter
     obs_counters.inc("hist_dispatch", method="fused", site=site,
-                     interpret=bool(interpret))
+                     interpret=bool(interpret),
+                     col_tiles=panel.shape[0])
     _maybe_inject_hist_fault("fused", site)
     h6 = hist6_fused(order, panel, start, cnt, n_cols, words_per, num_bins,
                      row_tile=row_tile, num_row_tiles=num_row_tiles,
@@ -228,7 +229,8 @@ def subset_histogram_fused_local(row_leaf: jnp.ndarray, leaf_id,
     # whole mesh, same as any other trace-time counter — observed_kernel()
     # and the census must still attribute the hybrid to the fused kernel
     obs_counters.inc("hist_dispatch", method="fused", site=site,
-                     interpret=bool(interpret))
+                     interpret=bool(interpret),
+                     col_tiles=panel.shape[0])
     _maybe_inject_hist_fault("fused", site)
     h6 = hist6_fused_local(row_leaf, leaf_id, panel, n_cols, words_per,
                            num_bins, row_tile=row_tile, interpret=interpret)

@@ -33,6 +33,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..data.packing import FUSED_COL_STEP, fused_col_tiles
+
 NUM_CH = 6   # weight channels: (g_hi, g_lo, h_hi, h_lo, c, unused)
 LANES = 128  # TPU vector register lane width — bin axis is padded to this
 NIB = 16     # nibble radix: bin = hi*16 + lo, each one-hot 16 wide
@@ -55,12 +57,23 @@ NIB = 16     # nibble radix: bin = hi*16 + lo, each one-hot 16 wide
 # cuts the MXU slot cost ~2x at B_pad = 256.  PERF.md projects the stack at
 # ~8.5 ns/row vs the measured 22 + 12.6.
 #
-# Three structural points:
+# Four structural points:
 #
-# * the input is the FUSED PANEL (data/packing.py:pack_fused_panel): packed
-#   bin words + the three bitcast f32 weight columns in one u32 row, so the
-#   per-row DMA is a single contiguous burst and the hi/lo bf16 weight
-#   split happens on-chip, per tile;
+# * the input is the FUSED PANEL (data/packing.py:pack_fused_panel): column
+#   tiles of 128 u32 words, each a row's packed bin words + the three
+#   bitcast f32 weight columns, so the per-row DMA is ONE descriptor of a
+#   512 B burst per column tile and the hi/lo bf16 weight split happens
+#   on-chip, per tile;
+# * the width is a LOOP, not an unroll: a column tile is walked in steps of
+#   32 columns (``fori_loop``; one step inline for a narrow data set), each
+#   step reading its 8 or 16 word rows of the transposed tile at a dynamic
+#   sublane offset and adding into its own [96, 512] slab of the output
+#   block, so neither the program nor its VMEM stack grows with the column
+#   count (unrolled, 256 columns already asked for 22.5 MB of the 16 MB a
+#   kernel gets, and 2000 would compile for minutes: v5e AOT probe, PR 27).
+#   Both one-hots are built [16, TR] and the dot contracts the row axis of
+#   both operands: a step that indexes columns dynamically has no static
+#   [TR, 1] lane slice to make a column-shaped lo one-hot from;
 # * the grid is 1-D over row tiles and may be DYNAMIC (a traced tile
 #   count): the grower passes ceil(cnt / row_tile), so a small leaf costs
 #   a small grid — this is what retires the gather-bucket ``lax.switch``
@@ -70,19 +83,21 @@ NIB = 16     # nibble radix: bin = hi*16 + lo, each one-hot 16 wide
 #   anywhere downstream.
 #
 # Mosaic surfaces kept deliberately boring (round-2/round-5 lessons): the
-# output block is written in static 128-lane groups (8 features x 16 lo
-# bins) via full-width concatenated stores — never a sub-lane-width partial
-# store — and every reshape happens outside the kernel in XLA.
+# output block is written in whole [96, 512] slabs of four 128-lane groups
+# (8 features x 16 lo bins each), indexed on their leading axis — never a
+# sub-lane-width partial store, never a dynamic lane offset — and every
+# reshape happens outside the kernel in XLA.
 # ---------------------------------------------------------------------------
 
 FUSED_GROUP = 8        # features per 128-lane output group (8 * NIB = 128)
-FUSED_MAX_COLS = 512   # feature-loop unroll + VMEM output-block ceiling
+STEP_LANES = FUSED_COL_STEP * NIB   # output lanes of one step: 4 groups
 IDX_ALIGN = 1024       # i32 1-D tile: dynamic slices of ``order`` must sit
 #                        on this boundary AND have a multiple-of-it length
 #                        (Mosaic "tile index divisible by tiling" / "slice
 #                        shape aligned to tile boundaries", both proven by
 #                        the v5e AOT probe), so the kernel over-fetches the
 #                        enclosing aligned region
+VMEM_DEFAULT = 16 << 20   # what a Mosaic kernel may use on the v5e unasked
 
 
 def fused_idx_fetch(row_tile: int) -> int:
@@ -92,10 +107,32 @@ def fused_idx_fetch(row_tile: int) -> int:
     return -(-(row_tile + IDX_ALIGN - 1) // IDX_ALIGN) * IDX_ALIGN
 
 
+def _bf16_round_f32(wf):
+    """f32 value of bf16(wf), without materializing a bf16 vector: integer
+    round-to-nearest-even on the raw bits."""
+    u = lax.bitcast_convert_type(wf, jnp.uint32)
+    r = (u + jnp.uint32(0x7fff) + ((u >> 16) & jnp.uint32(1))) \
+        & jnp.uint32(0xffff0000)
+    return lax.bitcast_convert_type(r, jnp.float32)
+
+
+def _loop(n: int, body):
+    """``body(i)`` for i in [0, n): inline where there is one trip (the
+    narrow data set's whole kernel), a ``fori_loop`` otherwise."""
+    if n == 1:
+        body(0)
+        return
+
+    def trip(i, carry):
+        body(i)
+        return carry
+    lax.fori_loop(0, n, trip, 0)
+
+
 def _hist_kernel_fused(sc_ref, order_ref, panel_ref, out_ref,
-                       idx_smem, rows_vmem, idx_sem, row_sem, *,
-                       sentinel: int, n_words: int, words_per: int,
-                       n_cols_pad: int, row_tile: int):
+                       idx_smem, rows_vmem, words_vmem, idx_sem, row_sem, *,
+                       sentinel: int, tile_words: int, words_per: int,
+                       tile_steps: int, col_tiles: int, row_tile: int):
     ri = pl.program_id(0)
 
     @pl.when(ri == 0)
@@ -123,17 +160,18 @@ def _hist_kernel_fused(sc_ref, order_ref, panel_ref, out_ref,
     def _row_copy(i):
         # positions past the leaf's count read the sentinel row (zero
         # words, zero weights) — same contract as the gen-1 sentinel pad.
-        # pl.ds(r, 1) keeps the slice 2-D: integer .at[r] indexing squeezes
-        # the row dim and that squeeze is what the LLO lowering choked on
+        # pl.ds(r, 1) keeps the slice's row dim: integer .at[r] indexing
+        # squeezes it and that squeeze is what the LLO lowering choked on
         # ("dynamic_dim_it != dynamic_sizes.end()", v5e AOT probe) — the
-        # compact kernel's proven dynamic-offset DMAs are all pl.ds-shaped
+        # compact kernel's proven dynamic-offset DMAs are all pl.ds-shaped.
+        # One descriptor a row: its 512 B from every column tile.
         r = jnp.where(base + i < cnt, idx_smem[off + i], sentinel)
-        return pltpu.make_async_copy(panel_ref.at[pl.ds(r, 1), :],
-                                     rows_vmem.at[pl.ds(i, 1), :],
+        return pltpu.make_async_copy(panel_ref.at[:, pl.ds(r, 1), :],
+                                     rows_vmem.at[:, pl.ds(i, 1), :],
                                      row_sem)
 
     # start every row DMA, then drain: the copies are independent and tiny
-    # (W+3 u32 words each), so queueing them all before the first wait is
+    # (512 B a column tile), so queueing them all before the first wait is
     # what lets the DMA engines overlap them
     def _start(i, _):
         _row_copy(i).start()
@@ -145,77 +183,81 @@ def _hist_kernel_fused(sc_ref, order_ref, panel_ref, out_ref,
         return 0
     lax.fori_loop(0, row_tile, _wait, 0)
 
-    # word rows on the sublane axis (same orientation trick as the gen-1
-    # kernels' [F, N] layout): static sublane indexing below, no dynamic
-    # lane slicing for Mosaic to reject.  The untransposed form stays live
-    # too: the lo one-hot needs COLUMN-shaped bins, and Mosaic rejects the
-    # [TR] -> [TR, 1] shape cast from a sublane-layout vector (v5e AOT
-    # probe) — a static [TR, 1] lane slice of the row-major value is
-    # column-shaped from birth.
-    rows2d = rows_vmem[...]                          # [TR, n_words + 3] u32
-    words_t = rows2d.T                               # [n_words + 3, TR] u32
+    tr = row_tile
     shift = 32 // words_per
     wmask = jnp.uint32((1 << shift) - 1)
+    step_words = FUSED_COL_STEP // words_per
+    nib_iota = lax.broadcasted_iota(jnp.int32, (NIB, tr), 0)
 
-    # on-chip hi/lo weight split (the _split_hi_lo contract): channels
-    # (g_hi, g_lo, h_hi, h_lo, c, 0), the retired gen-1 kernels' layout.
-    # NO bf16 values exist below full-tile width: Mosaic rejected both the
-    # gen-1 nibble form's [6, 1, TR] broadcast-multiply (vector.shape_cast)
-    # and a [1, TR] bf16 sublane broadcast (vector.broadcast) — bf16's
-    # packed (16, 128) tiling makes narrow bf16 vectors a hostile surface
-    # (both caught by the v5e AOT probe).  So the hi half is computed IN
-    # f32 via integer round-to-nearest-even on the raw bits (bit-identical
-    # to an f32->bf16->f32 round-trip), everything stays f32 through the
-    # broadcasts, and the one cast to bf16 happens on the full [96, TR]
-    # tile right before the MXU.
-    def _bf16_round_f32(wf):
-        """f32 value of bf16(wf), without materializing a bf16 vector."""
-        u = lax.bitcast_convert_type(wf, jnp.uint32)
-        r = (u + jnp.uint32(0x7fff) + ((u >> 16) & jnp.uint32(1))) \
-            & jnp.uint32(0xffff0000)
-        return lax.bitcast_convert_type(r, jnp.float32)
+    def _tile(t):
+        # word rows on the sublane axis (same orientation trick as the
+        # gen-1 kernels' [F, N] layout), parked in VMEM so that a step
+        # reads ITS words as whole sublanes at a dynamic, 8-aligned
+        # offset: no dynamic lane slicing for Mosaic to reject
+        words_vmem[...] = rows_vmem[t].T             # [128, TR] u32
 
-    chans32 = []
-    for k in range(2):
-        wf = lax.bitcast_convert_type(words_t[n_words + k], jnp.float32)
-        w_hi = _bf16_round_f32(wf)
-        chans32 += [w_hi, wf - w_hi]
-    chans32.append(lax.bitcast_convert_type(words_t[n_words + 2],
-                                            jnp.float32))
-    chans32.append(jnp.zeros_like(chans32[-1]))
+        # on-chip hi/lo weight split (the _split_hi_lo contract): channels
+        # (g_hi, g_lo, h_hi, h_lo, c, 0), the retired gen-1 kernels'
+        # layout.  NO bf16 values exist below full-tile width: Mosaic
+        # rejected both the gen-1 nibble form's [6, 1, TR]
+        # broadcast-multiply (vector.shape_cast) and a [1, TR] bf16
+        # sublane broadcast (vector.broadcast) — bf16's packed (16, 128)
+        # tiling makes narrow bf16 vectors a hostile surface (both caught
+        # by the v5e AOT probe).  So the hi half is computed IN f32 via
+        # integer round-to-nearest-even on the raw bits (bit-identical to
+        # an f32->bf16->f32 round-trip), everything stays f32 through the
+        # broadcasts, and the one cast to bf16 happens on the full
+        # [96, TR] tile right before the MXU.
+        chans32 = []
+        for k in range(2):
+            wf = lax.bitcast_convert_type(words_vmem[tile_words + k],
+                                          jnp.float32)
+            w_hi = _bf16_round_f32(wf)
+            chans32 += [w_hi, wf - w_hi]
+        chans32.append(lax.bitcast_convert_type(words_vmem[tile_words + 2],
+                                                jnp.float32))
+        chans32.append(jnp.zeros_like(chans32[-1]))
+        # U's weight factor, feature-independent, built once per row and
+        # column tile — strictly 2-D f32: each channel row broadcast to
+        # its 16-row band
+        w_rep = jnp.concatenate(
+            [jnp.broadcast_to(ch[None, :], (NIB, tr)) for ch in chans32],
+            axis=0)                                  # [96, TR] f32
 
-    tr = row_tile
-    # U's weight factor, feature-independent, built once per row tile —
-    # strictly 2-D f32: each channel row broadcast to its 16-row band
-    w_rep = jnp.concatenate(
-        [jnp.broadcast_to(ch[None, :], (NIB, tr)) for ch in chans32],
-        axis=0)                                      # [96, TR] f32
-    for g0 in range(0, n_cols_pad, FUSED_GROUP):
-        blocks = []
-        for k in range(FUSED_GROUP):
-            c = g0 + k
-            w_i = c // words_per
-            sh = (c % words_per) * shift
-            binc = ((words_t[w_i] >> sh) & wmask).astype(jnp.int32)
-            hi = binc >> 4                           # [TR], < 16
-            oh_hi = (hi[None, :] ==
-                     lax.broadcasted_iota(jnp.int32, (NIB, tr), 0)
-                     ).astype(jnp.float32)           # [16, TR]
-            # masked weights in f32, ONE full-tile bf16 cast before the
-            # dot (oh is 0/1, so bf16(w * oh) == bf16(w) * oh exactly)
-            u = (w_rep * jnp.concatenate([oh_hi] * NUM_CH, axis=0)
-                 ).astype(jnp.bfloat16)              # [96, TR]
-            lo_col = ((rows2d[:, w_i:w_i + 1] >> sh)
-                      & wmask).astype(jnp.int32) & 15  # [TR, 1]
-            oh_lo = (lo_col ==
-                     lax.broadcasted_iota(jnp.int32, (tr, NIB), 1)
-                     ).astype(jnp.bfloat16)          # [TR, 16]
-            blocks.append(jnp.dot(u, oh_lo,
-                                  preferred_element_type=jnp.float32))
-        # one concatenated 128-lane-aligned store per feature group — the
-        # masked sub-lane partial stores Mosaic has mislowered never happen
-        out_ref[:, g0 * NIB:(g0 + FUSED_GROUP) * NIB] += jnp.concatenate(
-            blocks, axis=1)                          # [96, 128]
+        def _step(s):
+            w0 = s * step_words
+            if not isinstance(w0, int):
+                w0 = pl.multiple_of(w0, step_words)
+            words = words_vmem[pl.ds(w0, step_words), :]
+            groups = []
+            for g0 in range(0, FUSED_COL_STEP, FUSED_GROUP):
+                blocks = []
+                for c in range(g0, g0 + FUSED_GROUP):
+                    sh = (c % words_per) * shift
+                    binc = ((words[c // words_per] >> sh)
+                            & wmask).astype(jnp.int32)   # [TR]
+                    oh_hi = ((binc >> 4)[None, :] == nib_iota
+                             ).astype(jnp.float32)       # [16, TR]
+                    # masked weights in f32, ONE full-tile bf16 cast before
+                    # the dot (oh is 0/1, so bf16(w * oh) == bf16(w) * oh
+                    # exactly)
+                    u = (w_rep * jnp.concatenate([oh_hi] * NUM_CH, axis=0)
+                         ).astype(jnp.bfloat16)          # [96, TR]
+                    # the lo one-hot in the same [16, TR] orientation: the
+                    # dot contracts the row axis of both operands
+                    oh_lo = ((binc & 15)[None, :] == nib_iota
+                             ).astype(jnp.bfloat16)      # [16, TR]
+                    blocks.append(lax.dot_general(
+                        u, oh_lo, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32))
+                # one concatenated 128-lane group — the masked sub-lane
+                # partial stores Mosaic has mislowered never happen
+                groups.append(jnp.concatenate(blocks, axis=1))   # [96, 128]
+            out_ref[t * tile_steps + s] += jnp.concatenate(groups, axis=1)
+
+        _loop(tile_steps, _step)
+
+    _loop(col_tiles, _tile)
 
 
 def hist6_fused(order: jnp.ndarray, panel: jnp.ndarray, start, cnt,
@@ -223,7 +265,7 @@ def hist6_fused(order: jnp.ndarray, panel: jnp.ndarray, start, cnt,
                 row_tile: int = 512, num_row_tiles=None,
                 interpret: bool = False) -> jnp.ndarray:
     """Fused-gather nibble histogram: order [NO] i32 row ids (the leaf's
-    window lives at [start, start + cnt)), panel [N + 1, n_words + 3] u32
+    window lives at [start, start + cnt)), panel [tiles, N + 1, 128] u32
     (pack_fused_panel layout, last row = sentinel) -> [6, n_cols, num_bins]
     f32.
 
@@ -236,48 +278,56 @@ def hist6_fused(order: jnp.ndarray, panel: jnp.ndarray, start, cnt,
     ``order`` with sentinel tail accordingly).
     """
     assert 1 < num_bins <= NIB * NIB, num_bins
-    assert n_cols <= FUSED_MAX_COLS, (n_cols, FUSED_MAX_COLS)
     assert order.shape[0] >= fused_idx_fetch(row_tile), order.shape
-    n_cols_pad = -(-n_cols // FUSED_GROUP) * FUSED_GROUP
-    # the panel's word region covers exactly the group-padded columns
-    # (pack_fused_panel layout); everything beyond words + 3 weight
-    # columns is DMA-alignment padding, never read
-    n_words = n_cols_pad // words_per
-    assert panel.shape[1] >= n_words + 3, (panel.shape, n_words)
-    sentinel = panel.shape[0] - 1
+    col_tiles, tile_cols = fused_col_tiles(n_cols, words_per)
+    assert panel.shape[0] == col_tiles, (panel.shape, col_tiles)
+    tile_steps = tile_cols // FUSED_COL_STEP
+    sentinel = panel.shape[1] - 1
     if num_row_tiles is None:
         num_row_tiles = 1
     sc = jnp.stack([jnp.asarray(start, jnp.int32),
                     jnp.asarray(cnt, jnp.int32)])
-    out2d = pl.pallas_call(
+    out_shape = (col_tiles * tile_steps, NUM_CH * NIB, STEP_LANES)
+    # the output block stays in VMEM over the row grid (twice: Pallas
+    # double-buffers it) beside the row tile's panel rows; past the
+    # compiler's default the kernel asks for what it holds and as much
+    # again as the default for the values of a step
+    held = (2 * 4 * out_shape[0] * out_shape[1] * out_shape[2]
+            + (col_tiles + 1) * row_tile * LANES * 4)
+    vmem_limit = (held + VMEM_DEFAULT
+                  if held > VMEM_DEFAULT // 2 else None)
+    out3d = pl.pallas_call(
         functools.partial(_hist_kernel_fused, sentinel=sentinel,
-                          n_words=n_words, words_per=words_per,
-                          n_cols_pad=n_cols_pad, row_tile=row_tile),
+                          tile_words=tile_cols // words_per,
+                          words_per=words_per, tile_steps=tile_steps,
+                          col_tiles=col_tiles, row_tile=row_tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(num_row_tiles,),
             in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((NUM_CH * NIB, n_cols_pad * NIB),
-                                   lambda ri, sc: (0, 0)),
+            out_specs=pl.BlockSpec(out_shape, lambda ri, sc: (0, 0, 0)),
             scratch_shapes=[pltpu.SMEM((fused_idx_fetch(row_tile),),
                                        jnp.int32),
-                            pltpu.VMEM((row_tile, panel.shape[1]),
+                            pltpu.VMEM((col_tiles, row_tile, LANES),
                                        jnp.uint32),
+                            pltpu.VMEM((LANES, row_tile), jnp.uint32),
                             pltpu.SemaphoreType.DMA,
                             pltpu.SemaphoreType.DMA],
         ),
-        out_shape=jax.ShapeDtypeStruct((NUM_CH * NIB, n_cols_pad * NIB),
-                                       jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit),
     )(sc, order, panel)
-    # [(ch, hi), (f, lo)] -> [ch, f, hi*16+lo], all in XLA (the same
-    # epilogue the retired gen-1 nibble form used)
-    out4 = out2d.reshape(NUM_CH, NIB, n_cols_pad, NIB)
-    return out4.transpose(0, 2, 1, 3).reshape(
-        NUM_CH, n_cols_pad, NIB * NIB)[:, :n_cols, :num_bins]
+    # [step, (ch, hi), (f, lo)] -> [ch, step * f, hi*16+lo], all in XLA (the
+    # epilogue the retired gen-1 nibble form used, a step at a time); the
+    # phantom columns of the even spread drop out here
+    out5 = out3d.reshape(col_tiles * tile_steps, NUM_CH, NIB,
+                         FUSED_COL_STEP, NIB)
+    return out5.transpose(1, 0, 3, 2, 4).reshape(
+        NUM_CH, col_tiles * tile_cols, NIB * NIB)[:, :n_cols, :num_bins]
 
 
 def hist6_fused_local(row_leaf: jnp.ndarray, leaf_id, panel: jnp.ndarray,

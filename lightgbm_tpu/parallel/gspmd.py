@@ -193,7 +193,7 @@ def make_gspmd_grower(cfg: GrowerConfig, mesh: Mesh,
                     pack_island,
                     in_specs=(P(BATCH_AXIS, panel_fspec), P(BATCH_AXIS),
                               P(BATCH_AXIS), P(BATCH_AXIS)),
-                    out_specs=P(BATCH_AXIS, panel_fspec),
+                    out_specs=P(None, BATCH_AXIS, panel_fspec),
                 )(hist_src, gw, hw, cw)
 
         def measure(row_leaf_cur, leaf_id, g_, h_, c_, site):
@@ -221,8 +221,8 @@ def make_gspmd_grower(cfg: GrowerConfig, mesh: Mesh,
 
                 part = smap(
                     hist_island,
-                    in_specs=(P(BATCH_AXIS, panel_fspec), P(BATCH_AXIS),
-                              P()),
+                    in_specs=(P(None, BATCH_AXIS, panel_fspec),
+                              P(BATCH_AXIS), P()),
                     out_specs=P(BATCH_AXIS, panel_fspec, None, None),
                 )(panel, row_leaf_cur, jnp.asarray(leaf_id, jnp.int32))
                 hist = jnp.sum(part, axis=0)

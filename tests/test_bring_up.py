@@ -79,32 +79,25 @@ def test_cache_helper_defers_to_the_environment(monkeypatch):
         jax.config.update("jax_compilation_cache_dir", before)
 
 
-def test_unfusable_layout_resolves_to_a_method_that_exists(monkeypatch):
-    """600 histogram columns exceed the fused kernel's ceiling: the
-    downgrade must name a method ``subset_histogram`` accepts (it used to
-    name the deleted gen-1 kernel) and the training must run on it."""
+def test_wide_layout_resolves_to_the_fused_kernel(monkeypatch):
+    """600 histogram columns are two column tiles of the fused kernel, not
+    a reason to leave it: a fused request stays fused (interpret mode
+    here), no ``layout_downgrade`` fires, and the training runs on it."""
     import lightgbm_tpu as lgb
-    from lightgbm_tpu import boosting, grower
     from lightgbm_tpu.obs.counters import counters
-    from lightgbm_tpu.ops.pallas_hist import FUSED_MAX_COLS
-    monkeypatch.setattr(boosting, "on_tpu", lambda: True)
-    monkeypatch.setattr(grower, "on_tpu", lambda: True)
     counters.reset()
     rng = np.random.RandomState(0)
     X = rng.randn(300, 600)
-    assert X.shape[1] > FUSED_MAX_COLS
     y = (X[:, 0] + X[:, 1] > 0).astype(np.float64)
     bst = lgb.train({"objective": "binary", "num_leaves": 4, "verbose": -1,
-                     "min_data_in_leaf": 5, "enable_bin_packing": False},
+                     "min_data_in_leaf": 5, "enable_bin_packing": False,
+                     "cpu_hist_method": "fused"},
                     lgb.Dataset(X, label=y), num_boost_round=2,
                     verbose_eval=False)
-    resolved = bst.inner.grower_cfg.hist_method
-    assert resolved == "einsum"          # the TPU answer of the shared gate
-    events = [e for e in counters.events("layout_downgrade")
-              if e.get("requested") == "fused"]
-    assert events and events[0]["resolved"] == resolved, events
+    assert bst.inner.grower_cfg.hist_method == "fused"
+    assert not counters.events("layout_downgrade")
     assert set(counters.get("hist_dispatch")) == {
-        f"interpret=False,method={resolved},site={s}"
+        f"col_tiles=2,interpret=True,method=fused,site={s}"
         for s in ("root", "split")}
     assert bst.inner.models[0].num_leaves > 1
 

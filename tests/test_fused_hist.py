@@ -98,6 +98,32 @@ def test_fused_bit_identical_integer_weights():
     np.testing.assert_array_equal(out, ref)
 
 
+@pytest.mark.parametrize("f,tiles", [(600, 2), (1100, 3)])
+def test_fused_wide_bit_identical_integer_weights(f, tiles):
+    """Past one column tile: 600 columns cross one tile boundary (two
+    tiles of 320, 40 phantom columns), 1100 are not a multiple of their
+    tile (three of 384).  Same pin as at 28 columns: bit-identical to
+    the segment oracle on bf16-exact weights, over an unaligned window
+    whose last row tile runs into the sentinel."""
+    from lightgbm_tpu.data.packing import fused_col_tiles
+    assert fused_col_tiles(f, 4)[0] == tiles
+    n, b = 1536, 255
+    bins, g, h, c = _problem(n, f, b, seed=f, integer_weights=True)
+    panel, per = _fused_inputs(bins, g, h, c)
+    assert panel.shape == (tiles, n + 1, 128)
+    perm = np.random.RandomState(3).permutation(n).astype(np.int32)
+    order = _order_with_tail(perm, n)
+    start, cnt = 301, 700
+    sel = perm[start:start + cnt]
+    ref = np.asarray(subset_histogram_segment(
+        jnp.asarray(bins[sel]), jnp.asarray(g[sel]), jnp.asarray(h[sel]),
+        jnp.asarray(c[sel]), b))
+    out = np.asarray(subset_histogram_fused(
+        order, panel, start, cnt, f, per, b, row_tile=ROW_TILE,
+        num_row_tiles=-(-cnt // ROW_TILE), interpret=True))
+    np.testing.assert_array_equal(out, ref)
+
+
 def test_fused_dynamic_grid_matches_static():
     """The grower's dynamic-grid form (traced tile count) must equal the
     static grid bin for bin."""
@@ -185,6 +211,22 @@ def test_grower_fused_tree_identical_to_segment():
     np.testing.assert_array_equal(rl_seg, rl_fus)
     np.testing.assert_allclose(t_seg.leaf_value, t_fus.leaf_value,
                                rtol=2e-4, atol=2e-4)
+
+
+def test_grower_wide_tree_identical_across_rungs():
+    """A 31-leaf tree at 600 columns (two column tiles) is the same tree
+    on the fused and the segment rung: bf16-exact integer weights, so
+    byte for byte."""
+    n, f, b = 1500, 600, 63
+    bins, g, h, c = _problem(n, f, b, seed=41, integer_weights=True)
+    kw = dict(num_leaves=31, min_data_in_leaf=1)
+    t_seg, rl_seg = _grow_tree_strings("segment", bins, g, h, c, b, **kw)
+    t_fus, rl_fus = _grow_tree_strings("fused", bins, g, h, c, b, **kw)
+    assert int(t_seg.num_leaves) == 31
+    np.testing.assert_array_equal(t_seg.split_feature, t_fus.split_feature)
+    np.testing.assert_array_equal(t_seg.threshold_bin, t_fus.threshold_bin)
+    np.testing.assert_array_equal(rl_seg, rl_fus)
+    np.testing.assert_array_equal(t_seg.leaf_value, t_fus.leaf_value)
 
 
 def test_grower_fused_packed_storage():

@@ -53,6 +53,7 @@ def v5e():
 
 @pytest.mark.parametrize("dyn_grid,num_bins,f", [
     (False, 255, 28), (True, 255, 28), (True, 63, 28), (True, 256, 12),
+    (True, 255, 2000), (True, 255, 513),
 ])
 def test_fused_hist_kernel_lowers(v5e, dyn_grid, num_bins, f):
     """The fused-gather kernel Mosaic-compiles for v5e: in-kernel
@@ -64,21 +65,25 @@ def test_fused_hist_kernel_lowers(v5e, dyn_grid, num_bins, f):
     slices, an LLO compiler crash on integer-indexed (dim-squeezing)
     DMAs, and narrow-bf16 shape-cast/broadcast rejections."""
     import jax.numpy as jnp
+    from lightgbm_tpu.data.packing import fused_col_tiles
     from lightgbm_tpu.ops.histogram import subset_histogram_fused
     from lightgbm_tpu.ops.pallas_hist import fused_idx_fetch
     n, tr = 1 << 16, 512
-    pw = 128        # pack_fused_panel pads the row to a 128-lane multiple
+    # pack_fused_panel's layout: column tiles of 128 words (513 columns are
+    # two, 2000 five: the kernel walks them in steps of 32 columns, so its
+    # program does not grow with the width)
+    panel = (fused_col_tiles(f, 4)[0], n + 1, 128)
     no = n + fused_idx_fetch(tr)
     if dyn_grid:
         fn = jax.jit(lambda o, p, s, c, nt: subset_histogram_fused(
             o, p, s, c, f, 4, num_bins, row_tile=tr, num_row_tiles=nt))
-        fn.lower(v5e((no,), jnp.int32), v5e((n + 1, pw), jnp.uint32),
+        fn.lower(v5e((no,), jnp.int32), v5e(panel, jnp.uint32),
                  v5e((), jnp.int32), v5e((), jnp.int32),
                  v5e((), jnp.int32)).compile()
     else:
         fn = jax.jit(lambda o, p, s, c: subset_histogram_fused(
             o, p, s, c, f, 4, num_bins, row_tile=tr, num_row_tiles=16))
-        fn.lower(v5e((no,), jnp.int32), v5e((n + 1, pw), jnp.uint32),
+        fn.lower(v5e((no,), jnp.int32), v5e(panel, jnp.uint32),
                  v5e((), jnp.int32), v5e((), jnp.int32)).compile()
 
 
@@ -151,18 +156,16 @@ def test_full_grower_lowers(v5e, knobs):
 
 @FULL_GROWER_PROOFS
 def test_full_grower_lowers_wide(v5e):
-    """Epsilon-wide (F=2000) grower Mosaic-compiles — the capture's wide
-    coverage stage cannot be lost to a lowering surprise (measured ~96 s
-    to compile on the 1-core host; budget the in-window remote compile
-    accordingly).  F=2000 exceeds the fused kernel's column ceiling, so
-    the TPU ladder lands on the einsum reference — compile exactly that
-    program."""
+    """Epsilon-wide (F=2000) grower Mosaic-compiles on the FUSED rung: five
+    column tiles through the same kernel as the 28-column data set, the
+    per-leaf pool carried as [255, 3 * 2000 * 255] rows (about 140 s here
+    on one core at 400,000 rows, PR 27)."""
     import jax.numpy as jnp
     from lightgbm_tpu.grower import FeatureMeta, GrowerConfig, make_grower
     n, f = 1 << 17, 2000
     cfg = GrowerConfig(num_leaves=255, min_data_in_leaf=1,
                        min_sum_hessian_in_leaf=100.0, max_bin=255,
-                       hist_method="einsum", gather_words="on")
+                       hist_method="fused")
     meta = FeatureMeta(
         num_bin=v5e((f,), jnp.int32), missing_type=v5e((f,), jnp.int32),
         default_bin=v5e((f,), jnp.int32),
