@@ -94,10 +94,12 @@ def fused_col_tiles(n_cols: int, per: int):
     return tiles, -(-even // FUSED_COL_STEP) * FUSED_COL_STEP
 
 
-def pack_fused_panel(bins_pad, gw_pad, hw_pad, cw_pad):
+def pack_fused_panel(bins_pad, gw_pad, hw_pad, cw_pad, row_multiple: int = 1):
     """The u32 row layout the fused histogram kernel DMAs per row:
     [N(+1), C] uint8/uint16 bins + three f32 weight columns ->
-    ([tiles, N(+1), 128] uint32, lanes_per_word).
+    ([tiles, R, 128] uint32, lanes_per_word), R = N(+1) rounded up to
+    ``row_multiple`` with more sentinel (all-zero) rows: the kernel's
+    block fetch reads whole row tiles, the last one past N.
 
     The columns are zero-padded to ``tiles * tile_cols``
     (:func:`fused_col_tiles`) BEFORE word packing, so the kernel's phantom
@@ -126,9 +128,10 @@ def pack_fused_panel(bins_pad, gw_pad, hw_pad, cw_pad):
     # into one pass over the rows (built as one [N, tiles, 128]
     # concatenate it kept four 16-times padded byte planes of the bins
     # alive: 20 GB at 10.5M rows)
+    pad_rows = -words.shape[0] % row_multiple
     panel = [jnp.pad(jnp.concatenate(
         [words[:, t * tile_words:(t + 1) * tile_words]] + weights, axis=1),
-        ((0, 0), (0, FUSED_PANEL_LANES - tile_words - 3)))
+        ((0, pad_rows), (0, FUSED_PANEL_LANES - tile_words - 3)))
         for t in range(tiles)]
     return jnp.stack(panel), per
 

@@ -717,8 +717,10 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
             # the fused panel subsumes the word/panel gather staging —
             # nothing is gathered outside the kernel on this path
             use_words, use_panel = "off", False
+            # rows padded to whole row tiles: the root fetches blocks
             fused_panel, fused_per = pack_fused_panel(
-                hbins_pad, gw_pad, hw_pad, cw_pad)
+                hbins_pad, gw_pad, hw_pad, cw_pad,
+                row_multiple=cfg.row_tile)
         if use_words == "on":
             hwords_pad, words_per = pack_gather_words(hbins_pad)
             if use_panel:
@@ -1017,13 +1019,15 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
         with jax.named_scope("histogram"):
             if use_fused:
                 # the fused rung is SELF-CONTAINED: the root histogram goes
-                # through the fused kernel too (static grid over the
-                # identity prefix of order0) — it is the one
-                # lowering-proven Pallas path (see test_mosaic_aot)
+                # through the fused kernel too — it is the one
+                # lowering-proven Pallas path (see test_mosaic_aot) — on a
+                # static grid, and ``contiguous``: its window is the
+                # ``arange`` that order0 was built from just above, so the
+                # kernel fetches whole blocks of panel rows
                 hist_root = globalize(subset_histogram_fused(
                     order0, fused_panel, 0, n, n_hist_cols, fused_per,
                     hist_width, row_tile=cfg.row_tile,
-                    num_row_tiles=-(-n // cfg.row_tile),
+                    num_row_tiles=-(-n // cfg.row_tile), contiguous=True,
                     interpret=cfg.hist_interpret, site="root"))
             else:
                 hist_root = globalize(hist_subset(hbins, gw, hw, cw,

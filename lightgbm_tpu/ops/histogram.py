@@ -187,13 +187,16 @@ def subset_histogram_fused(order: jnp.ndarray, panel: jnp.ndarray,
                            start, cnt, n_cols: int, words_per: int,
                            num_bins: int, row_tile: int = 512,
                            num_row_tiles=None,
+                           contiguous: bool = False,
                            interpret: bool = False,
                            site: str = "split") -> jnp.ndarray:
     """Fused rung: histogram a leaf's ``order`` window WITHOUT a separate
-    gather pass — the kernel DMAs the indexed panel rows itself.
+    gather pass — the kernel DMAs the indexed panel rows itself, or whole
+    blocks of them where the caller built the window as the identity
+    (``contiguous``; see hist6_fused for its contract).
 
     order [NO] i32 (window at [start, start + cnt); see hist6_fused for
-    the tail-padding contract), panel [tiles, N + 1, 128] u32
+    the tail-padding contract), panel [tiles, R, 128] u32
     (data/packing.py:pack_fused_panel) -> [n_cols, num_bins, 3] f32 with
     the reference (sum_grad, sum_hess, count) layout; gradients/hessians
     carry the bf16 hi/lo accuracy contract (counts exact)."""
@@ -202,11 +205,12 @@ def subset_histogram_fused(order: jnp.ndarray, panel: jnp.ndarray,
     # and decide_flips verify the label against this counter
     obs_counters.inc("hist_dispatch", method="fused", site=site,
                      interpret=bool(interpret),
-                     col_tiles=panel.shape[0])
+                     col_tiles=panel.shape[0],
+                     fetch="block" if contiguous else "rows")
     _maybe_inject_hist_fault("fused", site)
     h6 = hist6_fused(order, panel, start, cnt, n_cols, words_per, num_bins,
                      row_tile=row_tile, num_row_tiles=num_row_tiles,
-                     interpret=interpret)
+                     contiguous=contiguous, interpret=interpret)
     return jnp.stack([h6[0] + h6[1], h6[2] + h6[3], h6[4]], axis=-1)
 
 
@@ -230,7 +234,7 @@ def subset_histogram_fused_local(row_leaf: jnp.ndarray, leaf_id,
     # and the census must still attribute the hybrid to the fused kernel
     obs_counters.inc("hist_dispatch", method="fused", site=site,
                      interpret=bool(interpret),
-                     col_tiles=panel.shape[0])
+                     col_tiles=panel.shape[0], fetch="rows")
     _maybe_inject_hist_fault("fused", site)
     h6 = hist6_fused_local(row_leaf, leaf_id, panel, n_cols, words_per,
                            num_bins, row_tile=row_tile, interpret=interpret)

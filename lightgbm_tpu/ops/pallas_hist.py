@@ -54,8 +54,26 @@ NIB = 16     # nibble radix: bin = hi*16 + lo, each one-hot 16 wide
 # VMEM, so the gathered [M, F] matrix never exists in HBM and the separate
 # gather dispatch disappears — and the contraction is the nibble-factorized
 # form (bin = hi*16 + lo, M = ch x hi = 96 rows, 16-wide lo one-hot) that
-# cuts the MXU slot cost ~2x at B_pad = 256.  PERF.md projects the stack at
-# ~8.5 ns/row vs the measured 22 + 12.6.
+# cuts the MXU slot cost ~2x at B_pad = 256.
+#
+# What the v5e read (scripts/probe_hist_fetch.py, PR 28; PERF.md section
+# 5): a row of 28 columns is 4.9 ns of arithmetic, and before PR 28 it was
+# 24.2 ns of issuing its descriptor and 6.3 of waiting for it, one row
+# after another, 35.7 in all.  The scalar core's issue is what binds: a
+# row's 512 B are in VMEM long before the next descriptor is built.  So
+# the fetch of a row tile is
+#
+#   * ONE block copy where the caller built the window as the identity
+#     (``contiguous``: the root, whose ``order`` is an ``arange``): no
+#     index, no SMEM, no per-row loop — 4.9 ns a row, the arithmetic's;
+#   * otherwise one descriptor a row, issued 32 to a trip of the loop
+#     (a trip cost as much as a descriptor: 8.6 ns a row at one a trip)
+#     and waited for ONCE (a DMA semaphore counts bytes): 19.3 ns a row;
+#   * either way into one of TWO slots, tile i + 1 started before tile i
+#     is waited for.  That hides the block copy behind the arithmetic
+#     whole; on indexed rows it reads 0.3 ns at 28 columns and 0.7-1.4
+#     at 2000, and is kept because the block form needs the slots anyway
+#     and one schedule is less code than two.
 #
 # Four structural points:
 #
@@ -98,6 +116,8 @@ IDX_ALIGN = 1024       # i32 1-D tile: dynamic slices of ``order`` must sit
 #                        the v5e AOT probe), so the kernel over-fetches the
 #                        enclosing aligned region
 VMEM_DEFAULT = 16 << 20   # what a Mosaic kernel may use on the v5e unasked
+ISSUE_UNROLL = 32      # row copies issued per trip of the issue loop (probe,
+#                        PR 28: 20.6 ns a row at 8, 19.8 at 16, 19.5 at 32)
 
 
 def fused_idx_fetch(row_tile: int) -> int:
@@ -129,60 +149,12 @@ def _loop(n: int, body):
     lax.fori_loop(0, n, trip, 0)
 
 
-def _hist_kernel_fused(sc_ref, order_ref, panel_ref, out_ref,
-                       idx_smem, rows_vmem, words_vmem, idx_sem, row_sem, *,
-                       sentinel: int, tile_words: int, words_per: int,
-                       tile_steps: int, col_tiles: int, row_tile: int):
-    ri = pl.program_id(0)
-
-    @pl.when(ri == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    start = sc_ref[0]
-    cnt = sc_ref[1]
-    # the tile's slice of the leaf's ``order`` window, HBM -> SMEM: these
-    # are the row ids the per-row DMAs below need as scalars.  The window
-    # position is arbitrary but the source slice must be IDX_ALIGN-aligned,
-    # so fetch the enclosing aligned region and read at the residual
-    # offset — 3x the index bytes, which is noise next to the panel rows.
-    pos = start + ri * row_tile
-    aligned = pl.multiple_of((pos // IDX_ALIGN) * IDX_ALIGN, IDX_ALIGN)
-    off = pos - aligned
-    idx_copy = pltpu.make_async_copy(
-        order_ref.at[pl.ds(aligned, fused_idx_fetch(row_tile))], idx_smem,
-        idx_sem)
-    idx_copy.start()
-    idx_copy.wait()
-
-    base = ri * row_tile
-
-    def _row_copy(i):
-        # positions past the leaf's count read the sentinel row (zero
-        # words, zero weights) — same contract as the gen-1 sentinel pad.
-        # pl.ds(r, 1) keeps the slice's row dim: integer .at[r] indexing
-        # squeezes it and that squeeze is what the LLO lowering choked on
-        # ("dynamic_dim_it != dynamic_sizes.end()", v5e AOT probe) — the
-        # compact kernel's proven dynamic-offset DMAs are all pl.ds-shaped.
-        # One descriptor a row: its 512 B from every column tile.
-        r = jnp.where(base + i < cnt, idx_smem[off + i], sentinel)
-        return pltpu.make_async_copy(panel_ref.at[:, pl.ds(r, 1), :],
-                                     rows_vmem.at[:, pl.ds(i, 1), :],
-                                     row_sem)
-
-    # start every row DMA, then drain: the copies are independent and tiny
-    # (512 B a column tile), so queueing them all before the first wait is
-    # what lets the DMA engines overlap them
-    def _start(i, _):
-        _row_copy(i).start()
-        return 0
-    lax.fori_loop(0, row_tile, _start, 0)
-
-    def _wait(i, _):
-        _row_copy(i).wait()
-        return 0
-    lax.fori_loop(0, row_tile, _wait, 0)
-
+def _accumulate(rows_ref, words_vmem, out_ref, *, tile_words: int,
+                words_per: int, tile_steps: int, col_tiles: int,
+                row_tile: int):
+    """Add one fetched row tile, ``rows_ref`` [col_tiles, row_tile, 128]
+    u32 in VMEM, into the output block: the kernel's arithmetic, whatever
+    brought the rows."""
     tr = row_tile
     shift = 32 // words_per
     wmask = jnp.uint32((1 << shift) - 1)
@@ -194,7 +166,7 @@ def _hist_kernel_fused(sc_ref, order_ref, panel_ref, out_ref,
         # gen-1 kernels' [F, N] layout), parked in VMEM so that a step
         # reads ITS words as whole sublanes at a dynamic, 8-aligned
         # offset: no dynamic lane slicing for Mosaic to reject
-        words_vmem[...] = rows_vmem[t].T             # [128, TR] u32
+        words_vmem[...] = rows_ref[t].T              # [128, TR] u32
 
         # on-chip hi/lo weight split (the _split_hi_lo contract): channels
         # (g_hi, g_lo, h_hi, h_lo, c, 0), the retired gen-1 kernels'
@@ -260,12 +232,92 @@ def _hist_kernel_fused(sc_ref, order_ref, panel_ref, out_ref,
     _loop(col_tiles, _tile)
 
 
+def _hist_kernel_fused(sc_ref, order_ref, panel_ref, out_ref,
+                       idx_smem, rows_vmem, words_vmem, idx_sem, row_sem, *,
+                       sentinel: int, contiguous: bool, tile_words: int,
+                       words_per: int, tile_steps: int, col_tiles: int,
+                       row_tile: int):
+    ri = pl.program_id(0)
+    slot = ri % 2
+    start = sc_ref[0]
+    cnt = sc_ref[1]
+
+    def _fetch(tile, slot):
+        """Start the copies that bring row tile ``tile`` of the window
+        into ``rows_vmem[slot]``, all signalling ``row_sem[slot]``."""
+        if contiguous:
+            # the window IS the panel's first rows, in order: one
+            # descriptor carries the whole tile (no index, no SMEM)
+            r0 = pl.multiple_of(tile * row_tile, row_tile)
+            pltpu.make_async_copy(panel_ref.at[:, pl.ds(r0, row_tile), :],
+                                  rows_vmem.at[slot],
+                                  row_sem.at[slot]).start()
+            return
+        # the tile's slice of the leaf's ``order`` window, HBM -> SMEM:
+        # these are the row ids the per-row DMAs below need as scalars.
+        # The window position is arbitrary but the source slice must be
+        # IDX_ALIGN-aligned, so fetch the enclosing aligned region and
+        # read at the residual offset — 3x the index bytes, which is noise
+        # next to the panel rows.  The ids are read while the row copies
+        # are ISSUED and never again (nothing below rebuilds a
+        # descriptor), so one SMEM buffer serves both slots.
+        pos = start + tile * row_tile
+        aligned = pl.multiple_of((pos // IDX_ALIGN) * IDX_ALIGN, IDX_ALIGN)
+        off = pos - aligned
+        idx_copy = pltpu.make_async_copy(
+            order_ref.at[pl.ds(aligned, fused_idx_fetch(row_tile))],
+            idx_smem, idx_sem)
+        idx_copy.start()
+        idx_copy.wait()
+        base = tile * row_tile
+
+        def _issue(j, carry):
+            # positions past the leaf's count read the sentinel row (zero
+            # words, zero weights) — same contract as the gen-1 sentinel
+            # pad.  pl.ds(r, 1) keeps the slice's row dim: integer .at[r]
+            # indexing squeezes it and that squeeze is what the LLO
+            # lowering choked on ("dynamic_dim_it != dynamic_sizes.end()",
+            # v5e AOT probe) — the compact kernel's proven dynamic-offset
+            # DMAs are all pl.ds-shaped.  One descriptor a row: its 512 B
+            # from every column tile.  Unrolled by hand: Mosaic's
+            # ``fori_loop`` takes ``unroll`` 1 or the whole trip count.
+            for k in range(ISSUE_UNROLL):
+                i = j * ISSUE_UNROLL + k
+                r = jnp.where(base + i < cnt, idx_smem[off + i], sentinel)
+                pltpu.make_async_copy(panel_ref.at[:, pl.ds(r, 1), :],
+                                      rows_vmem.at[slot, :, pl.ds(i, 1), :],
+                                      row_sem.at[slot]).start()
+            return carry
+        lax.fori_loop(0, row_tile // ISSUE_UNROLL, _issue, 0)
+
+    @pl.when(ri == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+        _fetch(0, 0)
+
+    # two slots: the rows of tile ri + 1 fly while tile ri is computed
+    @pl.when(ri + 1 < pl.num_programs(0))
+    def _ahead():
+        _fetch(ri + 1, 1 - slot)
+
+    # ONE wait a tile: a DMA semaphore counts bytes, so a descriptor that
+    # spans the whole slot stands for every copy that filled it (one block
+    # copy, or row_tile row copies); only its destination's size is read
+    rows_ref = rows_vmem.at[slot]
+    pltpu.make_async_copy(rows_ref, rows_ref, row_sem.at[slot]).wait()
+
+    _accumulate(rows_ref, words_vmem, out_ref, tile_words=tile_words,
+                words_per=words_per, tile_steps=tile_steps,
+                col_tiles=col_tiles, row_tile=row_tile)
+
+
 def hist6_fused(order: jnp.ndarray, panel: jnp.ndarray, start, cnt,
                 n_cols: int, words_per: int, num_bins: int,
                 row_tile: int = 512, num_row_tiles=None,
+                contiguous: bool = False,
                 interpret: bool = False) -> jnp.ndarray:
     """Fused-gather nibble histogram: order [NO] i32 row ids (the leaf's
-    window lives at [start, start + cnt)), panel [tiles, N + 1, 128] u32
+    window lives at [start, start + cnt)), panel [tiles, R, 128] u32
     (pack_fused_panel layout, last row = sentinel) -> [6, n_cols, num_bins]
     f32.
 
@@ -276,8 +328,17 @@ def hist6_fused(order: jnp.ndarray, panel: jnp.ndarray, start, cnt,
     rounded down to IDX_ALIGN, plus fused_idx_fetch(row_tile): the aligned
     over-fetch may read that far past the window (the grower pads
     ``order`` with sentinel tail accordingly).
+
+    ``contiguous`` is what a caller says that BUILT the window as the
+    identity: ``start == 0`` and ``order[i] == i`` for i < cnt (the root:
+    ``order0`` is an ``arange``).  The kernel then fetches a tile as one
+    block of ``row_tile`` consecutive panel rows and reads neither
+    ``order`` nor ``cnt``, so the grid is static and every panel row from
+    ``cnt`` to ``num_row_tiles * row_tile`` is a sentinel row
+    (``pack_fused_panel(..., row_multiple=row_tile)`` pads so).
     """
     assert 1 < num_bins <= NIB * NIB, num_bins
+    assert row_tile % ISSUE_UNROLL == 0, row_tile
     assert order.shape[0] >= fused_idx_fetch(row_tile), order.shape
     col_tiles, tile_cols = fused_col_tiles(n_cols, words_per)
     assert panel.shape[0] == col_tiles, (panel.shape, col_tiles)
@@ -285,19 +346,24 @@ def hist6_fused(order: jnp.ndarray, panel: jnp.ndarray, start, cnt,
     sentinel = panel.shape[1] - 1
     if num_row_tiles is None:
         num_row_tiles = 1
+    if contiguous:
+        assert isinstance(num_row_tiles, int) \
+            and num_row_tiles * row_tile <= panel.shape[1], (
+                num_row_tiles, row_tile, panel.shape)
     sc = jnp.stack([jnp.asarray(start, jnp.int32),
                     jnp.asarray(cnt, jnp.int32)])
     out_shape = (col_tiles * tile_steps, NUM_CH * NIB, STEP_LANES)
     # the output block stays in VMEM over the row grid (twice: Pallas
-    # double-buffers it) beside the row tile's panel rows; past the
-    # compiler's default the kernel asks for what it holds and as much
-    # again as the default for the values of a step
+    # double-buffers it) beside the two slots of panel rows and a tile's
+    # transpose; past the compiler's default the kernel asks for what it
+    # holds and as much again as the default for the values of a step
     held = (2 * 4 * out_shape[0] * out_shape[1] * out_shape[2]
-            + (col_tiles + 1) * row_tile * LANES * 4)
+            + (2 * col_tiles + 1) * row_tile * LANES * 4)
     vmem_limit = (held + VMEM_DEFAULT
                   if held > VMEM_DEFAULT // 2 else None)
     out3d = pl.pallas_call(
         functools.partial(_hist_kernel_fused, sentinel=sentinel,
+                          contiguous=contiguous,
                           tile_words=tile_cols // words_per,
                           words_per=words_per, tile_steps=tile_steps,
                           col_tiles=col_tiles, row_tile=row_tile),
@@ -309,11 +375,11 @@ def hist6_fused(order: jnp.ndarray, panel: jnp.ndarray, start, cnt,
             out_specs=pl.BlockSpec(out_shape, lambda ri, sc: (0, 0, 0)),
             scratch_shapes=[pltpu.SMEM((fused_idx_fetch(row_tile),),
                                        jnp.int32),
-                            pltpu.VMEM((col_tiles, row_tile, LANES),
+                            pltpu.VMEM((2, col_tiles, row_tile, LANES),
                                        jnp.uint32),
                             pltpu.VMEM((LANES, row_tile), jnp.uint32),
                             pltpu.SemaphoreType.DMA,
-                            pltpu.SemaphoreType.DMA],
+                            pltpu.SemaphoreType.DMA((2,))],
         ),
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         interpret=interpret,

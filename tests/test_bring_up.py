@@ -97,8 +97,8 @@ def test_wide_layout_resolves_to_the_fused_kernel(monkeypatch):
     assert bst.inner.grower_cfg.hist_method == "fused"
     assert not counters.events("layout_downgrade")
     assert set(counters.get("hist_dispatch")) == {
-        f"col_tiles=2,interpret=True,method=fused,site={s}"
-        for s in ("root", "split")}
+        f"col_tiles=2,fetch={fetch},interpret=True,method=fused,site={s}"
+        for s, fetch in (("root", "block"), ("split", "rows"))}
     assert bst.inner.models[0].num_leaves > 1
 
 

@@ -170,6 +170,81 @@ def test_fused_empty_and_tiny_windows():
     np.testing.assert_allclose(one, ref, rtol=3e-4, atol=3e-4)
 
 
+def _identity_problem(n, f, dtype, row_tile):
+    """A panel of ``n`` real rows padded to whole row tiles, as the grower
+    packs it, and the identity ``order`` the root passes."""
+    b = 255 if dtype == np.uint8 else 256
+    bins, g, h, c = _problem(n, f, b, seed=n + f, dtype=dtype)
+    pad1 = lambda x: jnp.concatenate([jnp.asarray(x),
+                                      jnp.zeros((1,), jnp.float32)])
+    panel, per = pack_fused_panel(
+        jnp.concatenate([jnp.asarray(bins), jnp.zeros((1, f), dtype)]),
+        pad1(g), pad1(h), pad1(c), row_multiple=row_tile)
+    assert panel.shape[1] % row_tile == 0 and panel.shape[1] > n
+    order = jnp.concatenate(
+        [jnp.arange(n, dtype=jnp.int32),
+         jnp.full((fused_idx_fetch(row_tile),), n, jnp.int32)])
+    return panel, per, order, b, float(c.sum())
+
+
+BLOCK_TILE = 128     # the smallest row tile the configuration admits
+
+
+@pytest.mark.parametrize("n", [1, BLOCK_TILE - 1, BLOCK_TILE, BLOCK_TILE + 1,
+                               3 * BLOCK_TILE + 7])
+@pytest.mark.parametrize("f,dtype", [(28, np.uint8), (600, np.uint8),
+                                     (1100, np.uint8), (28, np.uint16)],
+                         ids=["28", "600", "1100", "28-uint16"])
+def test_fused_block_fetch_identical_to_indexed(n, f, dtype):
+    """The root's fetch form (``contiguous``: one block copy a row tile,
+    no index) against the indexed form over the same identity window:
+    the same tiles in the same order into the same block, so bit for bit,
+    float weights and all — through the ragged last tile, whose rows past
+    ``n`` are the panel's sentinel padding."""
+    panel, per, order, b, counted = _identity_problem(n, f, dtype, BLOCK_TILE)
+    kw = dict(row_tile=BLOCK_TILE, num_row_tiles=-(-n // BLOCK_TILE),
+              interpret=True)
+    rows = np.asarray(subset_histogram_fused(order, panel, 0, n, f, per, b,
+                                             **kw))
+    block = np.asarray(subset_histogram_fused(order, panel, 0, n, f, per, b,
+                                              contiguous=True, **kw))
+    assert rows[:, :, 2].sum() == f * counted     # every row, once a column
+    np.testing.assert_array_equal(block, rows)
+
+
+@pytest.mark.parametrize("dyn_grid", [False, True], ids=["static", "dynamic"])
+@pytest.mark.parametrize("cnt", [0, 1, ROW_TILE, 2 * ROW_TILE + 3])
+def test_fused_pipeline_identical_to_one_slot(cnt, dyn_grid):
+    """The two-slot fetch (tile i + 1 issued before tile i is waited for,
+    one wait a tile) against a ONE-slot kernel that issues, waits row by
+    row and then computes, as the kernel did before the pipeline
+    (``scripts/probe_hist_fetch.py``'s ``parent`` form, around the same
+    arithmetic): bit for bit over windows at an unaligned start."""
+    import importlib
+    import jax
+    probe = importlib.import_module("scripts.probe_hist_fetch")
+    n, f, b = 2048, 28, 255
+    bins, g, h, c = _problem(n, f, b, seed=cnt)
+    panel, per = _fused_inputs(bins, g, h, c)
+    perm = np.random.RandomState(9).permutation(n).astype(np.int32)
+    order = _order_with_tail(perm, n)
+    start = 333
+    nt = max(1, -(-cnt // ROW_TILE))
+    ref = np.asarray(probe.probe_hist6("parent", order, panel, start, cnt, f,
+                                       b, nt, interpret=True))
+    from lightgbm_tpu.ops.pallas_hist import hist6_fused
+    if dyn_grid:
+        got = jax.jit(lambda o, p, s, k: hist6_fused(
+            o, p, s, k, f, per, b, row_tile=ROW_TILE,
+            num_row_tiles=jnp.maximum(1, (k + ROW_TILE - 1) // ROW_TILE),
+            interpret=True))(order, panel, jnp.int32(start), jnp.int32(cnt))
+    else:
+        got = hist6_fused(order, panel, start, cnt, f, per, b,
+                          row_tile=ROW_TILE, num_row_tiles=nt, interpret=True)
+    assert (np.asarray(got)[4].sum() > 0) == (cnt > 0)
+    np.testing.assert_array_equal(np.asarray(got), ref)
+
+
 def _grow_tree_strings(hist_method, bins, g, h, c, num_bins, pack_plan=None,
                        hist_bins=None, num_bin_arr=None, num_leaves=15,
                        min_data_in_leaf=5):
@@ -292,3 +367,34 @@ def test_fused_warns_and_falls_back_on_wide_bins():
     t_fus, _ = _grow_tree_strings("fused", bins, g, h, c, b)
     np.testing.assert_array_equal(t_seg.split_feature, t_fus.split_feature)
     np.testing.assert_array_equal(t_seg.threshold_bin, t_fus.threshold_bin)
+
+
+def test_hist_block_fetch_metric_reads_the_fetch_tag():
+    """The benchmark's ``hist_block_fetch`` counts the ``hist_dispatch``
+    call sites built with the block fetch: 1 from a grower on the fused
+    rung (the root), None from a program that does not tag its fetch."""
+    import json
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from benchmarks.harness import metrics
+    from lightgbm_tpu.obs.counters import counters
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        entry = next(m for m in json.load(fh)["per_layer"]
+                     if m["name"] == "hist_block_fetch")
+    assert entry["layer"] == "histogram kernel"
+    assert entry["moves"] == "trees_per_s" and entry["better"] == "higher"
+
+    counters.reset()
+    bins, g, h, c = _problem(600, 4, 16, seed=3)
+    _grow_tree_strings("fused", bins, g, h, c, 16, num_leaves=4)
+    assert set(counters.get("hist_dispatch")) == {
+        f"col_tiles=1,fetch={fetch},interpret=True,method=fused,site={s}"
+        for s, fetch in (("root", "block"), ("split", "rows"))}
+    assert metrics.read_metric("hist_block_fetch", {}) == 1
+    counters.reset()
+    counters.inc("hist_dispatch", method="fused", site="root", col_tiles=1)
+    assert metrics.read_metric("hist_block_fetch", {}) is None
+    counters.reset()
+    assert metrics.read_metric("hist_block_fetch", {}) is None

@@ -244,8 +244,8 @@ def test_gspmd_fused_zero_recompile_across_calls():
     disp = counters.get("hist_dispatch")
     # one trace per mesh per site: 2 meshes x {root, split}, never 4
     assert disp == {
-        "col_tiles=1,interpret=True,method=fused,site=root": 2,
-        "col_tiles=1,interpret=True,method=fused,site=split": 2,
+        "col_tiles=1,fetch=rows,interpret=True,method=fused,site=root": 2,
+        "col_tiles=1,fetch=rows,interpret=True,method=fused,site=split": 2,
     }, disp
 
 

@@ -51,14 +51,17 @@ def v5e():
         jax.config.update("jax_compilation_cache_dir", prev_cache_dir)
 
 
-@pytest.mark.parametrize("dyn_grid,num_bins,f", [
-    (False, 255, 28), (True, 255, 28), (True, 63, 28), (True, 256, 12),
-    (True, 255, 2000), (True, 255, 513),
+@pytest.mark.parametrize("grid,num_bins,f", [
+    ("static", 255, 28), ("dynamic", 255, 28), ("dynamic", 63, 28),
+    ("dynamic", 256, 12), ("dynamic", 255, 2000), ("dynamic", 255, 513),
+    ("contiguous", 255, 28), ("contiguous", 255, 2000),
 ])
-def test_fused_hist_kernel_lowers(v5e, dyn_grid, num_bins, f):
+def test_fused_hist_kernel_lowers(v5e, grid, num_bins, f):
     """The fused-gather kernel Mosaic-compiles for v5e: in-kernel
     index fetch (aligned over-read), per-row panel DMA, nibble
-    contraction — with both static and DYNAMIC (traced tile count) grids.
+    contraction — with both static and DYNAMIC (traced tile count) grids,
+    and the root's ``contiguous`` form (one block copy a row tile, static
+    grid, the panel's rows padded to whole tiles).
     Offline runs of this proof caught FIVE real lowering failures that
     every interpret-mode test passed: unaligned dynamic 1-D slice
     offsets, non-tile-multiple slice lengths, sub-128-lane panel row
@@ -72,9 +75,10 @@ def test_fused_hist_kernel_lowers(v5e, dyn_grid, num_bins, f):
     # pack_fused_panel's layout: column tiles of 128 words (513 columns are
     # two, 2000 five: the kernel walks them in steps of 32 columns, so its
     # program does not grow with the width)
-    panel = (fused_col_tiles(f, 4)[0], n + 1, 128)
+    contiguous = grid == "contiguous"
+    panel = (fused_col_tiles(f, 4)[0], n + (tr if contiguous else 1), 128)
     no = n + fused_idx_fetch(tr)
-    if dyn_grid:
+    if grid == "dynamic":
         fn = jax.jit(lambda o, p, s, c, nt: subset_histogram_fused(
             o, p, s, c, f, 4, num_bins, row_tile=tr, num_row_tiles=nt))
         fn.lower(v5e((no,), jnp.int32), v5e(panel, jnp.uint32),
@@ -82,7 +86,9 @@ def test_fused_hist_kernel_lowers(v5e, dyn_grid, num_bins, f):
                  v5e((), jnp.int32)).compile()
     else:
         fn = jax.jit(lambda o, p, s, c: subset_histogram_fused(
-            o, p, s, c, f, 4, num_bins, row_tile=tr, num_row_tiles=16))
+            o, p, s, c, f, 4, num_bins, row_tile=tr,
+            num_row_tiles=n // tr if contiguous else 16,
+            contiguous=contiguous))
         fn.lower(v5e((no,), jnp.int32), v5e(panel, jnp.uint32),
                  v5e((), jnp.int32), v5e((), jnp.int32)).compile()
 
