@@ -86,7 +86,7 @@ import time
 BASELINE_TREES_PER_SEC_1M = 2.5285 * 28  # see module docstring
 
 # only binning-relevant params key the dataset cache: grower knobs
-# (gather_*, partition_impl, ordered_bins, bin packing, pallas_fused, ...)
+# (partition_impl, bin packing, use_pallas, ...)
 # never change the constructed dataset, and hashing them would make every
 # A/B stage re-bin.  INVARIANT (pinned by tests/test_bench_keys.py): this
 # set must stay a superset of every
@@ -726,8 +726,6 @@ def child_main():
         "learning_rate": 0.1,
         "verbose": -1,
         "use_pallas": use_pallas and platform == "tpu",
-        "pallas_fused": "on" if mode == "fused" and platform == "tpu"
-                        else "auto",
         "enable_bundle": sparsity > 0.0,
     }
     # ad-hoc A/B knobs (e.g. BENCH_EXTRA_PARAMS=enable_bin_packing=false)

@@ -262,48 +262,8 @@ class GBDT:
         self.num_data = train.num_data
         n = self.num_data
 
-        self.grower_cfg = GrowerConfig(
-            num_leaves=cfg.num_leaves,
-            max_depth=cfg.max_depth,
-            min_data_in_leaf=cfg.min_data_in_leaf,
-            min_sum_hessian_in_leaf=cfg.min_sum_hessian_in_leaf,
-            lambda_l1=cfg.lambda_l1,
-            lambda_l2=cfg.lambda_l2,
-            min_gain_to_split=cfg.min_gain_to_split,
-            max_bin=train.max_num_bin(),
-            # the ladder is fused-vs-reference since the gen-1 kernels
-            # were retired: on TPU, use_pallas runs the fused in-kernel-
-            # gather rung ('auto' and 'on' alike — it is the ONLY Pallas
-            # kernel left, and the lowering-proven one); pallas_fused=off
-            # / use_pallas=false force the MXU-shaped einsum oracle;
-            # off-TPU picks the cpu_hist_method reference
-            hist_method=("fused" if cfg.use_pallas and on_tpu()
-                         and cfg.pallas_fused != "off"
-                         else "einsum" if on_tpu()    # MXU-friendly debug
-                         else cfg.cpu_hist_method),   # scatter-add on CPU
-            row_tile=cfg.pallas_row_tile,
-            bucket_min_log2=cfg.pallas_bucket_min_log2,
-            gather_words=cfg.gather_words,
-            gather_panel=cfg.gather_panel,
-            ordered_bins=("off" if cfg.ordered_bins == "auto"
-                          else cfg.ordered_bins),
-            partition_impl=("scatter" if cfg.partition_impl == "auto"
-                            else cfg.partition_impl),
-            bucket_scheme=("pow2" if cfg.bucket_scheme == "auto"
-                           else cfg.bucket_scheme),
-            has_categorical=bool(np.asarray(fm["is_categorical"]).any()),
-            has_missing=bool((np.asarray(fm["missing_type"]) != 0).any()),
-            max_cat_threshold=cfg.max_cat_threshold,
-            max_cat_group=cfg.max_cat_group,
-            cat_smooth_ratio=cfg.cat_smooth_ratio,
-            min_cat_smooth=cfg.min_cat_smooth,
-            max_cat_smooth=cfg.max_cat_smooth,
-            # off-TPU a Pallas kernel (partition_impl=compact) can only
-            # run interpreted; on a TPU backend it compiles or raises
-            hist_interpret=not on_tpu(),
-            split_find=cfg.split_find)
         with obs_trace.phase("setup.grower"):
-            self._setup_grower(cfg, train)
+            self._setup_grower(cfg, train, fm)
         # rollback must act BEFORE the next iteration trains on poisoned
         # scores, so it forces synchronous tree materialization; the cheap
         # default (raise) keeps the pipeline and detects at drain time
@@ -435,9 +395,6 @@ class GBDT:
                                if stream is not None else 0),
             packed_cols=(plan.num_storage_cols if plan is not None else 0),
             valid_rows=sum(vs.data.num_data for vs in self.valid_sets),
-            ordered_bins=self.grower_cfg.ordered_bins == "on",
-            # 'auto' resolves ON everywhere since round 8 (grower.py)
-            gather_words=self.grower_cfg.gather_words in ("on", "auto"),
             bucket_min_log2=self.grower_cfg.bucket_min_log2,
             # GSPMD: the pre-flight judges the PER-DEVICE peak the planner
             # already sized the mesh for (docs/DISTRIBUTED.md)
@@ -454,7 +411,37 @@ class GBDT:
                     + (f", streamed in {stream.chunk_rows}-row blocks"
                        if stream is not None else ""))
 
-    def _setup_grower(self, cfg: Config, train: TrainingData) -> None:
+    def _grower_config(self, cfg: Config, train: TrainingData, fm,
+                       hist_method: str) -> GrowerConfig:
+        return GrowerConfig(
+            num_leaves=cfg.num_leaves,
+            max_depth=cfg.max_depth,
+            min_data_in_leaf=cfg.min_data_in_leaf,
+            min_sum_hessian_in_leaf=cfg.min_sum_hessian_in_leaf,
+            lambda_l1=cfg.lambda_l1,
+            lambda_l2=cfg.lambda_l2,
+            min_gain_to_split=cfg.min_gain_to_split,
+            max_bin=train.max_num_bin(),
+            hist_method=hist_method,
+            row_tile=cfg.pallas_row_tile,
+            bucket_min_log2=cfg.pallas_bucket_min_log2,
+            partition_impl=("scatter" if cfg.partition_impl == "auto"
+                            else cfg.partition_impl),
+            bucket_scheme=("pow2" if cfg.bucket_scheme == "auto"
+                           else cfg.bucket_scheme),
+            has_categorical=bool(np.asarray(fm["is_categorical"]).any()),
+            has_missing=bool((np.asarray(fm["missing_type"]) != 0).any()),
+            max_cat_threshold=cfg.max_cat_threshold,
+            max_cat_group=cfg.max_cat_group,
+            cat_smooth_ratio=cfg.cat_smooth_ratio,
+            min_cat_smooth=cfg.min_cat_smooth,
+            max_cat_smooth=cfg.max_cat_smooth,
+            # off-TPU a Pallas kernel can only run interpreted; on a TPU
+            # backend it compiles or raises
+            hist_interpret=not on_tpu(),
+            split_find=cfg.split_find)
+
+    def _setup_grower(self, cfg: Config, train: TrainingData, fm) -> None:
         """Select the tree learner (CreateTreeLearner analogue):
         serial on one device; data/feature/voting over the device mesh.
 
@@ -543,16 +530,6 @@ class GBDT:
                               for i in train.used_features])
             self._pack_plan = build_pack_plan(col_bins)
             if self._pack_plan is not None:
-                if cfg.ordered_bins == "on":
-                    log.warning("ordered_bins=on is ignored while nibble "
-                                "bin packing is active (the packed storage "
-                                "matrix has its own layout); set "
-                                "enable_bin_packing=false to use the "
-                                "leaf-ordered path")
-                    obs_counters.event(
-                        "layout_downgrade", stage="boosting",
-                        requested="ordered_bins=on", resolved="off",
-                        reason="nibble bin packing is active")
                 self._hist_bins = pack_columns(np.asarray(train.binned),
                                                self._pack_plan)
                 log.info("Bin packing: %d of %d columns nibble-packed "
@@ -560,28 +537,24 @@ class GBDT:
                          self._pack_plan.num_packed,
                          self._pack_plan.num_phys_cols,
                          self._pack_plan.num_storage_cols)
-        # fused-rung truthfulness: downgrade a fused request the layout
-        # cannot serve HERE, so grower_cfg.hist_method (which bench labels
-        # and A/B artifacts read) always names the kernel that runs; the
-        # grower re-checks the same gate at trace time as a safety net
-        if self.grower_cfg.hist_method == "fused":
-            from .data.packing import PACK_JOINT_BINS
-            from .grower import fused_fallback_method, fused_gate_reason
-            plan = self._pack_plan
-            hw = (max(PACK_JOINT_BINS, self.grower_cfg.max_bin)
-                  if plan is not None else self.grower_cfg.max_bin)
-            reason = fused_gate_reason(
-                train.binned.dtype, jnp.float32, hw,
-                self.grower_cfg.ordered_bins == "on" and plan is None)
-            if reason is not None:
-                resolved = fused_fallback_method()
-                log.warning("hist_method=fused unavailable (%s); using the "
-                            "%s reference path", reason, resolved)
-                obs_counters.event("layout_downgrade", stage="boosting",
-                                   requested="fused", resolved=resolved,
-                                   reason=reason)
-                self.grower_cfg = self.grower_cfg._replace(
-                    hist_method=resolved)
+        # the histogram method, chosen here and nowhere else: the pack plan
+        # above fixes the histogram's width, the last thing the choice
+        # needs.  grower_cfg.hist_method (which bench labels and A/B
+        # artifacts read) names the kernel that runs
+        from .data.packing import PACK_JOINT_BINS
+        from .grower import resolve_hist_method
+        max_bin = train.max_num_bin()
+        hist_method, reason = resolve_hist_method(
+            cfg.use_pallas, cfg.cpu_hist_method, train.binned.dtype,
+            jnp.float32, (max(PACK_JOINT_BINS, max_bin)
+                          if self._pack_plan is not None else max_bin))
+        if reason is not None:
+            log.warning("hist_method=fused unavailable (%s); using the "
+                        "%s reference path", reason, hist_method)
+            obs_counters.event("layout_downgrade", stage="boosting",
+                               requested="fused", resolved=hist_method,
+                               reason=reason)
+        self.grower_cfg = self._grower_config(cfg, train, fm, hist_method)
         # the bagged-subset optimization (gbdt.cpp:323-382 is_use_subset_)
         # gathers rows into a compact matrix — serial learner only for now
         self._can_subset = not use_dist
@@ -823,6 +796,10 @@ class GBDT:
                 reason="streamed blocks keep the raw 1:1 bin layout")
             self._pack_plan = None
             self._hist_bins = None
+        # not a second choice of kernel: the streamed grower has ONE
+        # histogram form (subset_histogram_flat over a block) and the
+        # placement that leads here is decided after the method is; the
+        # label follows what runs
         if self.grower_cfg.hist_method != "segment":
             log.warning("hist_method=%s is unavailable under "
                         "data_stream=chunked (per-block partial "
@@ -836,16 +813,6 @@ class GBDT:
                 reason="streamed blocks use the masked segment-sum")
             self.grower_cfg = self.grower_cfg._replace(
                 hist_method="segment")
-        if self.grower_cfg.ordered_bins == "on":
-            log.warning("ordered_bins=on is ignored under "
-                        "data_stream=chunked (leaf-ordered storage "
-                        "assumes the resident row layout); using the "
-                        "direct layout")
-            obs_counters.event(
-                "layout_downgrade", stage="boosting",
-                requested="ordered_bins=on", resolved="off",
-                reason="streamed blocks keep source row order")
-            self.grower_cfg = self.grower_cfg._replace(ordered_bins="off")
         # the bagged-subset gather materializes ANOTHER row matrix on
         # device — bagging under streaming keeps the weight-mask form
         self._can_subset = False
@@ -881,9 +848,10 @@ class GBDT:
         # partner) or fused (the shard_map hybrid: the fused Pallas
         # kernel per row shard, partitioner-owned cross-shard reduction).
         # ``auto`` stays flat until the on-chip A/B flips it
-        # (capture-backlog discipline, scripts/decide_flips.py).  The
-        # serial TPU/CPU ladder baked into grower_cfg.hist_method does
-        # not apply here — the partitioner owns the layout.
+        # (capture-backlog discipline, scripts/decide_flips.py).  What
+        # resolve_hist_method chose for one device does not apply here:
+        # the form is ``gspmd_hist``'s, behind the same fused_gate_reason
+        # and a condition on the mesh that is only known below.
         gspmd_hist = "flat" if cfg.gspmd_hist == "auto" else cfg.gspmd_hist
         procs = jax.process_count()
         if gspmd_hist == "fused" and procs > 1:
@@ -903,13 +871,13 @@ class GBDT:
                    else int(np.shape(self.bins)[1]))
         hist_mat = (self._hist_bins if self._pack_plan is not None
                     else self.bins)
-        hist_dtype = np.asarray(hist_mat).dtype
+        hist_bins_dtype = np.asarray(hist_mat).dtype
         if gspmd_hist == "fused":
             # shape-independent gate (the shape-dependent half runs after
             # the mesh plan below): downgrade loudly BEFORE labels are
             # read, per the rung-honesty discipline
-            reason = fused_gate_reason(hist_dtype, jnp.float32, hist_width,
-                                       False)
+            reason = fused_gate_reason(hist_bins_dtype, jnp.float32,
+                                       hist_width)
             if reason is not None:
                 log.warning("gspmd_hist=fused unavailable (%s); using the "
                             "flat scatter-add histogram", reason)

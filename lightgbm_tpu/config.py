@@ -492,24 +492,26 @@ class Config:
                                        # backoff per host-object collective
                                        # before the error surfaces
 
+    # the reference's gpu_* keys: accepted so that its config files load,
+    # and ignored (there is no OpenCL platform or double-precision switch
+    # here)
+    gpu_platform_id: int = -1      # accepted and ignored (reference key)
+    gpu_device_id: int = -1        # accepted and ignored (reference key)
+    gpu_use_dp: bool = False       # accepted and ignored (reference key)
+
     # compute backend knobs (TPU analogue of gpu_* params)
-    gpu_platform_id: int = -1
-    gpu_device_id: int = -1
-    gpu_use_dp: bool = False
-    hist_dtype: str = "float32"    # accumulator dtype for histograms
-    use_pallas: bool = True        # Pallas hist kernel on TPU
+    use_pallas: bool = True        # the fused Pallas histogram kernel on
+                                   # TPU; false forces the XLA einsum
+                                   # reference there
     cpu_hist_method: str = "segment"   # off-TPU histogram: segment | einsum
+                                       # | fused (the kernel interpreted:
+                                       # how tests reach it without a chip)
     pallas_row_tile: int = 512     # kernel grid: rows per block
     pallas_bucket_min_log2: int = 6    # smallest pow2 gather bucket (64
                                        # rows: deep-tree tail splits pay
                                        # O(leaf) work, not kilobucket
                                        # padding; sub-512 buckets shrink
                                        # the Pallas row tile to match)
-    gather_words: str = "auto"     # pack bin columns into u32 words for the
-                                   # histogram row gather: auto | on | off
-    gather_panel: str = "auto"     # fold the f32 weight columns into the
-                                   # word matrix so each split's read is
-                                   # ONE row gather: auto | on | off
     split_find: str = "fused"      # best-split scan formulation: fused
                                    # (gain scan fused onto the hot
                                    # histogram — per-direction reductions,
@@ -518,22 +520,11 @@ class Config:
                                    # historical packed-argmax form, kept as
                                    # the forced A/B baseline).  Trees are
                                    # bit-identical either way (pinned)
-    pallas_fused: str = "auto"     # fused-gather nibble histogram kernel
-                                   # (in-kernel row DMA, no gather pass,
-                                   # no pow2 staging buffer): auto | on
-                                   # | off; the ONLY Pallas rung since
-                                   # the gen-1 kernels were retired —
-                                   # 'auto'/'on' run it on TPU, 'off'
-                                   # forces the einsum reference oracle
-    ordered_bins: str = "auto"     # leaf-ordered bin matrix (OrderedBin
-                                   # analogue): auto | on | off; 'on' trades
-                                   # wide partition scatters for contiguous
-                                   # histogram reads (no row gathers)
     partition_impl: str = "auto"   # window partition: auto | scatter | sort
-                                   # | compact (sort = stable 1-bit-key
-                                   # payload sort; compact = Pallas two-pass
-                                   # MXU compaction kernel, all-sequential
-                                   # HBM traffic)
+                                   # (sort = one stable sort of the window
+                                   # keyed by the routing bit; auto is
+                                   # scatter until the PR that flips it:
+                                   # ROADMAP S1.2)
     bucket_scheme: str = "auto"    # gather-bucket sizes: auto | pow2 | pow15
                                    # (pow15 adds 1.5*2^k buckets: ~16% less
                                    # padded work, 2x the compiled branches)
@@ -697,15 +688,6 @@ def check_param_conflicts(cfg: Config) -> None:
     if cfg.pallas_bucket_min_log2 < 0 or cfg.pallas_bucket_min_log2 > 26:
         log.fatal("pallas_bucket_min_log2 must be in [0, 26]; got %d",
                   cfg.pallas_bucket_min_log2)
-    if cfg.gather_words not in ("auto", "on", "off"):
-        log.fatal("gather_words must be auto, on, or off; got %r",
-                  cfg.gather_words)
-    if cfg.gather_panel not in ("auto", "on", "off"):
-        log.fatal("gather_panel must be auto, on, or off; got %r",
-                  cfg.gather_panel)
-    if cfg.pallas_fused not in ("auto", "on", "off"):
-        log.fatal("pallas_fused must be auto, on, or off; got %r",
-                  cfg.pallas_fused)
     if cfg.gspmd_hist not in ("auto", "fused", "flat"):
         log.fatal("gspmd_hist must be auto, fused, or flat; got %r",
                   cfg.gspmd_hist)
@@ -724,12 +706,9 @@ def check_param_conflicts(cfg: Config) -> None:
     if cfg.saved_feature_importance_type not in (0, 1):
         log.fatal("saved_feature_importance_type must be 0 (split) or "
                   "1 (gain); got %d", cfg.saved_feature_importance_type)
-    if cfg.ordered_bins not in ("auto", "on", "off"):
-        log.fatal("ordered_bins must be auto, on, or off; got %r",
-                  cfg.ordered_bins)
-    if cfg.partition_impl not in ("auto", "scatter", "sort", "compact"):
-        log.fatal("partition_impl must be auto, scatter, sort, or compact; "
-                  "got %r", cfg.partition_impl)
+    if cfg.partition_impl not in ("auto", "scatter", "sort"):
+        log.fatal("partition_impl must be auto, scatter, or sort; got %r",
+                  cfg.partition_impl)
     if cfg.bucket_scheme not in ("auto", "pow2", "pow15"):
         log.fatal("bucket_scheme must be auto, pow2, or pow15; got %r",
                   cfg.bucket_scheme)

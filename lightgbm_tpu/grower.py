@@ -49,7 +49,6 @@ from .ops.histogram import (on_tpu, subset_histogram, subset_histogram_flat,
 from .ops.pallas_hist import NIB, fused_idx_fetch
 from .ops.split import (MISSING_NAN, MISSING_ZERO, SplitConfig, SplitResult,
                         best_split, leaf_output, make_fused_ctx)
-from .utils import log
 
 
 class GrowerConfig(NamedTuple):
@@ -71,13 +70,7 @@ class GrowerConfig(NamedTuple):
     #                                  (64 rows: tail splits of deep trees
     #                                  stop paying kilobucket padding —
     #                                  round-7 leaves-sweep measurement)
-    gather_words: str = "auto"       # word-pack bin columns for row gathers
-    ordered_bins: str = "off"        # leaf-ordered bin matrix: on | off
     partition_impl: str = "scatter"  # window partition: scatter | sort
-                                     # | compact (Pallas kernel)
-    gather_panel: str = "auto"       # fold weight columns into the word
-                                     # gather (one row gather per split):
-                                     # auto/on | off
     bucket_scheme: str = "pow2"      # gather-bucket sizes: pow2 | pow15
     has_categorical: bool = False    # static: enables the categorical path
     has_missing: bool = True         # static: False skips the dir=+1 scan
@@ -86,8 +79,7 @@ class GrowerConfig(NamedTuple):
     cat_smooth_ratio: float = 0.01
     min_cat_smooth: float = 5.0
     max_cat_smooth: float = 100.0
-    hist_interpret: bool = False     # run the Pallas kernels (fused
-                                     # histogram, compaction partition) in
+    hist_interpret: bool = False     # run the fused histogram kernel in
                                      # interpret mode — the off-TPU parity
                                      # path; never inferred, never on-chip
     split_find: str = "fused"        # best-split scan formulation: fused
@@ -158,21 +150,9 @@ def decode_bundle_bin(raw, feat, meta: FeatureMeta):
     return jnp.where(off < 0, raw, sub)
 
 
-# pack_gather_words / unpack_gather_words moved to data/packing.py (the
-# fused kernel DMAs the same word layout in-kernel); imported above so
-# existing call sites — including scripts/tpu_microprobe.py — keep
-# working unchanged.
-
-
-def fused_gate_reason(bins_dtype, weights_dtype, hist_width: int,
-                      use_ordered: bool):
+def fused_gate_reason(bins_dtype, weights_dtype, hist_width: int):
     """None when the fused-gather kernel can run on this layout, else the
-    human-readable reason it cannot.
-
-    Shared by the grower's trace-time gate AND boosting's method
-    resolution: the resolved ``hist_method`` must always name the kernel
-    that actually runs, so a fused request on an unfusable layout is
-    downgraded BEFORE anything (bench labels, A/B artifacts) reads it."""
+    human-readable reason it cannot."""
     if jnp.dtype(bins_dtype).itemsize > 2:
         return f"bin dtype {jnp.dtype(bins_dtype)} is wider than 2 bytes"
     if jnp.dtype(weights_dtype) != jnp.float32:
@@ -180,17 +160,29 @@ def fused_gate_reason(bins_dtype, weights_dtype, hist_width: int,
     if hist_width > NIB * NIB:
         return (f"histogram width {hist_width} exceeds the "
                 f"nibble-factorized limit {NIB * NIB}")
-    if use_ordered:
-        return "ordered_bins=on replaces the row gather entirely"
     return None
 
 
-def fused_fallback_method() -> str:
-    """The XLA reference rung a fused request resolves to when
-    :func:`fused_gate_reason` refuses the layout: einsum on TPU (the
-    MXU-shaped form), segment elsewhere.  One answer for the grower's
-    trace-time gate and boosting's method resolution."""
-    return "einsum" if on_tpu() else "segment"
+def resolve_hist_method(use_pallas: bool, cpu_hist_method: str, bins_dtype,
+                        weights_dtype, hist_width: int):
+    """The histogram method a layout trains with, and why it is not the
+    one that was asked for: ``(method, reason)``, ``reason`` None unless
+    :func:`fused_gate_reason` refused ``fused``.
+
+    The ONE place the kernel is chosen.  On the chip ``use_pallas`` asks
+    for the fused Pallas kernel and ``use_pallas=false`` for the
+    MXU-shaped ``einsum`` reference; off the chip ``cpu_hist_method`` is
+    the method (tests put the interpreted fused kernel there).  A fused
+    request the layout cannot serve resolves to the XLA reference of the
+    backend, so that ``GrowerConfig.hist_method`` always names the kernel
+    that runs; ``make_grower`` raises on a fused config it cannot serve."""
+    reference = "einsum" if on_tpu() else "segment"
+    wanted = (cpu_hist_method if not on_tpu()
+              else "fused" if use_pallas else reference)
+    if wanted != "fused":
+        return wanted, None
+    reason = fused_gate_reason(bins_dtype, weights_dtype, hist_width)
+    return ("fused", None) if reason is None else (reference, reason)
 
 
 def _row_leaf_from_intervals(order, leaf_start, leaf_cnt, n):
@@ -227,9 +219,6 @@ class _LoopState(NamedTuple):
     surface).  ``TreeArrays`` is unpacked ONCE after the loop."""
     step: jnp.ndarray
     order: jnp.ndarray           # [N + maxbuf] i32: row ids grouped by leaf
-    obins: jnp.ndarray           # [N + maxbuf, C] leaf-ordered bin matrix
-    ow: jnp.ndarray              # [N + maxbuf, 3] leaf-ordered (g, h, c)
-    #                              (both [0, 0] dummies unless ordered_bins)
     lsc: jnp.ndarray             # [L, 2] i32: (first position, local count)
     hist_store: jnp.ndarray      # [L, 3 * F * B]: per-leaf histograms, a
     #                              leaf's flat (pool_flat)
@@ -446,6 +435,59 @@ def take_row_bits(words, rows):
     return ((w >> plane.astype(jnp.uint32)) & 1).astype(bool)
 
 
+def partition_window(order, start, cnt, size: int, left_bits,
+                     impl: str = "scatter"):
+    """Stable two-way partition of one leaf's window of ``order``
+    (``DataPartition::Split``, data_partition.hpp:94-146).
+
+    ``order`` is ``i32[N + tail]``: row ids grouped by leaf, then ``tail >=
+    size`` sentinel slots holding ``N``.  The window is the ``size`` (static)
+    slots from ``start``, of which the first ``cnt`` are the leaf's rows;
+    ``left_bits`` is :func:`pack_row_bits` of the ``bool[N]`` decision "this
+    row goes left".  Returns ``(order, n_left)``: the leaf's rows that go
+    left, in the sequence they had, then those that go right, in theirs;
+    every slot outside ``[start, start + cnt)`` as it was.  Closes over
+    nothing: this is the seam a different transport of the window replaces.
+
+    ``impl`` is the transport: ``scatter`` ranks the rows by one cumsum and
+    scatters them to their slots; ``sort`` is one stable sort of the window
+    keyed left / right / past the leaf (the faster one on the chip; ROADMAP
+    S1.2 names the PR that makes it the only one)."""
+    win = lax.dynamic_slice(order, (start,), (size,))
+    j = jnp.arange(size, dtype=jnp.int32)
+    valid = j < cnt
+    # slots past the leaf may hold the sentinel N: read row 0's bit there
+    goes_left = take_row_bits(left_bits, jnp.where(valid, win, 0)) & valid
+    if impl == "sort":
+        # slots past the leaf (key 2) are already contiguous at the
+        # window's tail, so a stable sort returns them where they were
+        nl = jnp.sum(goes_left.astype(jnp.int32))
+        key = jnp.where(~valid, 2, jnp.where(goes_left, 0, 1)
+                        ).astype(jnp.int32)
+        _, new_win = lax.sort((key, win), is_stable=True, num_keys=1)
+        return lax.dynamic_update_slice(order, new_win, (start,)), nl
+    c1 = jnp.cumsum(goes_left.astype(jnp.int32))
+    nl = c1[-1]
+    # right-side rank needs cumsum(valid & ~goes_left); since valid =
+    # j < cnt that cumsum is min(j+1, cnt) - c1 in closed form — one
+    # cumsum pass instead of two
+    c0 = jnp.minimum(j + 1, cnt) - c1
+    # stable two-way rank inside the window; rows past the leaf (and
+    # sentinel padding) keep their own slot so the write-back leaves
+    # neighbors untouched
+    rank = jnp.where(goes_left, c1 - 1, nl + c0 - 1)
+    rank = jnp.where(valid, rank, j)
+    # ONE scatter straight into ``order`` at start + rank — not a
+    # window-local scatter followed by a dynamic_update_slice write-back.
+    # The read-then-write interference of the DUS form made XLA:CPU's copy
+    # insertion clone the whole O(N) carrier once per split
+    # (tests/test_grow_jaxpr.py pins the jaxpr against this class of
+    # regression); the direct scatter updates it in place
+    order = order.at[start + rank].set(
+        win, unique_indices=True, mode="promise_in_bounds")
+    return order, nl
+
+
 def pool_flat(hist):
     """[..., F, B, 3] histograms -> [..., 3 * F * B] rows of the per-leaf
     pool, one statistic's [F, B] plane after another.  The pool is carried
@@ -596,139 +638,41 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
         hw_pad = jnp.concatenate([hw, jnp.zeros((1,), dtype)])
         cw_pad = jnp.concatenate([cw, jnp.zeros((1,), dtype)])
 
-        use_words = cfg.gather_words
-        if use_words == "auto":
-            # round 8: 'auto' now resolves ON for the CPU rungs too — the
-            # per-element gather cost argument holds there as well, and
-            # with the panel fold (one u32 row gather per split instead of
-            # a u8 row gather + 3 weight gathers) the 200k x 28 CPU
-            # leaves-sweep marginal measured ~9% lower.  Explicit
-            # gather_words=off remains the escape hatch.
-            use_words = "on"
-        if hbins.dtype.itemsize > 2:
-            if cfg.gather_words == "on":
-                log.warning("gather_words=on ignored: bin dtype %s is wider "
-                            "than 2 bytes", hbins.dtype)
-                obs_counters.event(
-                    "layout_downgrade", stage="grower",
-                    requested="gather_words=on", resolved="off",
-                    reason=f"bin dtype {hbins.dtype} is wider than 2 bytes")
-            use_words = "off"
-        # leaf-ordered mode (OrderedSparseBin analogue,
-        # src/io/ordered_sparse_bin.hpp): a physically leaf-ordered copy of
-        # the histogram matrix (+ weights) rides along with ``order`` — the
-        # partition permutes its windows too, so every smaller-child
-        # histogram reads a CONTIGUOUS slice instead of a random row
-        # gather.  Profitable iff the wide-update scatter costs per index
-        # rather than per element (microprobe scatter_wide_ms); the window
-        # presents rows in exactly the gather's sequence, so trees are
-        # bit-identical either way.
-        use_ordered = cfg.ordered_bins == "on" and pack_plan is None
-        route_from_obins = (use_ordered and hbins is hist_src
-                            and hist_src is bins)
-        if not route_from_obins:
-            # column-major copy of the routing matrix, made once per tree
-            # outside the split loop: each partition branch slices its
-            # split column out of it
-            bins_cm = bins.T
-        if use_ordered:
-            if cfg.gather_words == "on":
-                log.warning("gather_words=on ignored: ordered_bins=on "
-                            "replaces the histogram row gather entirely")
-                obs_counters.event(
-                    "layout_downgrade", stage="grower",
-                    requested="gather_words=on", resolved="off",
-                    reason="ordered_bins=on replaces the row gather")
-            use_words = "off"         # nothing left to gather
-        if cfg.partition_impl == "compact":
-            # the A/B harness must never record scatter numbers labeled
-            # compact — name every silent-degradation condition up front
-            if n >= (1 << 24):
-                log.warning("partition_impl=compact falls back to scatter: "
-                            "%d rows exceed the f32-exact order-id limit "
-                            "(2^24)", n)
-                obs_counters.event(
-                    "layout_downgrade", stage="grower",
-                    requested="partition_impl=compact", resolved="scatter",
-                    reason=f"{n} rows exceed the f32-exact order-id "
-                           "limit (2^24)")
-            if cfg.bucket_min_log2 < 9:
-                log.warning("partition_impl=compact falls back to scatter "
-                            "for buckets below 512 rows "
-                            "(pallas_bucket_min_log2=%d)",
-                            cfg.bucket_min_log2)
-                obs_counters.event(
-                    "layout_downgrade", stage="grower",
-                    requested="partition_impl=compact", resolved="scatter",
-                    reason=f"buckets below 512 rows (bucket_min_log2="
-                           f"{cfg.bucket_min_log2})")
-            if use_ordered and dtype != jnp.float32:
-                log.warning("partition_impl=compact falls back to scatter: "
-                            "ordered_bins payload dtype %s is not float32",
-                            dtype)
-                obs_counters.event(
-                    "layout_downgrade", stage="grower",
-                    requested="partition_impl=compact", resolved="scatter",
-                    reason=f"ordered_bins payload dtype {dtype} is not "
-                           "float32")
-        # gather panel: the histogram's data movement is per-INDEX, not
-        # per-byte (measured 12.6 ns/row for a 28-byte row gather, and the
-        # same class for a single f32 column) — so the three separate
-        # weight gathers per split cost as much as three full row gathers.
-        # Bitcasting the f32 weight columns into the u32 word matrix makes
-        # the whole per-split read ONE row gather ([N, W+3] u32); values
-        # are bit-identical (pure bitcasts).  f32-only (f64 would need two
-        # columns per weight).
-        use_panel = (use_words == "on" and cfg.gather_panel != "off"
-                     and dtype == jnp.float32)
-        if cfg.gather_panel == "on" and not use_panel:
-            log.warning("gather_panel=on ignored: it needs gather_words on "
-                        "and float32 weights (words=%s, dtype=%s)",
-                        use_words, dtype)
-            obs_counters.event(
-                "layout_downgrade", stage="grower",
-                requested="gather_panel=on", resolved="off",
-                reason=f"needs gather_words on and float32 weights "
-                       f"(words={use_words}, dtype={dtype})")
-        # fused-gather histogram rung: the kernel DMAs the indexed panel
-        # rows itself, so the gather-bucket lax.switch (and its pow2
-        # staging buffer) is RETIRED on this path — no ``branches`` are
-        # traced at all.  The layout prerequisites mirror the gather
-        # panel's; anything outside them degrades loudly to an XLA
-        # reference rung (the A/B harness must never record mislabeled
-        # numbers): einsum on TPU (the MXU-shaped form), segment on CPU.
+        # column-major copy of the routing matrix, made once per tree
+        # outside the split loop: each partition branch slices its split
+        # column out of it
+        bins_cm = bins.T
         n_hist_cols = hbins.shape[1]
         use_fused = cfg.hist_method == "fused"
-        fallback_method = fused_fallback_method()
         if use_fused:
-            reason = fused_gate_reason(hbins.dtype, dtype, hist_width,
-                                       use_ordered)
+            # the kernel DMAs the indexed panel rows itself: no gather
+            # bucket ``branches`` are traced, nothing is gathered outside
+            # the kernel.  The method was chosen by resolve_hist_method;
+            # a config that names fused on a layout the kernel cannot
+            # serve is the caller's error, not a second policy here
+            reason = fused_gate_reason(hbins.dtype, dtype, hist_width)
             if reason is not None:
-                log.warning("hist_method=fused unavailable (%s); using the "
-                            "%s reference path", reason, fallback_method)
-                obs_counters.event("layout_downgrade", stage="grower",
-                                   requested="fused",
-                                   resolved=fallback_method,
-                                   reason=reason)
-                use_fused = False
-        base_method = fallback_method if cfg.hist_method == "fused" \
-            else cfg.hist_method
-        if use_fused:
-            # the fused panel subsumes the word/panel gather staging —
-            # nothing is gathered outside the kernel on this path
-            use_words, use_panel = "off", False
+                raise ValueError(
+                    f"hist_method=fused cannot run on this layout: {reason}")
             # rows padded to whole row tiles: the root fetches blocks
             fused_panel, fused_per = pack_fused_panel(
                 hbins_pad, gw_pad, hw_pad, cw_pad,
                 row_multiple=cfg.row_tile)
-        if use_words == "on":
+        # the XLA reference rungs read a split's rows by ONE row gather
+        # where the layout allows it: a gather costs per index, not per
+        # byte (12.6 ns a row for 28 bytes, the same for one f32 column),
+        # so the bin columns packed into u32 words with the three f32
+        # weight columns bitcast beside them ([N, W + 3] u32, pure
+        # bitcasts) are one gather where rows and weights apart are four
+        use_panel = (not use_fused and hbins.dtype.itemsize <= 2
+                     and dtype == jnp.float32)
+        if use_panel:
             hwords_pad, words_per = pack_gather_words(hbins_pad)
-            if use_panel:
-                panel = jnp.concatenate(
-                    [hwords_pad]
-                    + [lax.bitcast_convert_type(w, jnp.uint32)[:, None]
-                       for w in (gw_pad, hw_pad, cw_pad)], axis=1)
-                n_words = hwords_pad.shape[1]
+            n_words = hwords_pad.shape[1]
+            panel = jnp.concatenate(
+                [hwords_pad]
+                + [lax.bitcast_convert_type(w, jnp.uint32)[:, None]
+                   for w in (gw_pad, hw_pad, cw_pad)], axis=1)
 
         # the jax.named_scope names below are baked into the HLO: a device
         # trace attributes the per-split kernels to them (a host span here
@@ -744,7 +688,7 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
 
         def hist_subset(rows, g_, h_, c_, site="split"):
             return subset_histogram(rows, g_, h_, c_, hist_width,
-                                    method=base_method, site=site)
+                                    method=cfg.hist_method, site=site)
 
         def hist_fused_window(order, sstart, scnt):
             """Fused rung: histogram the window [sstart, sstart + scnt) of
@@ -772,12 +716,7 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                                                        jnp.float32)
                               for k in range(3))
                 return hist_subset(rows, g_, h_, c_)
-            if use_words == "on":
-                rows = unpack_gather_words(
-                    hwords_pad.at[idx].get(mode="promise_in_bounds"),
-                    hbins_pad.shape[1], words_per)
-            else:
-                rows = hbins_pad.at[idx].get(mode="promise_in_bounds")
+            rows = hbins_pad.at[idx].get(mode="promise_in_bounds")
             return hist_subset(rows, gw_pad[idx], hw_pad[idx], cw_pad[idx])
 
         def globalize(hist):
@@ -789,15 +728,7 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
 
         def bucket_branch(size):
             def branch(args):
-                order, obins, ow, sstart, scnt = args
-                if use_ordered:
-                    wb = lax.dynamic_slice(
-                        obins, (sstart, 0), (size, obins.shape[1]))
-                    wwt = lax.dynamic_slice(ow, (sstart, 0), (size, 3))
-                    mask = (jnp.arange(size, dtype=jnp.int32)
-                            < scnt).astype(wwt.dtype)
-                    return hist_subset(wb, wwt[:, 0] * mask,
-                                       wwt[:, 1] * mask, wwt[:, 2] * mask)
+                order, sstart, scnt = args
                 idx = lax.dynamic_slice(order, (sstart,), (size,))
                 valid = jnp.arange(size, dtype=jnp.int32) < scnt
                 return measure(jnp.where(valid, idx, n))
@@ -809,180 +740,42 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
 
         # ---- localized partition (DataPartition::Split,
         # data_partition.hpp:94-146).  The reference re-partitions only the
-        # SPLITTING leaf's index range; the same here: each branch slices
-        # the leaf's window out of ``order``, routes just those rows, and
-        # writes the stably-partitioned window back — O(leaf) per split,
-        # not O(N).  Routing decisions follow tree.h:257-313.
+        # SPLITTING leaf's index range; the same here: each branch routes
+        # the split column, then partition_window slices the leaf's window
+        # out of ``order`` and writes it back stably partitioned — O(leaf)
+        # per split, not O(N).  Routing decisions follow tree.h:257-313.
 
         def partition_branch(size):
 
             def branch(args):
                 if cfg.has_categorical:
-                    (order, obins, ow, start, cnt,
+                    (order, start, cnt,
                      feat, thr, dleft, is_cat_l, cat_row) = args
                 else:       # no categorical routing ops traced at all
-                    order, obins, ow, start, cnt, feat, thr, dleft = args
-                win = lax.dynamic_slice(order, (start,), (size,))
-                j = jnp.arange(size, dtype=jnp.int32)
-                valid = j < cnt
-                idx = jnp.where(valid, win, n)
+                    order, start, cnt, feat, thr, dleft = args
+                    is_cat_l = cat_row = None
+                # route the WHOLE split column, then read one bit per
+                # window row: the column is a dense slice of the
+                # column-major copy, its N decisions are elementwise
+                # (0.13 ms a split at 10.5M rows, paid by the smallest
+                # window too), and packed 32 to a word they make a
+                # table of N/8 bytes, small enough to stay on chip
+                # while a rank-1 gather reads it by row id: 8.6 ns an
+                # element on the v5e, where the (row, col) byte gather
+                # this replaces read 20 from HBM, and the column itself
+                # as s32[N], which the grow program also keeps in HBM,
+                # 23.5 (scripts/probe_route_read.py; PERF.md section 5)
+                obs_counters.inc("partition_route_dispatch", read="column")
                 col_idx = feat if meta.col is None else meta.col[feat]
-
-                def route(binf):
-                    return route_goes_left(
-                        binf, meta, feat, thr, dleft,
-                        has_categorical=cfg.has_categorical,
-                        is_cat_l=is_cat_l if cfg.has_categorical else None,
-                        cat_row=cat_row if cfg.has_categorical else None,
-                        max_bin=cfg.max_bin)
-
-                if route_from_obins:
-                    # the splitting column is a strided (not random) read
-                    # of the ordered window — no gather at all
-                    wb = lax.dynamic_slice(
-                        obins, (start, 0), (size, obins.shape[1]))
-                    binf = lax.dynamic_index_in_dim(
-                        wb, col_idx, axis=1, keepdims=False).astype(jnp.int32)
-                    goes_left = route(binf)
-                else:
-                    # route the WHOLE split column, then read one bit per
-                    # window row: the column is a dense slice of the
-                    # column-major copy, its N decisions are elementwise
-                    # (0.13 ms a split at 10.5M rows, paid by the smallest
-                    # window too), and packed 32 to a word they make a
-                    # table of N/8 bytes, small enough to stay on chip
-                    # while a rank-1 gather reads it by row id: 8.6 ns an
-                    # element on the v5e, where the (row, col) byte gather
-                    # this replaces read 20 from HBM, and the column itself
-                    # as s32[N], which the grow program also keeps in HBM,
-                    # 23.5 (scripts/probe_route_read.py; PERF.md section 5)
-                    obs_counters.inc("partition_route_dispatch",
-                                     read="column")
-                    colv = lax.dynamic_index_in_dim(
-                        bins_cm, col_idx, axis=0, keepdims=False)
-                    goes_left = take_row_bits(
-                        pack_row_bits(route(colv.astype(jnp.int32))),
-                        jnp.minimum(idx, n - 1))
-                goes_left = goes_left & valid
-                use_sort = cfg.partition_impl == "sort"
-                # the Pallas compaction kernel needs 512-row blocks, f32-
-                # exact window values (order ids < 2^24) and 32-bit payload
-                # columns; branches outside that contract keep the scatter
-                use_compact = (cfg.partition_impl == "compact"
-                               and size % 512 == 0 and n < (1 << 24)
-                               and (not use_ordered
-                                    or dtype == jnp.float32))
-                def payload_cols():
-                    """Ordered-mode payload marshalling shared by the sort
-                    and compact transports: slice the leaf-ordered windows
-                    and present them as 32/64-bit integer columns (bin
-                    columns packed into u32 words, weights bitcast to the
-                    matching uint)."""
-                    wbl = wb if route_from_obins else lax.dynamic_slice(
-                        obins, (start, 0), (size, obins.shape[1]))
-                    wwt = lax.dynamic_slice(ow, (start, 0), (size, 3))
-                    if wbl.dtype.itemsize <= 2:
-                        wbw, wper = pack_gather_words(wbl)
-                    else:          # rare wide dtype: raw columns
-                        wbw, wper = wbl, None
-                    uint_t = jnp.dtype(f"uint{wwt.dtype.itemsize * 8}")
-                    wtw = lax.bitcast_convert_type(wwt, uint_t)
-                    cols = (tuple(wbw[:, kk] for kk in range(wbw.shape[1]))
-                            + tuple(wtw[:, kk] for kk in range(3)))
-                    return cols, (wbl, wwt, wper, wbw.shape[1])
-
-                def payload_store(obins, ow, newcols, info):
-                    """Inverse of payload_cols: unpack the permuted columns
-                    and write the windows back."""
-                    wbl, wwt, wper, nw = info
-                    swbw = jnp.stack(newcols[:nw], axis=1)
-                    new_wb = (unpack_gather_words(
-                        swbw, wbl.shape[1], wper).astype(wbl.dtype)
-                        if wper is not None else swbw.astype(wbl.dtype))
-                    new_wt = lax.bitcast_convert_type(
-                        jnp.stack(newcols[nw:], axis=1), wwt.dtype)
-                    obins = lax.dynamic_update_slice(
-                        obins, new_wb, (start, 0))
-                    ow = lax.dynamic_update_slice(ow, new_wt, (start, 0))
-                    return obins, ow
-
-                if use_compact:
-                    from .ops.pallas_compact import compact_window
-                    # interpret mode is only ever REQUESTED (CPU tests
-                    # set cfg.hist_interpret), never inferred: on a TPU
-                    # backend the kernel compiles through Mosaic or raises
-                    interp = cfg.hist_interpret
-                    if use_ordered:
-                        payload, info = payload_cols()
-                        new_win, newpay, nl = compact_window(
-                            win, goes_left, valid, payload,
-                            interpret=interp)
-                        obins, ow = payload_store(obins, ow, newpay, info)
-                    else:
-                        new_win, _, nl = compact_window(
-                            win, goes_left, valid, (),
-                            interpret=interp)
-                    order = lax.dynamic_update_slice(order, new_win, (start,))
-                    return order, obins, ow, nl
-                if use_sort:
-                    # stable 3-way key sort: lefts (0) then rights (1);
-                    # past-the-leaf slots (2) are already contiguous at
-                    # the window tail in original order, so a stable sort
-                    # returns them exactly where they started.  XLA:TPU's
-                    # sort network is all vectorized sequential passes —
-                    # no random HBM access, unlike the rank scatter.  In
-                    # ordered mode the leaf-ordered data rides through the
-                    # same sort as extra payload operands (bin columns
-                    # packed into u32 words, weights bitcast to u32).
-                    nl = jnp.sum(goes_left.astype(jnp.int32))
-                    key = jnp.where(~valid, 2,
-                                    jnp.where(goes_left, 0, 1)
-                                    ).astype(jnp.int32)
-                    if use_ordered:
-                        payload, info = payload_cols()
-                        out = lax.sort((key, win, *payload),
-                                       is_stable=True, num_keys=1)
-                        new_win = out[1]
-                        obins, ow = payload_store(obins, ow, out[2:], info)
-                    else:
-                        _, new_win = lax.sort((key, win),
-                                              is_stable=True, num_keys=1)
-                    order = lax.dynamic_update_slice(order, new_win, (start,))
-                    return order, obins, ow, nl
-                c1 = jnp.cumsum(goes_left.astype(jnp.int32))
-                nl = c1[-1]
-                # right-side rank needs cumsum(valid & ~goes_left);
-                # since valid = j < cnt that cumsum is
-                # min(j+1, cnt) - c1 in closed form — one cumsum pass
-                # instead of two
-                c0 = jnp.minimum(j + 1, cnt) - c1
-                # stable two-way rank inside the window; rows past the
-                # leaf (and sentinel padding) keep their own slot so
-                # the write-back leaves neighbors untouched
-                rank = jnp.where(goes_left, c1 - 1, nl + c0 - 1)
-                rank = jnp.where(valid, rank, j)
-                # ONE scatter straight into ``order`` at start + rank —
-                # not a window-local scatter followed by a
-                # dynamic_update_slice write-back.  The read-then-write
-                # interference of the DUS form made XLA:CPU's copy
-                # insertion clone the whole O(N) carrier once per split
-                # (tests/test_grow_jaxpr.py pins the jaxpr against this
-                # class of regression); the direct scatter updates it in
-                # place, and touches the same slots with the same values
-                # so trees are bit-identical.
-                order = order.at[start + rank].set(
-                    win, unique_indices=True, mode="promise_in_bounds")
-                if use_ordered:
-                    # permute the ordered data windows, same ranks
-                    if not route_from_obins:
-                        wb = lax.dynamic_slice(
-                            obins, (start, 0), (size, obins.shape[1]))
-                    wwt = lax.dynamic_slice(ow, (start, 0), (size, 3))
-                    obins = obins.at[start + rank].set(
-                        wb, unique_indices=True, mode="promise_in_bounds")
-                    ow = ow.at[start + rank].set(
-                        wwt, unique_indices=True, mode="promise_in_bounds")
-                return order, obins, ow, nl
+                colv = lax.dynamic_index_in_dim(
+                    bins_cm, col_idx, axis=0, keepdims=False)
+                goes_left = route_goes_left(
+                    colv.astype(jnp.int32), meta, feat, thr, dleft,
+                    has_categorical=cfg.has_categorical,
+                    is_cat_l=is_cat_l, cat_row=cat_row, max_bin=cfg.max_bin)
+                return partition_window(order, start, cnt, size,
+                                        pack_row_bits(goes_left),
+                                        impl=cfg.partition_impl)
             return branch
 
         pbranches = [partition_branch(s) for s in bsizes]
@@ -1002,18 +795,6 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
         order0 = jnp.concatenate(
             [jnp.arange(n, dtype=jnp.int32),
              jnp.full((tail,), n, jnp.int32)])
-        if use_ordered:
-            # rows start in natural order (order0 = iota), so the ordered
-            # copies ARE the inputs; maxbuf tail rows never contribute
-            # (bucket masks zero their weights)
-            obins0 = jnp.concatenate(
-                [hbins, jnp.zeros((maxbuf, hbins.shape[1]), hbins.dtype)])
-            ow0 = jnp.concatenate(
-                [jnp.stack([gw, hw, cw], axis=1),
-                 jnp.zeros((maxbuf, 3), dtype)])
-        else:
-            obins0 = jnp.zeros((0, 0), hbins.dtype)
-            ow0 = jnp.zeros((0, 0), dtype)
         num_logical = meta.num_bin.shape[0]
         feat_ok_all = jnp.ones((num_logical,), bool)
         with jax.named_scope("histogram"):
@@ -1096,10 +877,9 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
             cat_args = ((state.scat[l], state.scatb[l])
                         if cfg.has_categorical else ())
             with jax.named_scope("partition"):
-                order, obins, ow, nl = lax.switch(
+                order, nl = lax.switch(
                     kp, pbranches,
-                    (state.order, state.obins, state.ow, start, cnt,
-                     feat, thr, dleft) + cat_args)
+                    (state.order, start, cnt, feat, thr, dleft) + cat_args)
             nr = cnt - nl
             lsc = state.lsc.at[pair_lr].set(
                 jnp.stack([jnp.stack([start, nl]),
@@ -1160,7 +940,7 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                 else:
                     ki = _bucket_index(scnt, bsizes)
                     hist_small = lax.switch(ki, branches,
-                                            (order, obins, ow, sstart, scnt))
+                                            (order, sstart, scnt))
                 hist_small = globalize(hist_small)
             # the pool's own work under one name: the parent's read, the
             # subtraction and the pair write are [F, B, 3] each, which on a
@@ -1221,11 +1001,11 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                     mode="promise_in_bounds")
             else:
                 scat, scatb = state.scat, state.scatb
-            return _LoopState(i + 1, order, obins, ow, lsc, hist_store,
+            return _LoopState(i + 1, order, lsc, hist_store,
                               feat_ok, sgain, sf32, si32, scat, scatb,
                               tnf, tni, tlf, tli, tcat, tcatb)
 
-        state = _LoopState(jnp.asarray(0, jnp.int32), order0, obins0, ow0,
+        state = _LoopState(jnp.asarray(0, jnp.int32), order0,
                            lsc0, hist_store0, feat_ok_store0,
                            sgain0, sf32_0, si32_0, scat0, scatb0,
                            tnf0, tni0, tlf0, tli0, tcat0, tcatb0)
@@ -1294,7 +1074,7 @@ class StreamedGrower:
     row_leaf)``.  Restrictions (gated loudly in ``boosting``): serial
     single-device, raw-bin layout only (no pack plan / fused panel —
     the per-tree weights those embed cannot be host-pre-packed ahead of
-    the tree), no ordered_bins."""
+    the tree)."""
 
     def __init__(self, cfg: GrowerConfig):
         self.cfg = cfg

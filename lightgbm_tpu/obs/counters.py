@@ -10,7 +10,7 @@ users:
   instead of trusting its label (:func:`observed_kernel`, consumed by
   ``bench.py`` / ``scripts/decide_flips.py``);
 * **layout-downgrade events** — the warn-once fallback paths (fused
-  gate, ``gspmd_hist=fused`` mesh gating, gather_words/panel gating)
+  gate, ``gspmd_hist=fused`` mesh gating)
   also record a ``layout_downgrade`` event with the machine-readable
   reason;
 * **collective accounting** — ``obs/collectives.py`` feeds

@@ -335,7 +335,6 @@ def _pow2_at_least(n: int, floor: int = 1) -> int:
 def predict_hbm(rows: int, features: int, bins: int = 255, leaves: int = 31,
                 num_class: int = 1, bin_bytes: Optional[int] = None,
                 packed_cols: int = 0, valid_rows: int = 0,
-                ordered_bins: bool = False, gather_words: bool = False,
                 bucket_min_log2: int = 6, serving_trees: int = 0,
                 serving_nodes: int = 0, serving_cols: int = 0,
                 serving_bins: int = 0,
@@ -404,8 +403,11 @@ def predict_hbm(rows: int, features: int, bins: int = 255, leaves: int = 31,
         "valid": -(-int(valid_rows) // d) * (features * bin_bytes
                                              + num_class * 4),
     }
-    words_bytes = (-(-features * bin_bytes // 4) + 3) * 4  # [W+3] u32 panel
-    row_bytes = features * bin_bytes + 12                  # bins + g,h,c
+    # a row of the histogram inputs as the grower stages and gathers it:
+    # the [W + 3] u32 word panel for bins of at most 2 bytes, bins + g, h, c
+    # apart for wider ones
+    row_bytes = ((-(-features * bin_bytes // 4) + 3) * 4 if bin_bytes <= 2
+                 else features * bin_bytes + 12)
     # the per-leaf histogram pool [L, F, B, 3] f32 — sharded over the
     # ``feature`` mesh axis (the planner's main lever: this is the
     # component that outgrows a chip first at Epsilon-wide shapes)
@@ -464,19 +466,13 @@ def predict_hbm(rows: int, features: int, bins: int = 255, leaves: int = 31,
         }
     else:
         transients = {
-            # sentinel-padded copy of the histogram inputs (hbins_pad +
-            # the three weight vectors; the word/panel layout on TPU)
-            "staging": (rows_d + 1) * (words_bytes if gather_words
-                                       else row_bytes),
+            # sentinel-padded copy of the histogram inputs
+            "staging": (rows_d + 1) * row_bytes,
             # order [N + maxbuf] i32 + the final row->leaf map [N] i32
             "order_partition": (rows_d + maxbuf) * 4 + rows_d * 4,
             "hist_store": pool_bytes,
             # the pow2 gather buffer for the largest bucket
-            "gather_buffer": maxbuf * (words_bytes if gather_words
-                                       else row_bytes),
-            # leaf-ordered copies ride the carry when ordered_bins=on
-            "ordered_copies": ((rows_d + maxbuf) * row_bytes
-                               if ordered_bins else 0),
+            "gather_buffer": maxbuf * row_bytes,
         }
     if serving_trees > 0:
         # the serving engine's term (docs/SERVING.md): resident SoA node
@@ -499,8 +495,6 @@ def predict_hbm(rows: int, features: int, bins: int = 255, leaves: int = 31,
                    "leaves": leaves, "num_class": num_class,
                    "bin_bytes": bin_bytes, "packed_cols": int(packed_cols),
                    "valid_rows": int(valid_rows),
-                   "ordered_bins": bool(ordered_bins),
-                   "gather_words": bool(gather_words),
                    "data_shards": d, "feature_shards": fs,
                    "block_shard_bins": bool(block_shard_bins),
                    "gspmd_fused": bool(gspmd_fused),

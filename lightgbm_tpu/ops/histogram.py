@@ -56,8 +56,8 @@ def _maybe_inject_hist_fault(method: str, site: str) -> None:
 
 
 def on_tpu() -> bool:
-    """Whether the default jax backend is a TPU (shared platform probe —
-    hist-method and gather-words 'auto' resolution must agree)."""
+    """Whether the default jax backend is a TPU (the one platform probe:
+    every decision that depends on the chip asks here)."""
     return any(d.platform == "tpu" for d in jax.devices())
 
 

@@ -277,8 +277,8 @@ def _hist_kernel_fused(sc_ref, order_ref, panel_ref, out_ref,
             # pad.  pl.ds(r, 1) keeps the slice's row dim: integer .at[r]
             # indexing squeezes it and that squeeze is what the LLO
             # lowering choked on ("dynamic_dim_it != dynamic_sizes.end()",
-            # v5e AOT probe) — the compact kernel's proven dynamic-offset
-            # DMAs are all pl.ds-shaped.  One descriptor a row: its 512 B
+            # v5e AOT probe); a dynamic offset lowers where the DMA's slice
+            # is pl.ds-shaped.  One descriptor a row: its 512 B
             # from every column tile.  Unrolled by hand: Mosaic's
             # ``fori_loop`` takes ``unroll`` 1 or the whole trip count.
             for k in range(ISSUE_UNROLL):

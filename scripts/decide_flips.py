@@ -43,25 +43,13 @@ def _load_obs_diff():
 FLIPS = [
     # INVERTED pair: the headline bench_1m.json is the tpu+fused number
     # (the default ladder tries fused first), so this artifact is the
-    # forced-XLA side — LOSE here means the fused kernel won and
-    # pallas_fused flips auto->on in config.py/boosting.py
+    # forced-XLA side — LOSE here means the fused kernel won and stays
+    # what use_pallas runs on the chip
     ("bench_1m_xla.json", "BENCH_FUSED=0 (XLA einsum rung forced)",
-     "if this LOSES >=5% to the headline, flip pallas_fused auto->on "
-     "(config.py) — the fused kernel becomes the TPU default", None),
-    ("bench_1m_ordered_sort.json", "ordered_bins=on + partition_impl=sort",
-     "flip BOTH autos in boosting.py", None),
-    ("bench_1m_compact.json", "partition_impl=compact",
-     "partition_impl auto->compact on TPU", None),
-    ("bench_1m_compact_ordered.json", "compact + ordered_bins",
-     "flip both if this beats every other combo", None),
-    ("bench_1m_ordered.json", "ordered_bins=on", "ordered_bins auto->on",
-     None),
+     "if this LOSES >=5% to the headline, the fused kernel stays the "
+     "TPU default (use_pallas=true)", None),
     ("bench_1m_sortpart.json", "partition_impl=sort",
      "partition_impl auto->sort", None),
-    ("bench_1m_nowords.json", "gather_words=off",
-     "gather_words auto->off on TPU if OFF wins (panel rides words)", None),
-    ("bench_1m_nopanel.json", "gather_panel=off",
-     "keep gather_panel auto-on unless OFF wins", None),
     ("bench_1m_pow15.json", "bucket_scheme=pow15",
      "bucket_scheme auto->pow15", None),
     ("bench_sparse_nopack.json", "enable_bin_packing=false",
@@ -480,7 +468,7 @@ def main():
         print("microprobe decomposition:",
               {k: round(mp[k], 3) for k in
                ("grow_per_split_fixed_ms", "grow_per_mrow_ms", "grow_ms",
-                "partition_compact_ms", "partition_sort_ms",
+                "partition_sort_ms",
                 "partition_window_opt_ms", "gather_panel_ms",
                 "gather_words_plus3_ms") if k in mp})
 

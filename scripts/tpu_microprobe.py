@@ -143,7 +143,7 @@ def main():
 
     # 4b2. gather panel (round 5): ONE [N, W+3] u32 row gather vs the word
     # gather PLUS three separate f32 column gathers — prices exactly what
-    # gather_panel removes from every split
+    # the panel removes from every split of the XLA reference rungs
     from jax import lax as _lax
     # three DISTINCT arrays, like the grower's gw/hw/cw — identical
     # operands would be CSE'd into one gather and underprice this side
@@ -169,7 +169,7 @@ def main():
     # gather_rows_words_ms (scaled by m/rows) + hist_ms[m] — the fused
     # kernel folds both into one dispatch with no staging buffer.  TPU
     # only: interpret-mode timings mean nothing, and a Mosaic rejection
-    # here is itself evidence (recorded, like the compact probe).
+    # here is itself evidence (recorded).
     if res["platform"] == "tpu":
         stage["name"] = "hist_fused"
         try:
@@ -238,8 +238,7 @@ def main():
     # the window as payload IS the stable partition, and XLA:TPU's sort
     # network does only vectorized sequential memory passes — no random
     # HBM access at all.  If this beats the rank scatter, the partition
-    # leaves the per-element-random cost class entirely (and can carry
-    # the ordered-mode data words as extra payload operands).
+    # leaves the per-element-random cost class entirely.
     from jax import lax
 
     def part_sort(ord_, gl):
@@ -251,27 +250,6 @@ def main():
         lambda: part_sort_fn(order, goes_left), n=5) * 1e3
     print(f"partition via stable sort {res['partition_sort_ms']:.1f} ms",
           file=sys.stderr, flush=True)
-
-    # 4f. Pallas compaction kernel head-to-head with scatter/sort (round-5
-    # candidate; ~5 ns/row projected).  TPU only: off-chip it would run in
-    # interpret mode and time nothing real.
-    if res["platform"] == "tpu":
-        try:
-            from lightgbm_tpu.ops.pallas_compact import compact_window
-            nn = n // 512 * 512
-            ordc, glc = order[:nn], goes_left[:nn]
-            validc = jnp.ones((nn,), bool)
-            comp_fn = jax.jit(lambda o, gl, v: compact_window(
-                o, gl & v, v, ())[0])
-            res["partition_compact_ms"] = _t(
-                lambda: comp_fn(ordc, glc, validc), n=5) * 1e3
-            print(f"partition via compact kernel "
-                  f"{res['partition_compact_ms']:.1f} ms",
-                  file=sys.stderr, flush=True)
-        except Exception as e:          # Mosaic rejection is itself evidence
-            res["partition_compact_error"] = str(e)[:300]
-            print(f"compact kernel probe failed: {e}",
-                  file=sys.stderr, flush=True)
 
     def part_opt(ord_, gl):
         # the production form after the round-4 retune: one cumsum

@@ -102,6 +102,82 @@ def test_wide_layout_resolves_to_the_fused_kernel(monkeypatch):
     assert bst.inner.models[0].num_leaves > 1
 
 
+@pytest.mark.parametrize("chip,use_pallas,cpu_method,bins,weights,width,want,why", [
+    # the chip's default layout: the fused kernel, nothing to say
+    (True, True, "segment", "uint8", "float32", 255, "fused", None),
+    # ROADMAP M2: 300 bins do not factor into two nibbles
+    (True, True, "segment", "uint16", "float32", 300, "einsum",
+     "exceeds the nibble-factorized limit 256"),
+    (True, True, "segment", "int32", "float32", 255, "einsum",
+     "bin dtype int32 is wider than 2 bytes"),
+    (True, True, "segment", "uint8", "float64", 255, "einsum",
+     "weights dtype float64 is not float32"),
+    # use_pallas=false: the one way to force the XLA reference on the chip
+    (True, False, "segment", "uint8", "float32", 255, "einsum", None),
+    # off the chip cpu_hist_method is the method; tests put the
+    # interpreted kernel there, behind the same gate
+    (False, True, "segment", "uint8", "float32", 255, "segment", None),
+    (False, True, "fused", "uint8", "float32", 255, "fused", None),
+    (False, True, "fused", "uint16", "float32", 300, "segment",
+     "exceeds the nibble-factorized limit 256"),
+])
+def test_hist_method_is_resolved_in_one_table(monkeypatch, chip, use_pallas,
+                                              cpu_method, bins, weights,
+                                              width, want, why):
+    from lightgbm_tpu import grower
+    monkeypatch.setattr(grower, "on_tpu", lambda: chip)
+    method, reason = grower.resolve_hist_method(
+        use_pallas, cpu_method, np.dtype(bins), np.dtype(weights), width)
+    assert method == want
+    assert (reason is None) if why is None else (why in reason), reason
+
+
+def test_fused_config_on_a_refused_layout_raises_the_gates_reason():
+    """``make_grower`` holds no second policy: a ``GrowerConfig`` that
+    names the fused kernel on a layout the gate refuses is an error that
+    says why, not a silent other method."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.grower import FeatureMeta, GrowerConfig, make_grower
+    n, f, b = 256, 3, 300
+    cfg = GrowerConfig(num_leaves=4, max_bin=b, hist_method="fused",
+                       hist_interpret=True)
+    meta = FeatureMeta(num_bin=jnp.full((f,), b, jnp.int32),
+                       missing_type=jnp.zeros((f,), jnp.int32),
+                       default_bin=jnp.zeros((f,), jnp.int32),
+                       is_categorical=jnp.zeros((f,), bool))
+    one = jnp.ones((n,), jnp.float32)
+    with pytest.raises(ValueError, match="hist_method=fused cannot run on "
+                       "this layout: histogram width 300 exceeds"):
+        jax.jit(make_grower(cfg))(jnp.zeros((n, f), jnp.uint16), one, one,
+                                  one, meta, jnp.ones((f,), bool))
+
+
+def test_refused_layout_trains_on_the_reference_with_one_event():
+    """300 bins through ``lgb.train`` with the fused kernel asked for: the
+    one ``layout_downgrade`` comes from the booster's set-up, names the
+    gate's reason, and ``grower_cfg.hist_method`` names what ran."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.obs.counters import counters
+    counters.reset()
+    rng = np.random.RandomState(1)
+    X = rng.randn(2000, 3)
+    y = (X[:, 0] > 0).astype(np.float64)
+    bst = lgb.train({"objective": "binary", "num_leaves": 4, "verbose": -1,
+                     "max_bin": 300, "min_data_in_bin": 1,
+                     "cpu_hist_method": "fused"},
+                    lgb.Dataset(X, label=y), num_boost_round=2,
+                    verbose_eval=False)
+    assert bst.inner.grower_cfg.hist_method == "segment"
+    evs = counters.events("layout_downgrade")
+    assert [(e["stage"], e["requested"], e["resolved"]) for e in evs] == [
+        ("boosting", "fused", "segment")]
+    assert "nibble-factorized limit" in evs[0]["reason"]
+    assert set(counters.get("hist_dispatch")) == {
+        f"interpret=False,method=segment,site={site}"
+        for site in ("root", "split")}
+
+
 def test_native_library_with_another_source_hash_is_rebuilt(tmp_path,
                                                             monkeypatch):
     from lightgbm_tpu import native

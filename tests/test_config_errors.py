@@ -20,7 +20,13 @@ from lightgbm_tpu.config import config_from_params
     ({"boosting": "rf"}, "bagging"),
     ({"max_bin": 100000}, "max_bin"),
     ({"pallas_row_tile": 100}, "multiple of 128"),
-    ({"gather_words": "maybe"}, "gather_words"),
+    # keys that selected a path that is gone (PR 29): rejected like any
+    # other unknown key, not accepted and ignored
+    ({"gather_words": "maybe"}, "Unknown parameter: gather_words"),
+    ({"gather_panel": "on"}, "Unknown parameter: gather_panel"),
+    ({"ordered_bins": "on"}, "Unknown parameter: ordered_bins"),
+    ({"pallas_fused": "off"}, "Unknown parameter: pallas_fused"),
+    ({"hist_dtype": "float32"}, "Unknown parameter: hist_dtype"),
     ({"gspmd_hist": "scatter"}, "gspmd_hist"),
     ({"metric": "made_up_metric", "objective": "binary"}, "metric"),
 ])

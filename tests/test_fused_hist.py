@@ -355,18 +355,17 @@ def test_grower_255_leaf_tree_identical_across_rungs():
         np.testing.assert_array_equal(t_seg.leaf_value, t.leaf_value)
 
 
-def test_fused_warns_and_falls_back_on_wide_bins():
-    """A > 2-byte bin matrix cannot word-pack: the grower must degrade
-    loudly to the XLA reference rung, not crash or mislabel."""
+def test_fused_config_on_wide_bins_raises():
+    """A > 2-byte bin matrix cannot word-pack.  Which method such a layout
+    trains with is ``resolve_hist_method``'s to say (the XLA reference, with
+    one ``layout_downgrade`` from the booster's set-up); a ``GrowerConfig``
+    that names the fused kernel on it all the same is an error that carries
+    the gate's reason, not a second, silent choice at trace time."""
     n, f, b = 1500, 6, 63
     bins, g, h, c = _problem(n, f, b, seed=29, dtype=np.int32)
-    c[:] = 1.0
-    t_seg, _ = _grow_tree_strings("segment", bins, g, h, c, b)
-    # fused request on an unfusable layout: falls back to the XLA
-    # reference (segment on this CPU host, einsum on TPU)
-    t_fus, _ = _grow_tree_strings("fused", bins, g, h, c, b)
-    np.testing.assert_array_equal(t_seg.split_feature, t_fus.split_feature)
-    np.testing.assert_array_equal(t_seg.threshold_bin, t_fus.threshold_bin)
+    with pytest.raises(ValueError, match="hist_method=fused cannot run on "
+                       "this layout: bin dtype int32 is wider than 2 bytes"):
+        _grow_tree_strings("fused", bins, g, h, c, b)
 
 
 def test_hist_block_fetch_metric_reads_the_fetch_tag():

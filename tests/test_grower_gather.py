@@ -1,8 +1,6 @@
-"""Word-packed histogram gather (`gather_words`) — the TPU gather-cost
-optimization must be bit-neutral: packing 4 uint8 (2 uint16) bin columns
-per gathered uint32 word changes data movement only, never the histogram,
-the tree, or the row→leaf map.  Off-TPU the 'auto' knob resolves to 'off',
-so this is the only coverage the words path gets without a chip."""
+"""The grower's row movement: the word packing the XLA reference rungs
+gather through, and the partition's routing read against a plain numpy
+router that knows nothing of how the grower moves rows."""
 import numpy as np
 import pytest
 
@@ -30,91 +28,8 @@ def test_pack_rejects_wide_dtypes():
         pack_gather_words(jnp.zeros((4, 4), jnp.int32))
 
 
-def test_grow_words_on_off_identical():
-    rng = np.random.RandomState(11)
-    n, f, b = 6000, 9, 47
-    bins = jnp.asarray(rng.randint(0, b, size=(n, f), dtype=np.uint8))
-    g = jnp.asarray(rng.randn(n).astype(np.float32))
-    h = jnp.asarray(np.ones(n, np.float32))
-    c = jnp.asarray(np.ones(n, np.float32))
-    meta = FeatureMeta(num_bin=jnp.full((f,), b, jnp.int32),
-                       missing_type=jnp.zeros((f,), jnp.int32),
-                       default_bin=jnp.zeros((f,), jnp.int32),
-                       is_categorical=jnp.zeros((f,), bool))
-    fv = jnp.ones((f,), bool)
-    outs = {}
-    for words in ("off", "on"):
-        cfg = GrowerConfig(num_leaves=31, min_data_in_leaf=1, max_bin=b,
-                           hist_method="segment", bucket_min_log2=6,
-                           gather_words=words)
-        tree, row_leaf = jax.jit(make_grower(cfg))(bins, g, h, c, meta, fv)
-        outs[words] = jax.tree.map(np.asarray, (tree, row_leaf))
-    ref_tree, ref_rl = outs["off"]
-    got_tree, got_rl = outs["on"]
-    for a, bb in zip(ref_tree, got_tree):
-        assert np.array_equal(a, bb)
-    assert np.array_equal(ref_rl, got_rl)
-    # row_leaf really is a leaf id per row consistent with leaf counts
-    num_leaves = int(ref_tree.num_leaves)
-    counts = np.bincount(ref_rl, minlength=num_leaves)
-    assert counts.sum() == n
-    assert np.array_equal(
-        np.sort(counts[:num_leaves]),
-        np.sort(ref_tree.leaf_count[:num_leaves].astype(np.int64)))
-
-
-def test_grow_ordered_bins_identical():
-    """ordered_bins=on maintains a leaf-ordered data copy whose windows
-    present rows in exactly the gather path's sequence — trees and
-    row_leaf must be bit-identical to the gather path."""
-    rng = np.random.RandomState(7)
-    n, f, b = 6000, 9, 47
-    bins = jnp.asarray(rng.randint(0, b, size=(n, f), dtype=np.uint8))
-    g = jnp.asarray(rng.randn(n).astype(np.float32))
-    h = jnp.asarray(np.ones(n, np.float32))
-    c = jnp.asarray(np.ones(n, np.float32))
-    meta = FeatureMeta(num_bin=jnp.full((f,), b, jnp.int32),
-                       missing_type=jnp.zeros((f,), jnp.int32),
-                       default_bin=jnp.zeros((f,), jnp.int32),
-                       is_categorical=jnp.zeros((f,), bool))
-    fv = jnp.ones((f,), bool)
-    outs = {}
-    for mode in ("off", "on"):
-        cfg = GrowerConfig(num_leaves=31, min_data_in_leaf=1, max_bin=b,
-                           hist_method="segment", bucket_min_log2=6,
-                           ordered_bins=mode)
-        tree, row_leaf = jax.jit(make_grower(cfg))(bins, g, h, c, meta, fv)
-        outs[mode] = jax.tree.map(np.asarray, (tree, row_leaf))
-    for a, bb in zip(outs["off"][0], outs["on"][0]):
-        assert np.array_equal(a, bb)
-    assert np.array_equal(outs["off"][1], outs["on"][1])
-
-
-def test_grow_ordered_bins_identical_efb_end_to_end():
-    """ordered_bins through the full training stack with EFB bundles and
-    bagging: model text must match the gather path exactly."""
-    import lightgbm_tpu as lgb
-    rng = np.random.RandomState(8)
-    n = 3000
-    dense = rng.randn(n, 4)
-    onehot = (rng.rand(n, 12) < 0.06).astype(np.float64) \
-        * rng.randint(1, 4, size=(n, 12))
-    X = np.concatenate([dense, onehot], axis=1)
-    y = (dense[:, 0] + (onehot[:, 3] > 0) + 0.2 * rng.randn(n) > 0.4)
-    y = y.astype(np.float64)
-    texts = {}
-    for mode in ("off", "on"):
-        params = {"objective": "binary", "num_leaves": 15, "verbose": -1,
-                  "min_data_in_leaf": 5, "bagging_fraction": 0.8,
-                  "bagging_freq": 1, "seed": 7, "ordered_bins": mode,
-                  "enable_bin_packing": False}
-        bst = lgb.train(params, lgb.Dataset(X, label=y), num_boost_round=8)
-        texts[mode] = bst.model_to_string()
-    assert texts["off"] == texts["on"]
-
-
 def test_grow_partition_sort_identical():
-    """partition_impl=sort (stable 3-way-key payload sort) must reproduce
+    """partition_impl=sort (one stable sort on a 3-way key) must reproduce
     the rank-scatter partition bit for bit, including past-the-leaf window
     slots returning to their original positions."""
     rng = np.random.RandomState(9)
@@ -140,39 +55,9 @@ def test_grow_partition_sort_identical():
     assert np.array_equal(outs["scatter"][1], outs["sort"][1])
 
 
-def test_grow_partition_sort_with_ordered_bins_identical():
-    """sort partition carrying the leaf-ordered payloads (packed bin words
-    + bitcast weights) must match the scatter+gather baseline bit for bit."""
-    rng = np.random.RandomState(10)
-    n, f, b = 6000, 9, 47
-    bins = jnp.asarray(rng.randint(0, b, size=(n, f), dtype=np.uint8))
-    g = jnp.asarray(rng.randn(n).astype(np.float32))
-    h = jnp.asarray(np.abs(rng.randn(n)).astype(np.float32))
-    c = jnp.asarray(np.ones(n, np.float32))
-    meta = FeatureMeta(num_bin=jnp.full((f,), b, jnp.int32),
-                       missing_type=jnp.zeros((f,), jnp.int32),
-                       default_bin=jnp.zeros((f,), jnp.int32),
-                       is_categorical=jnp.zeros((f,), bool))
-    fv = jnp.ones((f,), bool)
-    outs = {}
-    for ordered, impl in (("off", "scatter"), ("on", "sort")):
-        cfg = GrowerConfig(num_leaves=31, min_data_in_leaf=1, max_bin=b,
-                           hist_method="segment", bucket_min_log2=6,
-                           ordered_bins=ordered, partition_impl=impl)
-        tree, row_leaf = jax.jit(make_grower(cfg))(bins, g, h, c, meta, fv)
-        outs[(ordered, impl)] = jax.tree.map(np.asarray, (tree, row_leaf))
-    ref = outs[("off", "scatter")]
-    got = outs[("on", "sort")]
-    for a, bb in zip(ref[0], got[0]):
-        assert np.array_equal(a, bb)
-    assert np.array_equal(ref[1], got[1])
-
-
-@pytest.mark.parametrize("ordered,impl", [("off", "sort"), ("on", "sort")])
-def test_grow_missing_routing_ordered_sort(ordered, impl):
-    """NaN- and zero-missing routing decisions must survive the ordered /
-    sort paths bit for bit (default_left handling happens on the routing
-    column read, which differs per path)."""
+def test_grow_missing_routing_sort():
+    """NaN- and zero-missing routing decisions must survive the sort
+    transport bit for bit."""
     import lightgbm_tpu as lgb
     rng = np.random.RandomState(12)
     n = 4000
@@ -184,7 +69,7 @@ def test_grow_missing_routing_ordered_sort(ordered, impl):
             "min_data_in_leaf": 5, "use_missing": True,
             "enable_bin_packing": False}
     ref = lgb.train(dict(base), lgb.Dataset(X, label=y), num_boost_round=5)
-    got = lgb.train(dict(base, ordered_bins=ordered, partition_impl=impl),
+    got = lgb.train(dict(base, partition_impl="sort"),
                     lgb.Dataset(X, label=y), num_boost_round=5)
     assert ref.model_to_string() == got.model_to_string()
 
@@ -202,27 +87,6 @@ def test_grow_bucket_scheme_pow15_identical():
     got = lgb.train(dict(base, bucket_scheme="pow15"),
                     lgb.Dataset(X, label=y), num_boost_round=5)
     assert ref.model_to_string() == got.model_to_string()
-
-
-def test_grow_gather_panel_identical():
-    """Folding the bitcast weight columns into the word gather (one row
-    gather per split) moves identical bits — trees bit-identical with the
-    panel on or off, with and without bagging weights."""
-    import lightgbm_tpu as lgb
-    rng = np.random.RandomState(31)
-    n = 4000
-    X = rng.randn(n, 6)
-    y = (X[:, 0] + 0.4 * rng.randn(n) > 0).astype(float)
-    for extra in ({}, {"bagging_fraction": 0.7, "bagging_freq": 1}):
-        base = {"objective": "binary", "num_leaves": 15, "verbose": -1,
-                "min_data_in_leaf": 5, "gather_words": "on",
-                "enable_bin_packing": False}
-        base.update(extra)
-        ref = lgb.train(dict(base, gather_panel="off"),
-                        lgb.Dataset(X, label=y), num_boost_round=4)
-        got = lgb.train(dict(base, gather_panel="on"),
-                        lgb.Dataset(X, label=y), num_boost_round=4)
-        assert ref.model_to_string() == got.model_to_string(), extra
 
 
 # ---- the routing read against a plain numpy router -------------------------
@@ -243,31 +107,50 @@ def _route_case(kind):
     if kind == "numeric_missing":
         X = rng.randn(n, 5)
         X[rng.rand(n, 5) < 0.1] = np.nan
+    elif kind in ("nan_missing", "zero_missing"):
+        # the label rises with every column; the rows that miss column 0
+        # are mostly positives (they belong right of any threshold) and
+        # those that miss column 1 mostly negatives (they belong left)
+        X = rng.randn(n, 4)
+        lab = X.sum(axis=1) + 0.5 * rng.randn(n) > 0
+        hole = np.nan if kind == "nan_missing" else 0.0
+        X[(rng.rand(n) < np.where(lab, 0.45, 0.03)), 0] = hole
+        X[(rng.rand(n) < np.where(lab, 0.03, 0.45)), 1] = hole
+        params["zero_as_missing"] = kind == "zero_missing"
+        y = lab.astype(np.float64)
     elif kind == "categorical":
         X = np.concatenate(
             [rng.randn(n, 2),
              rng.randint(0, 12, size=(n, 2)).astype(np.float64)], axis=1)
         cat = [2, 3]
     elif kind == "efb":
-        sparse = np.zeros((n, 10))
-        act = np.where(rng.rand(n) < 0.5)[0]    # mutually exclusive columns
-        sparse[act, rng.randint(0, 10, size=act.size)] = rng.randint(
-            1, 4, size=act.size)
-        X = np.concatenate([rng.randn(n, 3), sparse], axis=1)
+        X = _bundled_columns(rng, n)
     elif kind == "uint16":
         X = rng.randn(n, 3)
         params.update(max_bin=400, min_data_in_bin=1)
-    y = (np.nan_to_num(X[:, 0]) + 0.7 * np.nan_to_num(X[:, -1])
-         + 0.3 * rng.randn(n) > 0.3).astype(np.float64)
+    if kind not in ("nan_missing", "zero_missing"):
+        y = (np.nan_to_num(X[:, 0]) + 0.7 * np.nan_to_num(X[:, -1])
+             + 0.3 * rng.randn(n) > 0.3).astype(np.float64)
     td = lgb.Dataset(X, label=y, params=params,
                      categorical_feature=cat).construct().constructed
     return td.binned, td.feature_meta(), td.max_num_bin(), y
 
 
-def _numpy_route(bins, fm, tree, num_leaves):
+def _bundled_columns(rng, n):
+    sparse = np.zeros((n, 10))
+    act = np.where(rng.rand(n) < 0.5)[0]        # mutually exclusive columns
+    sparse[act, rng.randint(0, 10, size=act.size)] = rng.randint(
+        1, 4, size=act.size)
+    return np.concatenate([rng.randn(n, 3), sparse], axis=1)
+
+
+def _numpy_route(bins, fm, tree, num_leaves, weight=None):
     """Replay the splits in node order: node i splits the leaf that keeps
-    its id on the left and opens leaf i + 1 on the right."""
+    its id on the left and opens leaf i + 1 on the right.  Returns every
+    split's left count (the sum of ``weight``, a row each by default) and
+    the row -> leaf map."""
     n = bins.shape[0]
+    weight = np.ones(n) if weight is None else np.asarray(weight, np.float64)
     row_leaf = np.zeros(n, np.int32)
     left_counts = []
     for node in range(num_leaves - 1):
@@ -293,12 +176,25 @@ def _numpy_route(bins, fm, tree, num_leaves):
                 else np.zeros(len(b), bool)
             left = np.where(missing, bool(tree.default_left[node]),
                             b <= int(tree.threshold_bin[node]))
-        left_counts.append(int(left.sum()))
+        left_counts.append(int(round(weight[rows[left]].sum())))
         row_leaf[rows[~left]] = node + 1
     return left_counts, row_leaf
 
 
-@pytest.mark.parametrize("kind", ["numeric_missing", "categorical", "efb",
+def _assert_routed_like_numpy(bins, fm, tree, row_leaf, weight=None, tag=""):
+    tree = jax.tree.map(np.asarray, tree)
+    num_leaves = int(tree.num_leaves)
+    left_counts, want_leaf = _numpy_route(bins, fm, tree, num_leaves, weight)
+    for node, cnt in enumerate(left_counts):
+        lc = int(tree.left_child[node])
+        got = tree.internal_count[lc] if lc >= 0 else tree.leaf_count[~lc]
+        assert int(got) == cnt, (tag, node, int(got), cnt)
+    assert np.array_equal(np.asarray(row_leaf), want_leaf), tag
+    return tree, num_leaves
+
+
+@pytest.mark.parametrize("kind", ["numeric_missing", "nan_missing",
+                                  "zero_missing", "categorical", "efb",
                                   "uint16"])
 def test_grow_routes_like_plain_numpy_router(kind):
     from lightgbm_tpu.obs.counters import counters
@@ -321,19 +217,56 @@ def test_grow_routes_like_plain_numpy_router(kind):
     # it was built with
     assert counters.snapshot()["counters"]["partition_route_dispatch"][
         "read=column"] > before
-    tree = jax.tree.map(np.asarray, tree)
-    num_leaves = int(tree.num_leaves)
+    tree, num_leaves = _assert_routed_like_numpy(bins, fm, tree, row_leaf,
+                                                 tag=kind)
     assert num_leaves > 8
+    nodes = slice(0, num_leaves - 1)
     if kind == "categorical":
-        assert tree.is_cat[:num_leaves - 1].any()
+        assert tree.is_cat[nodes].any()
     if kind == "efb":
-        assert (fm["offset"][tree.split_feature[:num_leaves - 1]] >= 0).any()
-    left_counts, want_leaf = _numpy_route(bins, fm, tree, num_leaves)
-    for node, cnt in enumerate(left_counts):
-        lc = int(tree.left_child[node])
-        got = tree.internal_count[lc] if lc >= 0 else tree.leaf_count[~lc]
-        assert int(got) == cnt, (kind, node, int(got), cnt)
-    assert np.array_equal(np.asarray(row_leaf), want_leaf)
+        assert (fm["offset"][tree.split_feature[nodes]] >= 0).any()
+    if kind in ("nan_missing", "zero_missing"):
+        # both answers to "where do the missing rows go" were taken, on
+        # columns that have missing rows
+        mt = 2 if kind == "nan_missing" else 1
+        on_missing = fm["missing_type"][tree.split_feature[nodes]] == mt
+        assert (tree.default_left[nodes] & on_missing).any()
+        assert (~tree.default_left[nodes] & on_missing).any()
+
+
+def test_bagged_bundled_training_routes_like_plain_numpy_router():
+    """EFB bundles and ``bagging_fraction`` end to end: every call that
+    ``GBDT.train_one_iter`` makes into the grower, with the matrix and the
+    bag weights it really passes, is replayed by the numpy router."""
+    import lightgbm_tpu as lgb
+    rng = np.random.RandomState(8)
+    n = 3000
+    X = _bundled_columns(rng, n)
+    y = (X[:, 0] + (X[:, 6] > 0) + 0.2 * rng.randn(n) > 0.4).astype(np.float64)
+    bst = lgb.Booster(
+        {"objective": "binary", "num_leaves": 15, "verbose": -1,
+         "min_data_in_leaf": 5, "bagging_fraction": 0.8, "bagging_freq": 1,
+         "seed": 7, "enable_bin_packing": False},
+        lgb.Dataset(X, label=y))
+    gbdt = bst.inner
+    grow, calls = gbdt.grow, []
+
+    def recording(bins, gw, hw, cw, meta, feat_valid):
+        out = grow(bins, gw, hw, cw, meta, feat_valid)
+        calls.append((np.asarray(bins), np.asarray(cw), out))
+        return out
+
+    gbdt.grow = recording
+    for _ in range(4):
+        bst.update()
+    gbdt.grow = grow
+    fm = gbdt.train_set.feature_meta()
+    assert "col" in fm and len(calls) == 4
+    for i, (bins, cw, (tree, row_leaf)) in enumerate(calls):
+        assert 0 < cw.sum() < n or bins.shape[0] < n    # a bag was drawn
+        _, num_leaves = _assert_routed_like_numpy(bins, fm, tree, row_leaf,
+                                                  weight=cw, tag=i)
+        assert num_leaves > 4
 
 
 # ---- the bit tables the routing read is made of ----------------------------

@@ -617,20 +617,14 @@ def test_xentlambda_metric_value_parity(ref_bin, tmp_path):
 
 
 @pytest.mark.parametrize("knobs", [
-    # leaf-ordered matrix + Pallas compaction partition (ordered mode
-    # forces the gather path off, so words/panel are covered separately)
-    {"ordered_bins": "on", "partition_impl": "compact",
-     "bucket_scheme": "pow15"},
-    # word gathers + weight panel + payload-sort partition
-    {"gather_words": "on", "gather_panel": "on", "partition_impl": "sort",
-     "bucket_scheme": "pow15"},
+    # the sort transport of the partition on the 1.5 * 2^k bucket table
+    {"partition_impl": "sort", "bucket_scheme": "pow15"},
 ])
 def test_perf_knob_matrix_training_parity(ref_bin, tmp_path, knobs):
-    """The round-4/5 data-movement knobs (leaf-ordered matrix, Pallas
-    compaction partition, pow15 buckets, word gathers + weight panel)
-    are bit-neutral all the way to the reference: a model trained with
-    the knobs engaged predicts within the oracle envelope of the
-    reference CLI's."""
+    """The data-movement knobs that are left (sort partition, pow15
+    buckets) are bit-neutral all the way to the reference: a model
+    trained with the knobs engaged predicts within the oracle envelope of
+    the reference CLI's."""
     data_path = "/root/reference/examples/binary_classification/binary.train"
     if not os.path.exists(data_path):
         pytest.skip("reference example data missing")

@@ -10,9 +10,8 @@ target with NO device attached — real Mosaic lowering, the exact
 failure class interpret mode cannot see.  (Numerics still need the
 chip: the on-chip tier in test_tpu.py remains the execution proof.)
 
-Proven value: the first offline run of these caught the compact
-kernel's unaligned output-DMA width ("Slice shape along dimension 1
-must be aligned to tiling (128)") that all interpret-mode tests passed.
+Proven value: offline runs of these caught lowering failures that every
+interpret-mode test passed (``test_fused_hist_kernel_lowers`` lists five).
 """
 import os
 
@@ -114,17 +113,6 @@ def test_fused_grower_lowers(v5e):
                meta, v5e((f,), jnp.bool_)).compile()
 
 
-@pytest.mark.parametrize("npay", [0, 8, 10])
-def test_compact_kernel_lowers(v5e, npay):
-    import jax.numpy as jnp
-    from lightgbm_tpu.ops.pallas_compact import compact_window
-    size = 1 << 15
-    fn = jax.jit(lambda w, g, v, p: compact_window(w, g, v, p))
-    fn.lower(v5e((size,), jnp.int32), v5e((size,), jnp.bool_),
-             v5e((size,), jnp.bool_),
-             tuple(v5e((size,), jnp.uint32) for _ in range(npay))).compile()
-
-
 FULL_GROWER_PROOFS = pytest.mark.skipif(
     os.environ.get("LGBM_TPU_AOT_FULL") != "1",
     reason="~25 min of uncacheable XLA:TPU AOT compiles; run with "
@@ -134,12 +122,10 @@ FULL_GROWER_PROOFS = pytest.mark.skipif(
 
 @FULL_GROWER_PROOFS
 @pytest.mark.parametrize("knobs", [
-    {"gather_words": "on", "gather_panel": "auto"},          # TPU defaults
-    {"ordered_bins": "on", "partition_impl": "sort"},
-    {"partition_impl": "compact", "gather_words": "on"},
-    {"partition_impl": "compact", "ordered_bins": "on"},
-    {"gather_words": "on", "bucket_scheme": "pow15"},
-], ids=["defaults", "ordered_sort", "compact", "compact_ordered", "pow15"])
+    {},                                                      # TPU defaults
+    {"partition_impl": "sort"},
+    {"bucket_scheme": "pow15"},
+], ids=["defaults", "sort", "pow15"])
 def test_full_grower_lowers(v5e, knobs):
     """Every capture-playbook A/B configuration of the FULL grower
     (gather buckets, lax.switch, while_loop, Pallas kernels) must
@@ -204,7 +190,7 @@ def test_distributed_grower_lowers_4chip(learner):
     devs = np.array(topo.devices)
     cfg = GrowerConfig(num_leaves=63, min_data_in_leaf=1,
                        min_sum_hessian_in_leaf=100.0, max_bin=255,
-                       hist_method="fused", gather_words="on")
+                       hist_method="fused")
     n, f = 1 << 16, 32
     if learner == "data_feature":
         mesh = Mesh(devs.reshape(2, 2), ("data", "feature"))
@@ -288,7 +274,7 @@ def test_packed_grower_lowers(v5e):
     n = 1 << 16
     cfg = GrowerConfig(num_leaves=63, min_data_in_leaf=1,
                        min_sum_hessian_in_leaf=100.0, max_bin=255,
-                       hist_method="fused", gather_words="on")
+                       hist_method="fused")
     meta = FeatureMeta(
         num_bin=v5e((f,), jnp.int32), missing_type=v5e((f,), jnp.int32),
         default_bin=v5e((f,), jnp.int32),

@@ -227,15 +227,3 @@ def test_feature_parallel_gates_packing_off():
     X, y, cats = _narrow_problem()
     b = _train(X, y, cats, True, {"tree_learner": "feature"})
     assert b.inner._pack_plan is None
-
-
-def test_packed_training_with_gather_panel_identical():
-    """gather_panel folds weights into the word gather of the PACKED
-    storage matrix; with packing + categoricals the trained model must be
-    bit-identical to the panel-off path (the sparse bench A/B composition)."""
-    X, y, cats = _narrow_problem(seed=9)
-    ref = _train(X, y, cats, True, {"gather_words": "on",
-                                    "gather_panel": "off"})
-    got = _train(X, y, cats, True, {"gather_words": "on",
-                                    "gather_panel": "on"})
-    assert ref.model_to_string() == got.model_to_string()

@@ -238,32 +238,16 @@ def test_multiclass_data_parallel():
     assert float(np.mean(pred.argmax(axis=1) == labels)) > 0.85
 
 
-def test_data_parallel_ordered_sort_matches_serial(data):
-    """ordered_bins + sort partition compose with the data-parallel mesh:
-    every shard maintains its leaf-ordered local matrix and the psum'd
-    histograms reproduce the serial tree exactly."""
+def test_data_parallel_sort_partition_matches_serial(data):
+    """The sort partition composes with the data-parallel mesh: every
+    shard sorts its own window and the psum'd histograms reproduce the
+    serial tree exactly."""
     X, y, Xt, yt = data
     auc_serial, bst_s = _train_auc(X, y, Xt, yt, {"tree_learner": "serial"})
     auc_os, bst_o = _train_auc(
-        X, y, Xt, yt, {"tree_learner": "data", "ordered_bins": "on",
-                       "partition_impl": "sort",
+        X, y, Xt, yt, {"tree_learner": "data", "partition_impl": "sort",
                        "enable_bin_packing": False})
     assert auc_os == pytest.approx(auc_serial, abs=5e-3)
     t_s, t_o = bst_s.inner.models[0], bst_o.inner.models[0]
     np.testing.assert_array_equal(t_s.split_feature, t_o.split_feature)
     np.testing.assert_array_equal(t_s.threshold_bin, t_o.threshold_bin)
-
-
-def test_data_parallel_with_gather_panel_matches_serial(data):
-    """The panel gather composes with shard_map (each shard builds its
-    panel from its own row shard); identical first-tree structure."""
-    X, y, Xt, yt = data
-    base = {"gather_words": "on", "gather_panel": "on"}
-    auc_serial, bst_s = _train_auc(X, y, Xt, yt,
-                                   dict(base, tree_learner="serial"))
-    auc_data, bst_d = _train_auc(X, y, Xt, yt,
-                                 dict(base, tree_learner="data"))
-    assert auc_data == pytest.approx(auc_serial, abs=5e-3)
-    t_s, t_d = bst_s.inner.models[0], bst_d.inner.models[0]
-    np.testing.assert_array_equal(t_s.split_feature, t_d.split_feature)
-    np.testing.assert_array_equal(t_s.threshold_bin, t_d.threshold_bin)

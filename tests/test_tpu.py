@@ -126,54 +126,6 @@ def test_gspmd_fused_hybrid_matches_flat_on_tpu(tpu):
         np.testing.assert_array_equal(t1.threshold_bin, t2.threshold_bin)
 
 
-def test_pallas_compact_compiles_and_matches_on_tpu(tpu):
-    """Mosaic lowering proof for the compaction-partition kernel — the
-    riskiest surface (dynamic-offset HBM DMA, scalar-prefetch bases,
-    precomputed-rank permutation matmul).  Compiles, runs, and must
-    match the stable-partition oracle exactly; prints throughput (host
-    clock, information only)."""
-    import sys
-    import time
-    import jax
-    import jax.numpy as jnp
-    from lightgbm_tpu.ops.pallas_compact import compact_window
-
-    rng = np.random.RandomState(7)
-    size, cnt = 1 << 19, (1 << 19) - 777
-    win = rng.randint(0, 1 << 24, size).astype(np.int32)
-    valid = np.arange(size) < cnt
-    gl = (rng.rand(size) < 0.5) & valid
-    pay = [rng.randint(0, 1 << 32, size, dtype=np.uint64).astype(np.uint32)
-           for _ in range(8)]     # higgs-like: 7 packed-word cols + weights
-    fn = jax.jit(lambda w, g, v, p: compact_window(w, g, v, p))
-    nw, npay, _nl = fn(jnp.asarray(win), jnp.asarray(gl), jnp.asarray(valid),
-                  tuple(jnp.asarray(p) for p in pay))
-    order = np.concatenate([np.flatnonzero(gl), np.flatnonzero(valid & ~gl)])
-    exp = win.copy()
-    exp[:cnt] = win[order]
-    np.testing.assert_array_equal(np.asarray(nw), exp)
-    # the no-payload shape (output width 1, the narrowest unaligned DMA)
-    # must ALSO lower — the bench A/B without ordered_bins runs exactly
-    # this; the 8-payload case above exercises output width 17
-    nw0, _, _ = jax.jit(lambda w, g, v: compact_window(w, g, v, ()))(
-        jnp.asarray(win), jnp.asarray(gl), jnp.asarray(valid))
-    np.testing.assert_array_equal(np.asarray(nw0), exp)
-    ep = pay[0].copy()
-    ep[:cnt] = pay[0][order]
-    np.testing.assert_array_equal(np.asarray(npay[0]), ep)
-    args = (jnp.asarray(win), jnp.asarray(gl), jnp.asarray(valid),
-            tuple(jnp.asarray(p) for p in pay))
-    jax.block_until_ready(fn(*args))
-    t0 = time.perf_counter()
-    out = None
-    for _ in range(5):
-        out = fn(*args)
-    jax.block_until_ready(out)
-    dt = (time.perf_counter() - t0) / 5
-    print(f"compact: {dt*1e3:.2f} ms at {size} rows x 8 payload cols "
-          f"({dt/size*1e9:.1f} ns/row)", file=sys.stderr)
-
-
 def test_fused_hist_matches_einsum_on_device(tpu):
     """On-device proof of the fused-gather kernel: compiles under Mosaic,
     matches the f32 einsum oracle over the same gathered window (counts
