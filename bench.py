@@ -86,7 +86,7 @@ import time
 BASELINE_TREES_PER_SEC_1M = 2.5285 * 28  # see module docstring
 
 # only binning-relevant params key the dataset cache: grower knobs
-# (partition_impl, bin packing, use_pallas, ...)
+# (bin packing, use_pallas, split_find, ...)
 # never change the constructed dataset, and hashing them would make every
 # A/B stage re-bin.  INVARIANT (pinned by tests/test_bench_keys.py): this
 # set must stay a superset of every

@@ -425,10 +425,6 @@ class GBDT:
             hist_method=hist_method,
             row_tile=cfg.pallas_row_tile,
             bucket_min_log2=cfg.pallas_bucket_min_log2,
-            partition_impl=("scatter" if cfg.partition_impl == "auto"
-                            else cfg.partition_impl),
-            bucket_scheme=("pow2" if cfg.bucket_scheme == "auto"
-                           else cfg.bucket_scheme),
             has_categorical=bool(np.asarray(fm["is_categorical"]).any()),
             has_missing=bool((np.asarray(fm["missing_type"]) != 0).any()),
             max_cat_threshold=cfg.max_cat_threshold,

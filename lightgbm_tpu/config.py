@@ -520,14 +520,6 @@ class Config:
                                    # historical packed-argmax form, kept as
                                    # the forced A/B baseline).  Trees are
                                    # bit-identical either way (pinned)
-    partition_impl: str = "auto"   # window partition: auto | scatter | sort
-                                   # (sort = one stable sort of the window
-                                   # keyed by the routing bit; auto is
-                                   # scatter until the PR that flips it:
-                                   # ROADMAP S1.2)
-    bucket_scheme: str = "auto"    # gather-bucket sizes: auto | pow2 | pow15
-                                   # (pow15 adds 1.5*2^k buckets: ~16% less
-                                   # padded work, 2x the compiled branches)
 
     pipeline_trees: bool = True    # pipeline tree materialization: keep
     # freshly grown trees on device and pull them to host a few iterations
@@ -706,12 +698,6 @@ def check_param_conflicts(cfg: Config) -> None:
     if cfg.saved_feature_importance_type not in (0, 1):
         log.fatal("saved_feature_importance_type must be 0 (split) or "
                   "1 (gain); got %d", cfg.saved_feature_importance_type)
-    if cfg.partition_impl not in ("auto", "scatter", "sort"):
-        log.fatal("partition_impl must be auto, scatter, or sort; got %r",
-                  cfg.partition_impl)
-    if cfg.bucket_scheme not in ("auto", "pow2", "pow15"):
-        log.fatal("bucket_scheme must be auto, pow2, or pow15; got %r",
-                  cfg.bucket_scheme)
     if cfg.nonfinite_policy not in ("raise", "rollback", "clamp"):
         log.fatal("nonfinite_policy must be raise, rollback, or clamp; "
                   "got %r", cfg.nonfinite_policy)

@@ -6,13 +6,13 @@ TPU-native re-design of ``SerialTreeLearner::Train``
 * the reference's ``DataPartition`` index reordering is kept as-is on device:
   an index array ``order`` groups rows contiguously by leaf
   (``data_partition.hpp:94-146``); per split only the SPLITTING leaf's
-  window of ``order`` is sliced out (pow2 bucket), routed, stably
-  cumsum-rank-partitioned and written back — O(leaf) per split, exactly
+  window of ``order`` is sliced out (a size of ``_bucket_sizes``), routed,
+  stably sorted left before right and written back — O(leaf) per split, exactly
   the reference's per-leaf partition cost, summing to O(N·log L) per
   tree instead of O(N·L);
 * per split only the **smaller child** is histogrammed — its rows are
-  gathered through ``order`` into a power-of-two padded buffer chosen by
-  ``lax.switch`` (static shapes, ~log2(N) compiled buckets) and reduced by
+  gathered through ``order`` into a padded buffer of a static size chosen by
+  ``lax.switch`` (``_bucket_sizes``: ~log2(N) compiled buckets) and reduced by
   a one-hot MXU matmul (Pallas kernel on TPU); the larger child is obtained
   by parent − smaller subtraction exactly like the reference
   (``serial_tree_learner.cpp:482-488``).  Per-leaf parent histograms live in
@@ -66,12 +66,10 @@ class GrowerConfig(NamedTuple):
                                      # rung; falls back to an XLA reference
                                      # rung when the layout cannot fuse)
     row_tile: int = 512              # Pallas grid: rows per block
-    bucket_min_log2: int = 6         # smallest pow2 gather-buffer bucket
+    bucket_min_log2: int = 6         # smallest window size (_bucket_sizes)
     #                                  (64 rows: tail splits of deep trees
     #                                  stop paying kilobucket padding —
     #                                  round-7 leaves-sweep measurement)
-    partition_impl: str = "scatter"  # window partition: scatter | sort
-    bucket_scheme: str = "pow2"      # gather-bucket sizes: pow2 | pow15
     has_categorical: bool = False    # static: enables the categorical path
     has_missing: bool = True         # static: False skips the dir=+1 scan
     max_cat_threshold: int = 256
@@ -218,7 +216,7 @@ class _LoopState(NamedTuple):
     fixed cost, and every extra carried-array scatter is copy-insertion
     surface).  ``TreeArrays`` is unpacked ONCE after the loop."""
     step: jnp.ndarray
-    order: jnp.ndarray           # [N + maxbuf] i32: row ids grouped by leaf
+    order: jnp.ndarray           # [N + tail] i32: row ids grouped by leaf
     lsc: jnp.ndarray             # [L, 2] i32: (first position, local count)
     hist_store: jnp.ndarray      # [L, 3 * F * B]: per-leaf histograms, a
     #                              leaf's flat (pool_flat)
@@ -435,57 +433,41 @@ def take_row_bits(words, rows):
     return ((w >> plane.astype(jnp.uint32)) & 1).astype(bool)
 
 
-def partition_window(order, start, cnt, size: int, left_bits,
-                     impl: str = "scatter"):
+def partition_window(order, start, cnt, size: int, left_bits):
     """Stable two-way partition of one leaf's window of ``order``
     (``DataPartition::Split``, data_partition.hpp:94-146).
 
-    ``order`` is ``i32[N + tail]``: row ids grouped by leaf, then ``tail >=
-    size`` sentinel slots holding ``N``.  The window is the ``size`` (static)
-    slots from ``start``, of which the first ``cnt`` are the leaf's rows;
+    ``order`` is ``i32[N + tail]``: row ids grouped by leaf, then sentinel
+    slots holding ``N``, enough of them that ``start + size`` stays inside
+    (:func:`_order_tail`).  The window is the ``size`` (static) slots from
+    ``start``, of which the first ``cnt`` are the leaf's rows;
     ``left_bits`` is :func:`pack_row_bits` of the ``bool[N]`` decision "this
     row goes left".  Returns ``(order, n_left)``: the leaf's rows that go
     left, in the sequence they had, then those that go right, in theirs;
     every slot outside ``[start, start + cnt)`` as it was.  Closes over
     nothing: this is the seam a different transport of the window replaces.
 
-    ``impl`` is the transport: ``scatter`` ranks the rows by one cumsum and
-    scatters them to their slots; ``sort`` is one stable sort of the window
-    keyed left / right / past the leaf (the faster one on the chip; ROADMAP
-    S1.2 names the PR that makes it the only one)."""
+    The transport is ONE sort of the window and one slice written back.
+    The key is unique, ``group * size + slot`` with the group left / right
+    / past the leaf, so a plain two-operand sort IS the stable partition:
+    asked for a stable sort of the three-valued key alone, XLA carries an
+    ``iota`` through every pass as a third operand to break the ties
+    (v5e, 10.5M rows: the sort 0.112 s a tree against 0.187; a cumsum, a
+    rank and a scatter into ``order`` took 0.97; PERF.md section 6, PR 30)."""
+    if 3 * size > 2 ** 31:
+        raise ValueError(f"window of {size} slots: 3 * size overflows the "
+                         f"int32 sort key")
     win = lax.dynamic_slice(order, (start,), (size,))
-    j = jnp.arange(size, dtype=jnp.int32)
-    valid = j < cnt
+    slot = jnp.arange(size, dtype=jnp.int32)
+    valid = slot < cnt
     # slots past the leaf may hold the sentinel N: read row 0's bit there
     goes_left = take_row_bits(left_bits, jnp.where(valid, win, 0)) & valid
-    if impl == "sort":
-        # slots past the leaf (key 2) are already contiguous at the
-        # window's tail, so a stable sort returns them where they were
-        nl = jnp.sum(goes_left.astype(jnp.int32))
-        key = jnp.where(~valid, 2, jnp.where(goes_left, 0, 1)
-                        ).astype(jnp.int32)
-        _, new_win = lax.sort((key, win), is_stable=True, num_keys=1)
-        return lax.dynamic_update_slice(order, new_win, (start,)), nl
-    c1 = jnp.cumsum(goes_left.astype(jnp.int32))
-    nl = c1[-1]
-    # right-side rank needs cumsum(valid & ~goes_left); since valid =
-    # j < cnt that cumsum is min(j+1, cnt) - c1 in closed form — one
-    # cumsum pass instead of two
-    c0 = jnp.minimum(j + 1, cnt) - c1
-    # stable two-way rank inside the window; rows past the leaf (and
-    # sentinel padding) keep their own slot so the write-back leaves
-    # neighbors untouched
-    rank = jnp.where(goes_left, c1 - 1, nl + c0 - 1)
-    rank = jnp.where(valid, rank, j)
-    # ONE scatter straight into ``order`` at start + rank — not a
-    # window-local scatter followed by a dynamic_update_slice write-back.
-    # The read-then-write interference of the DUS form made XLA:CPU's copy
-    # insertion clone the whole O(N) carrier once per split
-    # (tests/test_grow_jaxpr.py pins the jaxpr against this class of
-    # regression); the direct scatter updates it in place
-    order = order.at[start + rank].set(
-        win, unique_indices=True, mode="promise_in_bounds")
-    return order, nl
+    nl = jnp.sum(goes_left.astype(jnp.int32))
+    # slots past the leaf (the last group) are already contiguous at the
+    # window's tail, so the sort returns them where they were
+    key = slot + jnp.where(goes_left, 0, jnp.where(valid, size, 2 * size))
+    _, new_win = lax.sort((key, win), is_stable=False, num_keys=1)
+    return lax.dynamic_update_slice(order, new_win, (start,)), nl
 
 
 def pool_flat(hist):
@@ -553,24 +535,46 @@ def _depth_gate(res: SplitResult, leaf_depth, max_depth) -> SplitResult:
                         gain=jnp.where(ok, res.gain, -jnp.inf))
 
 
-def _bucket_sizes(cfg: "GrowerConfig", n: int):
-    """Static gather-bucket size table covering [1, n].
+# Window sizes above 2^HALF_STEP_ABOVE_LOG2 come in half-steps.  On the v5e
+# a partition branch costs 8.6 ns (the routing read's gather) + 1.1 to 2.9
+# ns (the sort) a padded window element, on top of a dense pass over all N
+# rows that the smallest window pays too (0.13 ms a split at 10.5M rows):
+# a window of 2^13 is 0.09 ms, less than that pass, so finer sizes there
+# could save a few ms of a tree while each size is one more branch to
+# compile.  Above it the padding is what a split costs (PERF.md section 6,
+# PR 30).
+HALF_STEP_ABOVE_LOG2 = 13
 
-    ``pow2``: {2^k} — avg padding ~1.44x of the leaf count.
-    ``pow15``: {2^k, 3*2^(k-1)} — avg padding ~1.21x at 2x the branch
-    count (compile cost is one-time via the persistent cache; runtime
-    executes exactly one branch either way).  These buckets serve only
-    the XLA reference rungs (segment/einsum): the fused Pallas rung's
-    dynamic grid retires the staging switch entirely."""
-    kmin = cfg.bucket_min_log2
-    kmax = max(int(n - 1).bit_length(), kmin)
-    sizes = {1 << k for k in range(kmin, kmax + 1)}
-    if cfg.bucket_scheme == "pow15":
-        sizes |= {3 << (k - 1) for k in range(kmin + 1, kmax + 1)}
-    sizes = sorted(s for s in sizes if s < 2 * n or s == min(sizes))
-    while sizes[-1] < n:      # coverage: largest bucket must hold n rows
-        sizes.append(sizes[-1] * 2)
-    return sizes
+
+def _bucket_sizes(cfg: "GrowerConfig", n: int):
+    """The static, ascending table of window sizes covering [1, n]: one
+    branch of the partition's ``lax.switch`` (``pbranches``, every cell)
+    and of the XLA reference rungs' histogram gather (``branches``) each.
+
+    Powers of two from ``2^bucket_min_log2``, and ``3 * 2^(k-1)`` between
+    them above ``2^HALF_STEP_ABOVE_LOG2`` (mean padding 1.44 of the leaf
+    below it, about 1.21 above).  The table ends at the first size that
+    holds ``n``: a larger one could never be selected and would still be
+    compiled, as the largest branch."""
+    sizes, k = [], cfg.bucket_min_log2
+    while True:
+        sizes.append(1 << k)
+        if k >= HALF_STEP_ABOVE_LOG2 and sizes[-1] < n:
+            sizes.append(3 << (k - 1))
+        if sizes[-1] >= n:
+            return sizes
+        k += 1
+
+
+def _order_tail(sizes):
+    """Sentinel slots ``order`` needs past its ``n`` rows so that no window
+    is sliced out of bounds (``dynamic_slice`` would clamp its start and
+    move the window).  A leaf of ``cnt`` rows ends at or before ``n`` and
+    gets the smallest size that holds ``cnt``, so its window overhangs
+    ``n`` by less than that size less the size below it: the tail is the
+    widest step of the table, not its largest size (4,194,303 slots for
+    12,582,912 at 10.5M rows)."""
+    return max([sizes[0]] + [b - a - 1 for a, b in zip(sizes, sizes[1:])])
 
 
 def _bucket_index(scnt, sizes):
@@ -627,9 +631,9 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
         fh = (pack_plan.num_phys_cols if pack_plan is not None
               else hbins.shape[1])
 
-        # pow2 gather buckets for the smaller child (static branch sizes)
+        # static window sizes: one partition branch each (and one gather
+        # branch each on the XLA reference rungs)
         bsizes = _bucket_sizes(cfg, n)
-        maxbuf = bsizes[-1]
 
         # sentinel row n: weight 0, bin 0 — receives all buffer padding
         hbins_pad = jnp.concatenate(
@@ -734,7 +738,7 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                 return measure(jnp.where(valid, idx, n))
             return branch
 
-        # fused rung: no gather buckets are traced at all — the pow2
+        # fused rung: no gather buckets are traced at all — the
         # staging switch exists only for the fallback rungs
         branches = None if use_fused else [bucket_branch(s) for s in bsizes]
 
@@ -765,7 +769,8 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                 # this replaces read 20 from HBM, and the column itself
                 # as s32[N], which the grow program also keeps in HBM,
                 # 23.5 (scripts/probe_route_read.py; PERF.md section 5)
-                obs_counters.inc("partition_route_dispatch", read="column")
+                obs_counters.inc("partition_route_dispatch", read="column",
+                                 size=size)
                 col_idx = feat if meta.col is None else meta.col[feat]
                 colv = lax.dynamic_index_in_dim(
                     bins_cm, col_idx, axis=0, keepdims=False)
@@ -774,8 +779,7 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                     has_categorical=cfg.has_categorical,
                     is_cat_l=is_cat_l, cat_row=cat_row, max_bin=cfg.max_bin)
                 return partition_window(order, start, cnt, size,
-                                        pack_row_bits(goes_left),
-                                        impl=cfg.partition_impl)
+                                        pack_row_bits(goes_left))
             return branch
 
         pbranches = [partition_branch(s) for s in bsizes]
@@ -787,11 +791,11 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
 
         # fused rung: the kernel's aligned index over-fetch may read up to
         # fused_idx_fetch(row_tile) past the window, so the sentinel tail
-        # must cover that beyond ``maxbuf`` (sentinel reads are harmless —
-        # they only ever resolve to the zero-weight panel row)
-        tail = maxbuf
+        # must cover that too (sentinel reads are harmless — they only
+        # ever resolve to the zero-weight panel row)
+        tail = _order_tail(bsizes)
         if use_fused:
-            tail = max(maxbuf, fused_idx_fetch(cfg.row_tile))
+            tail = max(tail, fused_idx_fetch(cfg.row_tile))
         order0 = jnp.concatenate(
             [jnp.arange(n, dtype=jnp.int32),
              jnp.full((tail,), n, jnp.int32)])

@@ -325,13 +325,6 @@ def analyze_jitted(fn, *args, label: str = "") -> Optional[Dict[str, int]]:
 # ------------------------------------------------------------ fit predictor
 
 
-def _pow2_at_least(n: int, floor: int = 1) -> int:
-    p = max(int(floor), 1)
-    while p < n:
-        p *= 2
-    return p
-
-
 def predict_hbm(rows: int, features: int, bins: int = 255, leaves: int = 31,
                 num_class: int = 1, bin_bytes: Optional[int] = None,
                 packed_cols: int = 0, valid_rows: int = 0,
@@ -381,7 +374,11 @@ def predict_hbm(rows: int, features: int, bins: int = 255, leaves: int = 31,
     rows_d = -(-rows // d)                  # rows per data shard (ceil)
     if bin_bytes is None:
         bin_bytes = 1 if bins < 256 else 2
-    maxbuf = _pow2_at_least(rows_d, 1 << bucket_min_log2)
+    # the largest window of the grower's own table (lazy: grower imports obs)
+    from ..grower import GrowerConfig, _bucket_sizes, _order_tail
+    sizes = _bucket_sizes(GrowerConfig(bucket_min_log2=bucket_min_log2),
+                          rows_d)
+    maxbuf = sizes[-1]
     residents = {
         # the binned matrix [N, C] (+ the nibble-packed histogram copy):
         # row-sharded over ``batch``; over ``feature`` too when the
@@ -468,10 +465,10 @@ def predict_hbm(rows: int, features: int, bins: int = 255, leaves: int = 31,
         transients = {
             # sentinel-padded copy of the histogram inputs
             "staging": (rows_d + 1) * row_bytes,
-            # order [N + maxbuf] i32 + the final row->leaf map [N] i32
-            "order_partition": (rows_d + maxbuf) * 4 + rows_d * 4,
+            # order [N + tail] i32 + the final row->leaf map [N] i32
+            "order_partition": (rows_d + _order_tail(sizes)) * 4 + rows_d * 4,
             "hist_store": pool_bytes,
-            # the pow2 gather buffer for the largest bucket
+            # the gather buffer for the largest window
             "gather_buffer": maxbuf * row_bytes,
         }
     if serving_trees > 0:

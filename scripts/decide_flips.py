@@ -48,10 +48,6 @@ FLIPS = [
     ("bench_1m_xla.json", "BENCH_FUSED=0 (XLA einsum rung forced)",
      "if this LOSES >=5% to the headline, the fused kernel stays the "
      "TPU default (use_pallas=true)", None),
-    ("bench_1m_sortpart.json", "partition_impl=sort",
-     "partition_impl auto->sort", None),
-    ("bench_1m_pow15.json", "bucket_scheme=pow15",
-     "bucket_scheme auto->pow15", None),
     ("bench_sparse_nopack.json", "enable_bin_packing=false",
      "flip packing default off on TPU if OFF wins",
      "bench_sparse.json"),

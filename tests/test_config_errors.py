@@ -27,6 +27,8 @@ from lightgbm_tpu.config import config_from_params
     ({"ordered_bins": "on"}, "Unknown parameter: ordered_bins"),
     ({"pallas_fused": "off"}, "Unknown parameter: pallas_fused"),
     ({"hist_dtype": "float32"}, "Unknown parameter: hist_dtype"),
+    ({"partition_impl": "sort"}, "Unknown parameter: partition_impl"),
+    ({"bucket_scheme": "pow15"}, "Unknown parameter: bucket_scheme"),
     ({"gspmd_hist": "scatter"}, "gspmd_hist"),
     ({"metric": "made_up_metric", "objective": "binary"}, "metric"),
 ])

@@ -29,7 +29,8 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp
 
-from lightgbm_tpu.grower import FeatureMeta, GrowerConfig, make_grower
+from lightgbm_tpu.grower import (FeatureMeta, GrowerConfig, _bucket_sizes,
+                                 _order_tail, make_grower)
 from lightgbm_tpu.utils.jaxpr_audit import audit_loop_body
 
 N, F, B, L = 32768, 8, 64, 15
@@ -207,14 +208,29 @@ def test_compiled_body_has_no_full_pool_copies():
 # work (or a toolchain move) can never silently multiply it — and the
 # GSPMD grower, which has no ``order`` carrier at all, is pinned copy-free
 # below as the contrast.
+#
+# Re-recorded on purpose in PR 30, which made one sort of the window
+# (slice, sort, dynamic_update_slice) the only transport and put half-step
+# sizes into the window table above 2^13.  The sort form ALONE read what the
+# scatter form read: 12 copies on the power-of-two table (one a branch, as
+# before: the slice-then-update of a conditional's operand draws the same
+# defensive copy as the slice-then-scatter did, and no second one; temp
+# bytes 3,905,912 against 3,905,272).  The table now has 12 sizes at this
+# N (12288 and 24576 are new), so the text has 14: twelve branches, the
+# body, the initial carry.  Still ONE executes a split.  The carrier's
+# length comes from the table: N + its widest step (``_order_tail``: 8,191
+# slots here), where it was N + 2^ceil(log2 N), 65,536 entries, whatever
+# the table: each copy is 40,959 entries long.
 
-ORDER_COPY_BUDGET = 12      # recorded on jax 0.9.0 (see above)
+ORDER_COPY_BUDGET = 14      # one a window size + 2 (see above)
 
 
 def test_compiled_order_copy_count_ratchet():
     grow, args = _grow_and_args()
     txt = jax.jit(grow).lower(*args).compile().as_text()
-    carrier = N + 32768                       # order [N + maxbuf] i32
+    sizes = _bucket_sizes(GrowerConfig(), N)
+    assert len(sizes) + 2 == ORDER_COPY_BUDGET
+    carrier = N + _order_tail(sizes)          # order [N + tail] i32
     copies = re.findall(rf"= s32\[{carrier}\][^ ]* copy\(", txt)
     assert 1 <= len(copies) <= ORDER_COPY_BUDGET, (
         f"{len(copies)} order-carrier copies in the compiled executable "
@@ -264,7 +280,12 @@ def test_gspmd_grower_has_no_order_carrier_copies():
 # [15,8,64,3] clones alone is +737,280 temp bytes, which overshoots the
 # headroom.  If a jax upgrade legitimately moves the number, re-measure
 # and ratchet the constant (and say so in the commit); never widen it past
-# one pool-clone pair.
+# one pool-clone pair.  Read again in PR 30: 3,905,272 on the parent (PR
+# 29's deletions took 92,160 off), 3,905,912 with the sort transport on
+# the same table, 3,906,168 with the two half-step branches, 3,709,560
+# with ``order`` 24,577 entries shorter (two live copies of it; the same
+# for the stable three-operand sort and the two-operand one): the budget
+# stands.
 
 TEMP_BYTES_BUDGET = 4_100_000
 TEMP_BYTES_FLOOR = 1_000_000    # sanity: hist_store alone is 368,640 —

@@ -7,7 +7,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from lightgbm_tpu.grower import (FeatureMeta, GrowerConfig, make_grower,
+from lightgbm_tpu.grower import (HALF_STEP_ABOVE_LOG2, FeatureMeta,
+                                 GrowerConfig, _bucket_sizes, make_grower,
                                  pack_gather_words, unpack_gather_words)
 
 
@@ -28,67 +29,6 @@ def test_pack_rejects_wide_dtypes():
         pack_gather_words(jnp.zeros((4, 4), jnp.int32))
 
 
-def test_grow_partition_sort_identical():
-    """partition_impl=sort (one stable sort on a 3-way key) must reproduce
-    the rank-scatter partition bit for bit, including past-the-leaf window
-    slots returning to their original positions."""
-    rng = np.random.RandomState(9)
-    n, f, b = 6000, 9, 47
-    bins = jnp.asarray(rng.randint(0, b, size=(n, f), dtype=np.uint8))
-    g = jnp.asarray(rng.randn(n).astype(np.float32))
-    h = jnp.asarray(np.ones(n, np.float32))
-    c = jnp.asarray(np.ones(n, np.float32))
-    meta = FeatureMeta(num_bin=jnp.full((f,), b, jnp.int32),
-                       missing_type=jnp.zeros((f,), jnp.int32),
-                       default_bin=jnp.zeros((f,), jnp.int32),
-                       is_categorical=jnp.zeros((f,), bool))
-    fv = jnp.ones((f,), bool)
-    outs = {}
-    for impl in ("scatter", "sort"):
-        cfg = GrowerConfig(num_leaves=31, min_data_in_leaf=1, max_bin=b,
-                           hist_method="segment", bucket_min_log2=6,
-                           partition_impl=impl)
-        tree, row_leaf = jax.jit(make_grower(cfg))(bins, g, h, c, meta, fv)
-        outs[impl] = jax.tree.map(np.asarray, (tree, row_leaf))
-    for a, bb in zip(outs["scatter"][0], outs["sort"][0]):
-        assert np.array_equal(a, bb)
-    assert np.array_equal(outs["scatter"][1], outs["sort"][1])
-
-
-def test_grow_missing_routing_sort():
-    """NaN- and zero-missing routing decisions must survive the sort
-    transport bit for bit."""
-    import lightgbm_tpu as lgb
-    rng = np.random.RandomState(12)
-    n = 4000
-    X = rng.randn(n, 6)
-    X[rng.rand(n, 6) < 0.15] = np.nan          # NaN missing
-    X[:, 2] = np.where(rng.rand(n) < 0.5, 0.0, X[:, 2])  # zero-heavy col
-    y = ((np.nan_to_num(X[:, 0]) + np.nan_to_num(X[:, 1])) > 0).astype(float)
-    base = {"objective": "binary", "num_leaves": 15, "verbose": -1,
-            "min_data_in_leaf": 5, "use_missing": True,
-            "enable_bin_packing": False}
-    ref = lgb.train(dict(base), lgb.Dataset(X, label=y), num_boost_round=5)
-    got = lgb.train(dict(base, partition_impl="sort"),
-                    lgb.Dataset(X, label=y), num_boost_round=5)
-    assert ref.model_to_string() == got.model_to_string()
-
-
-def test_grow_bucket_scheme_pow15_identical():
-    """pow15 buckets change only padded (masked) work — trees identical."""
-    import lightgbm_tpu as lgb
-    rng = np.random.RandomState(13)
-    n = 5000
-    X = rng.randn(n, 8)
-    y = (X[:, 0] + 0.5 * rng.randn(n) > 0).astype(float)
-    base = {"objective": "binary", "num_leaves": 31, "verbose": -1,
-            "min_data_in_leaf": 3, "enable_bin_packing": False}
-    ref = lgb.train(dict(base), lgb.Dataset(X, label=y), num_boost_round=5)
-    got = lgb.train(dict(base, bucket_scheme="pow15"),
-                    lgb.Dataset(X, label=y), num_boost_round=5)
-    assert ref.model_to_string() == got.model_to_string()
-
-
 # ---- the routing read against a plain numpy router -------------------------
 #
 # The partition slices the split column out of a column-major copy, routes
@@ -101,10 +41,11 @@ def test_grow_bucket_scheme_pow15_identical():
 def _route_case(kind):
     import lightgbm_tpu as lgb
     rng = np.random.RandomState(26)
-    n = 4000
+    # half_step: rows enough for windows of 3 * 2^12 and 3 * 2^13 slots
+    n = 30000 if kind == "half_step" else 4000
     params = {"max_bin": 31, "verbose": -1}
     cat = "auto"
-    if kind == "numeric_missing":
+    if kind in ("numeric_missing", "half_step"):
         X = rng.randn(n, 5)
         X[rng.rand(n, 5) < 0.1] = np.nan
     elif kind in ("nan_missing", "zero_missing"):
@@ -195,7 +136,7 @@ def _assert_routed_like_numpy(bins, fm, tree, row_leaf, weight=None, tag=""):
 
 @pytest.mark.parametrize("kind", ["numeric_missing", "nan_missing",
                                   "zero_missing", "categorical", "efb",
-                                  "uint16"])
+                                  "uint16", "half_step"])
 def test_grow_routes_like_plain_numpy_router(kind):
     from lightgbm_tpu.obs.counters import counters
     bins, fm, max_bin, y = _route_case(kind)
@@ -209,18 +150,29 @@ def test_grow_routes_like_plain_numpy_router(kind):
                        has_missing=bool((fm["missing_type"] != 0).any()))
     g = jnp.asarray((0.5 - y).astype(np.float32))
     one = jnp.ones((n,), jnp.float32)
-    before = counters.get("partition_route_dispatch").get("read=column", 0)
+    counters.reset()
     tree, row_leaf = jax.jit(make_grower(cfg))(
         jnp.asarray(bins), g, one * 0.25, one, meta,
         jnp.ones((len(fm["num_bin"]),), bool))
     # one count per traced partition branch: the grower says which read
-    # it was built with
-    assert counters.snapshot()["counters"]["partition_route_dispatch"][
-        "read=column"] > before
+    # and which window sizes it was built with
+    sizes = _bucket_sizes(cfg, n)
+    assert counters.get("partition_route_dispatch") == {
+        f"read=column,size={s}": 1 for s in sizes}
     tree, num_leaves = _assert_routed_like_numpy(bins, fm, tree, row_leaf,
                                                  tag=kind)
     assert num_leaves > 8
     nodes = slice(0, num_leaves - 1)
+    halves = [s for s in sizes if s & (s - 1)]
+    if kind == "half_step":
+        # splits ran in half-step windows: a split leaf's row count picked
+        # a size that is no power of two
+        assert halves == [12288, 24576]
+        split_rows = tree.internal_count[nodes].astype(np.int64)
+        picked = {min(s for s in sizes if s >= c) for c in split_rows}
+        assert picked & set(halves), sorted(picked)
+    else:
+        assert not halves and sizes[-1] <= 1 << HALF_STEP_ABOVE_LOG2
     if kind == "categorical":
         assert tree.is_cat[nodes].any()
     if kind == "efb":
@@ -232,6 +184,74 @@ def test_grow_routes_like_plain_numpy_router(kind):
         on_missing = fm["missing_type"][tree.split_feature[nodes]] == mt
         assert (tree.default_left[nodes] & on_missing).any()
         assert (~tree.default_left[nodes] & on_missing).any()
+
+
+def test_grow_dense_columns_route_like_plain_numpy_router():
+    """Columns with no missing type and unit hessians, straight into
+    ``make_grower``: every window slot past the leaf comes back where it
+    was, or the next leaf's rows would be misplaced in the row -> leaf map
+    the router arrives at."""
+    rng = np.random.RandomState(9)
+    n, f, b = 6000, 9, 47
+    bins = rng.randint(0, b, size=(n, f)).astype(np.uint8)
+    fm = {"num_bin": np.full(f, b, np.int32),
+          "missing_type": np.zeros(f, np.int32),
+          "default_bin": np.zeros(f, np.int32),
+          "is_categorical": np.zeros(f, bool)}
+    cfg = GrowerConfig(num_leaves=31, min_data_in_leaf=1, max_bin=b,
+                       hist_method="segment", bucket_min_log2=6)
+    tree, row_leaf = jax.jit(make_grower(cfg))(
+        jnp.asarray(bins), jnp.asarray(rng.randn(n).astype(np.float32)),
+        jnp.ones((n,), jnp.float32), jnp.ones((n,), jnp.float32),
+        FeatureMeta(**{k: jnp.asarray(v) for k, v in fm.items()}),
+        jnp.ones((f,), bool))
+    _, num_leaves = _assert_routed_like_numpy(bins, fm, tree, row_leaf)
+    assert num_leaves == 31
+
+
+def _record_grow_calls(bst, rounds):
+    """Every call ``GBDT.train_one_iter`` makes into the grower over
+    ``rounds`` updates: (bins, bag weights, (tree, row_leaf))."""
+    gbdt = bst.inner
+    grow, calls = gbdt.grow, []
+
+    def recording(bins, gw, hw, cw, meta, feat_valid):
+        out = grow(bins, gw, hw, cw, meta, feat_valid)
+        calls.append((np.asarray(bins), np.asarray(cw), out))
+        return out
+
+    gbdt.grow = recording
+    for _ in range(rounds):
+        bst.update()
+    gbdt.grow = grow
+    return calls
+
+
+def test_training_with_missing_values_routes_like_plain_numpy_router():
+    """NaN-missing columns and a zero-heavy one through ``lgb.Booster``,
+    at a row count whose root window is a half-step size (3 * 2^12): five
+    trees, each replayed by the numpy router."""
+    import lightgbm_tpu as lgb
+    rng = np.random.RandomState(12)
+    n = 12000
+    X = rng.randn(n, 6)
+    X[rng.rand(n, 6) < 0.15] = np.nan          # NaN missing
+    X[:, 2] = np.where(rng.rand(n) < 0.5, 0.0, X[:, 2])  # zero-heavy col
+    y = ((np.nan_to_num(X[:, 0]) + np.nan_to_num(X[:, 1])) > 0).astype(float)
+    bst = lgb.Booster(
+        {"objective": "binary", "num_leaves": 15, "verbose": -1,
+         "min_data_in_leaf": 5, "use_missing": True,
+         "enable_bin_packing": False}, lgb.Dataset(X, label=y))
+    calls = _record_grow_calls(bst, 5)
+    fm = bst.inner.train_set.feature_meta()
+    assert (fm["missing_type"] == 2).any() and len(calls) == 5
+    for i, (bins, cw, (tree, row_leaf)) in enumerate(calls):
+        assert bins.shape[0] == n
+        tree, num_leaves = _assert_routed_like_numpy(bins, fm, tree,
+                                                     row_leaf, tag=i)
+        assert num_leaves == 15
+        nodes = slice(0, num_leaves - 1)
+        assert (fm["missing_type"][tree.split_feature[nodes]] == 2).any()
 
 
 def test_bagged_bundled_training_routes_like_plain_numpy_router():
@@ -248,19 +268,8 @@ def test_bagged_bundled_training_routes_like_plain_numpy_router():
          "min_data_in_leaf": 5, "bagging_fraction": 0.8, "bagging_freq": 1,
          "seed": 7, "enable_bin_packing": False},
         lgb.Dataset(X, label=y))
-    gbdt = bst.inner
-    grow, calls = gbdt.grow, []
-
-    def recording(bins, gw, hw, cw, meta, feat_valid):
-        out = grow(bins, gw, hw, cw, meta, feat_valid)
-        calls.append((np.asarray(bins), np.asarray(cw), out))
-        return out
-
-    gbdt.grow = recording
-    for _ in range(4):
-        bst.update()
-    gbdt.grow = grow
-    fm = gbdt.train_set.feature_meta()
+    calls = _record_grow_calls(bst, 4)
+    fm = bst.inner.train_set.feature_meta()
     assert "col" in fm and len(calls) == 4
     for i, (bins, cw, (tree, row_leaf)) in enumerate(calls):
         assert 0 < cw.sum() < n or bins.shape[0] < n    # a bag was drawn

@@ -616,21 +616,17 @@ def test_xentlambda_metric_value_parity(ref_bin, tmp_path):
         assert abs(ours - ref_val) < 1e-5, (obj, ours, ref_val)
 
 
-@pytest.mark.parametrize("knobs", [
-    # the sort transport of the partition on the 1.5 * 2^k bucket table
-    {"partition_impl": "sort", "bucket_scheme": "pow15"},
-])
-def test_perf_knob_matrix_training_parity(ref_bin, tmp_path, knobs):
-    """The data-movement knobs that are left (sort partition, pow15
-    buckets) are bit-neutral all the way to the reference: a model
-    trained with the knobs engaged predicts within the oracle envelope of
-    the reference CLI's."""
+def test_sort_partition_training_parity(ref_bin, tmp_path):
+    """The partition's transport (one sort of the window, on the
+    window table of ``grower._bucket_sizes``) is bit-neutral all the way to
+    the reference: a model trained through it predicts within the oracle
+    envelope of the reference CLI's."""
     data_path = "/root/reference/examples/binary_classification/binary.train"
     if not os.path.exists(data_path):
         pytest.skip("reference example data missing")
     ours = lgb.train({"objective": "binary", "num_leaves": 15,
                       "min_data_in_leaf": 20, "verbose": -1,
-                      "enable_bin_packing": False, **knobs},
+                      "enable_bin_packing": False},
                      lgb.Dataset(data_path), num_boost_round=6)
     model_path = tmp_path / "knobs_ref.txt"
     conf = tmp_path / "knobs.conf"

@@ -121,21 +121,21 @@ FULL_GROWER_PROOFS = pytest.mark.skipif(
 
 
 @FULL_GROWER_PROOFS
-@pytest.mark.parametrize("knobs", [
-    {},                                                      # TPU defaults
-    {"partition_impl": "sort"},
-    {"bucket_scheme": "pow15"},
-], ids=["defaults", "sort", "pow15"])
-def test_full_grower_lowers(v5e, knobs):
-    """Every capture-playbook A/B configuration of the FULL grower
-    (gather buckets, lax.switch, while_loop, Pallas kernels) must
-    Mosaic-compile for v5e at the bench config."""
+@pytest.mark.parametrize("n", [1 << 17, 98304, (1 << 17) + 1], ids=[
+    "pow2_rows",        # the window table ends at 2^17
+    "half_step_rows",   # ... at 3 * 2^15, the largest window and the tail
+    "one_row_more",     # ... at 3 * 2^16, the first size that holds n
+])
+def test_full_grower_lowers(v5e, n):
+    """The FULL grower (partition switch over the window table with its
+    half-step sizes, the sort transport, while_loop, Pallas kernel) must
+    Mosaic-compile for v5e at the bench config, wherever the table ends."""
     import jax.numpy as jnp
     from lightgbm_tpu.grower import FeatureMeta, GrowerConfig, make_grower
-    n, f = 1 << 17, 28
+    f = 28
     cfg = GrowerConfig(num_leaves=255, min_data_in_leaf=1,
                        min_sum_hessian_in_leaf=100.0, max_bin=255,
-                       hist_method="fused", **knobs)
+                       hist_method="fused")
     meta = FeatureMeta(
         num_bin=v5e((f,), jnp.int32), missing_type=v5e((f,), jnp.int32),
         default_bin=v5e((f,), jnp.int32),

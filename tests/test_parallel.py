@@ -245,7 +245,7 @@ def test_data_parallel_sort_partition_matches_serial(data):
     X, y, Xt, yt = data
     auc_serial, bst_s = _train_auc(X, y, Xt, yt, {"tree_learner": "serial"})
     auc_os, bst_o = _train_auc(
-        X, y, Xt, yt, {"tree_learner": "data", "partition_impl": "sort",
+        X, y, Xt, yt, {"tree_learner": "data",
                        "enable_bin_packing": False})
     assert auc_os == pytest.approx(auc_serial, abs=5e-3)
     t_s, t_o = bst_s.inner.models[0], bst_o.inner.models[0]
