@@ -275,7 +275,8 @@ class GBDT:
             log.info("nonfinite_policy=rollback forces synchronous tree "
                      "materialization (pipeline_trees disabled)")
 
-        self.objective.init(train.metadata, n)
+        with obs_trace.phase("objective.init"):
+            self.objective.init(train.metadata, n)
         self.num_class = self.objective.num_tree_per_iteration
         objective = self.objective
 

@@ -34,6 +34,7 @@ from jax import lax
 
 from .config import Config
 from .data.metadata import Metadata
+from .obs.counters import counters as obs_counters
 from .utils import log
 
 K_MIN_SCORE = -np.inf
@@ -351,13 +352,41 @@ def default_label_gain(max_label: int = 31):
     return [float((1 << i) - 1) for i in range(max_label)]
 
 
-class LambdarankNDCG(Objective):
-    """rank_objective.hpp:19-245.
+# Padded query lengths come in half-steps above this size, as the grower's
+# window sizes do above 2^13 (grower._bucket_sizes): a query costs its
+# padded length squared, so a table of powers of two alone would build
+# twice the pairs of the half-stepped one on lengths just past a power.
+_HALF_STEP_FROM = 16
+_PAIR_BLOCK = 16e6      # pair slots a chunk of queries may build (64 MB)
 
-    Vectorized: queries padded to the max query length D; per query the
-    pairwise [D, D] lambda matrix is computed in one shot (sigmoid applied
-    directly — no lookup table needed on TPU), processed in chunks of
-    queries via ``lax.map`` to bound memory.
+
+def _length_table(longest: int):
+    """The static, ascending table of padded query lengths covering
+    [1, longest]: powers of two and, from ``_HALF_STEP_FROM`` up, the
+    half-steps between them, ending at the first that holds ``longest``."""
+    sizes, d = [], 1
+    while True:
+        sizes.append(d)
+        if d >= _HALF_STEP_FROM and sizes[-1] < longest:
+            sizes.append(3 * d // 2)
+        if sizes[-1] >= longest:
+            return sizes
+        d *= 2
+
+
+class LambdarankNDCG(Objective):
+    """rank_objective.hpp:19-245, over length buckets.
+
+    ``init`` groups the queries by padded length on ``_length_table``; a
+    bucket is a ``[Q_b, D_b]`` table of row indices (``num_data`` where a
+    slot is padding), processed in chunks of queries that bound the
+    ``[C, D_b, D_b]`` pairwise block.  Nothing is sorted: a document's rank
+    is the count of its query's documents that score higher, or as high
+    and stand earlier (what a stable descending sort gives), and the block
+    is summed along one axis only, each pair seen from both its documents.
+    Every document sits in exactly one slot, so the way back to row order
+    is one gather through a permutation fixed here (sigmoid applied
+    directly — no lookup table needed on TPU).
     """
     name = "lambdarank"
     need_accurate_prediction = False
@@ -366,97 +395,127 @@ class LambdarankNDCG(Objective):
         super().init(metadata, num_data)
         if metadata.query_boundaries is None:
             log.fatal("Lambdarank tasks require query information")
-        bounds = np.asarray(metadata.query_boundaries)
+        bounds = np.asarray(metadata.query_boundaries, np.int64)
         self.num_queries = len(bounds) - 1
         sizes = np.diff(bounds)
-        D = int(sizes.max())
         label = np.asarray(metadata.label)
         gains = np.asarray(self.config.label_gain or default_label_gain(),
                            dtype=np.float64)
-        max_label = int(label.max())
-        if max_label >= len(gains):
-            log.fatal("Label %d exceeds label_gain size", max_label)
+        if int(label.max()) >= len(gains):
+            log.fatal("Label %d exceeds label_gain size", int(label.max()))
+        ilabel = label.astype(np.int64)
 
-        # padded [Q, D] gather indices (N = padding slot) and validity
-        qidx = np.full((self.num_queries, D), num_data, dtype=np.int32)
-        for q in range(self.num_queries):
-            qidx[q, :sizes[q]] = np.arange(bounds[q], bounds[q + 1])
-        valid = qidx < num_data
-        # truncated max DCG per query (CalMaxDCGAtK at max_position)
-        k = min(self.config.max_position, D)
-        discounts = 1.0 / np.log2(np.arange(D + 2, dtype=np.float64) + 2.0)
-        inv_max_dcg = np.zeros(self.num_queries, dtype=np.float64)
-        for q in range(self.num_queries):
-            ls = np.sort(label[bounds[q]:bounds[q + 1]])[::-1][:k]
-            mdcg = float((gains[ls.astype(np.int32)] * discounts[:len(ls)]).sum())
-            inv_max_dcg[q] = 1.0 / mdcg if mdcg > 0 else 0.0
+        # truncated max DCG per query (CalMaxDCGAtK at max_position): the
+        # documents by query and descending label, the first k of each
+        discounts = 1.0 / np.log2(np.arange(int(sizes.max()) + 2) + 2.0)
+        qid = np.repeat(np.arange(self.num_queries), sizes)
+        by_label = np.lexsort((-ilabel, qid))
+        place = np.arange(num_data) - bounds[qid]
+        top = place < self.config.max_position
+        max_dcg = np.bincount(
+            qid[top], gains[ilabel[by_label][top]] * discounts[place[top]],
+            minlength=self.num_queries)
+        inv_max_dcg = np.divide(1.0, max_dcg, out=np.zeros_like(max_dcg),
+                                where=max_dcg > 0)
 
-        self._qidx = jnp.asarray(qidx)
-        self._valid = jnp.asarray(valid)
-        self._inv_max_dcg = jnp.asarray(inv_max_dcg, jnp.float32)
-        self._gains = jnp.asarray(gains, jnp.float32)
-        self._label_pad = jnp.concatenate(
-            [self.labels, jnp.zeros((1,), jnp.float32)])
-        self._discount = jnp.asarray(discounts[:D], jnp.float32)
-        self._D = D
-        # chunk so chunk * D * D floats stays bounded (~64 MB)
-        self._chunk = max(1, min(self.num_queries, int(16e6 // max(D * D, 1)) or 1))
+        table = np.asarray(_length_table(int(sizes.max())))
+        bucket_of = np.searchsorted(table, sizes)
+        label_pad = np.append(label, 0).astype(np.float32)
+        idx, lens, inv, self._buckets = [], [], [], []
+        slot_of_row = np.empty(num_data, np.int64)
+        slots = qslots = 0
+        for b in np.unique(bucket_of):
+            D = int(table[b])
+            qs = np.flatnonzero(bucket_of == b)
+            C = min(len(qs), max(1, int(_PAIR_BLOCK // (D * D))))
+            Q = -(-len(qs) // C) * C             # whole chunks of C queries
+            col = np.arange(D)
+            real = col[None, :] < sizes[qs][:, None]
+            rows = np.full((Q, D), num_data, np.int64)
+            rows[:len(qs)] = np.where(real, bounds[qs][:, None] + col,
+                                      num_data)
+            slot_of_row[rows[:len(qs)][real]] = \
+                slots + np.flatnonzero(real.ravel())
+            idx.append(rows.ravel())
+            lens.append(np.pad(sizes[qs], (0, Q - len(qs))))
+            inv.append(np.pad(inv_max_dcg[qs], (0, Q - len(qs))))
+            self._buckets.append((slots, qslots, Q, D, C))
+            slots += Q * D
+            qslots += Q
+        idx = np.concatenate(idx)
+        self._pair_slots = sum(Q * D * D for _, _, Q, D, _ in self._buckets)
+        # converted here, on the host: a dtype given to jnp.asarray is one
+        # more device program to compile and run, each
+        self._idx = jnp.asarray(idx.astype(np.int32))
+        slot_label = label_pad[idx]
+        self._slot_label = jnp.asarray(slot_label)
+        self._slot_gain = jnp.asarray(
+            gains[slot_label.astype(np.int64)].astype(np.float32))
+        self._len = jnp.asarray(np.concatenate(lens).astype(np.int32))
+        self._inv_max_dcg = jnp.asarray(
+            np.concatenate(inv).astype(np.float32))
+        self._slot_of_row = jnp.asarray(slot_of_row.astype(np.int32))
+        self._discount = jnp.asarray(discounts.astype(np.float32))
 
-    def get_gradients(self, score):
-        s_pad = jnp.concatenate([score[0], jnp.full((1,), 0.0, score.dtype)])
+    def _one_chunk(self, args):
+        s, y, gain, n, inv_mdcg = args     # [C, D] x 3, [C], [C]
         sigma = self.config.sigmoid
-
-        def one_chunk(args):
-            qidx, valid, inv_mdcg = args          # [C, D], [C, D], [C]
-            s = jnp.where(valid, s_pad[qidx], -jnp.inf)
-            y = jnp.where(valid, self._label_pad[qidx], -1.0)
-            order = jnp.argsort(-s, axis=1)        # descending scores
-            ss = jnp.take_along_axis(s, order, axis=1)
-            sy = jnp.take_along_axis(y, order, axis=1).astype(jnp.int32)
-            sval = jnp.take_along_axis(valid, order, axis=1)
-            gain = self._gains[jnp.clip(sy, 0)]
-            disc = jnp.where(sval, self._discount[None, :], 0.0)
-            best = ss[:, :1]
-            cnt = sval.sum(axis=1)
-            worst = jnp.take_along_axis(
-                ss, jnp.maximum(cnt - 1, 0)[:, None], axis=1)
-            nondegen = best != worst               # [C, 1]
-
-            ds = ss[:, :, None] - ss[:, None, :]   # s_high - s_low
-            pair = ((sy[:, :, None] > sy[:, None, :])
-                    & sval[:, :, None] & sval[:, None, :])
-            dcg_gap = gain[:, :, None] - gain[:, None, :]
+        D = s.shape[1]
+        col = jnp.arange(D)
+        valid = col[None, :] < n[:, None]
+        both = valid[:, :, None] & valid[:, None, :]
+        ds = s[:, :, None] - s[:, None, :]         # s_i - s_j
+        with jax.named_scope("rank_sort"):
+            ahead = (ds < 0) | ((ds == 0) & (col[None, :] < col[:, None]))
+            rank = jnp.sum(ahead & both, axis=2)
+            disc = self._discount[rank]
+            best = jnp.max(jnp.where(valid, s, -jnp.inf), axis=1)
+            worst = jnp.min(jnp.where(valid, s, jnp.inf), axis=1)
+            nondegen = (best != worst)[:, None, None]
+        with jax.named_scope("rank_pairs"):
+            # document i against j, from i's side: i is the pair's high
+            # document where its label is the larger, else its low one
+            high = y[:, :, None] > y[:, None, :]
+            pair = (y[:, :, None] != y[:, None, :]) & both
+            sign = jnp.where(high, 1.0, -1.0)
+            dcg_gap = sign * (gain[:, :, None] - gain[:, None, :])
             paired_disc = jnp.abs(disc[:, :, None] - disc[:, None, :])
             delta_ndcg = dcg_gap * paired_disc * inv_mdcg[:, None, None]
             delta_ndcg = jnp.where(
-                nondegen[:, :, None],
-                delta_ndcg / (0.01 + jnp.abs(ds)), delta_ndcg)
-            p = 2.0 / (1.0 + jnp.exp(2.0 * sigma * ds))
-            lam = jnp.where(pair, -delta_ndcg * p, 0.0)
+                nondegen, delta_ndcg / (0.01 + jnp.abs(ds)), delta_ndcg)
+            p = 2.0 / (1.0 + jnp.exp(2.0 * sigma * sign * ds))
+            lam = jnp.where(pair, -sign * delta_ndcg * p, 0.0)
             hes = jnp.where(pair, p * (2.0 - p) * 2.0 * delta_ndcg, 0.0)
-            lam_i = lam.sum(axis=2) - lam.sum(axis=1)   # high gets +, low gets -
-            hes_i = hes.sum(axis=2) + hes.sum(axis=1)
-            # scatter back from sorted positions to original rows
-            rows = jnp.take_along_axis(qidx, order, axis=1)
-            return rows, lam_i, hes_i
+            return lam.sum(axis=2), hes.sum(axis=2)
 
-        Q, D = self._qidx.shape
-        C = self._chunk
-        pad_q = (-Q) % C
-        qidx = jnp.pad(self._qidx, ((0, pad_q), (0, 0)),
-                       constant_values=self.num_data)
-        validp = jnp.pad(self._valid, ((0, pad_q), (0, 0)))
-        inv = jnp.pad(self._inv_max_dcg, (0, pad_q))
-        nchunks = (Q + pad_q) // C
-        rows, lam, hes = lax.map(
-            one_chunk,
-            (qidx.reshape(nchunks, C, D), validp.reshape(nchunks, C, D),
-             inv.reshape(nchunks, C)))
-        g = jnp.zeros((self.num_data + 1,), jnp.float32)
-        h = jnp.zeros((self.num_data + 1,), jnp.float32)
-        g = g.at[rows.reshape(-1)].add(lam.reshape(-1))
-        h = h.at[rows.reshape(-1)].add(hes.reshape(-1))
-        g, h = g[:-1], h[:-1]
+    def get_gradients(self, score):
+        # trace-time identity evidence (the hist_dispatch discipline): what
+        # was built, per compiled call site
+        obs_counters.inc("objective_dispatch", impl="buckets",
+                         buckets=len(self._buckets),
+                         slots=int(self._idx.shape[0]),
+                         pair_slots=self._pair_slots)
+        with jax.named_scope("rank_sort"):
+            s_slot = jnp.append(score[0], 0.0)[self._idx]
+        lam, hes = [], []
+        for off, qoff, Q, D, C in self._buckets:
+            def table(a):
+                return a[off:off + Q * D].reshape(Q // C, C, D)
+
+            def per_query(a):
+                return a[qoff:qoff + Q].reshape(Q // C, C)
+            args = (table(s_slot), table(self._slot_label),
+                    table(self._slot_gain), per_query(self._len),
+                    per_query(self._inv_max_dcg))
+            if Q == C:
+                out = self._one_chunk(tuple(a[0] for a in args))
+            else:
+                out = lax.map(self._one_chunk, args)
+            lam.append(out[0].reshape(-1))
+            hes.append(out[1].reshape(-1))
+        with jax.named_scope("rank_write"):
+            g = jnp.concatenate(lam)[self._slot_of_row]
+            h = jnp.concatenate(hes)[self._slot_of_row]
         if self.weights is not None:
             g = g * self.weights
             h = h * self.weights

@@ -285,3 +285,30 @@ def test_packed_grower_lowers(v5e):
                v5e((n,), jnp.float32), v5e((n,), jnp.float32),
                v5e((n,), jnp.float32), meta,
                v5e((f,), jnp.bool_)).compile()
+
+
+def test_lambdarank_buckets_lower(v5e):
+    """The ranking objective over length buckets compiles for v5e at
+    MS LTR's query lengths (1 to 1,251: every table size from 1 to 1,536
+    that the draw fills), and no ``[C, D, D]`` pair block is written to
+    memory: the temporaries stay under what ONE chunk's block would take."""
+    import jax.numpy as jnp
+    from lightgbm_tpu import objectives
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.data.metadata import Metadata
+    rng = np.random.default_rng(31)
+    sizes = np.concatenate([[1, 2, 1251, 1024, 700, 500],
+                            rng.integers(1, 400, 1500)])
+    n = int(sizes.sum())
+    md = Metadata()
+    md.label = rng.integers(0, 5, n).astype(np.float32)
+    md.weight = None
+    md.query_boundaries = np.concatenate([[0], np.cumsum(sizes)]) \
+        .astype(np.int32)
+    obj = objectives.LambdarankNDCG(Config())
+    obj.init(md, n)
+    assert len(obj._buckets) >= 14
+    compiled = jax.jit(obj.get_gradients).lower(
+        v5e((1, n), jnp.float32)).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 4 * objectives._PAIR_BLOCK, temp
