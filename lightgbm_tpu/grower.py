@@ -188,24 +188,33 @@ def _row_leaf_from_intervals(order, leaf_start, leaf_cnt, n):
 
     ``leaf_start``/``leaf_cnt`` always partition positions [0, n) into
     disjoint per-leaf intervals, so the map is an interval lookup pushed
-    through the ``order`` permutation.  Computing it ONCE per tree here
-    replaces the per-split scatter the loop body used to do — the scatter
-    traffic drops from sum-of-window-sizes (~N*log2 L elements/tree) to a
-    single N-element pass."""
-    L = leaf_start.shape[0]
-    active = leaf_cnt > 0
-    starts = jnp.where(active, leaf_start, n)     # inactive -> spill slot n
-    leaf_ids = jnp.arange(L, dtype=jnp.int32)
-    leaf_at = jnp.zeros((n + 1,), jnp.int32).at[starts].set(leaf_ids)
-    # mark each interval head with its own position; cummax forward-fills
-    # so position p sees the start of the interval containing it (marks at
-    # non-head positions are 0, never above the true head)
-    marks = jnp.zeros((n + 1,), jnp.int32).at[starts].set(
-        jnp.where(active, leaf_start, 0))[:n]
-    head = lax.cummax(marks, axis=0)
-    leaf_of_pos = leaf_at.at[head].get(mode="promise_in_bounds")
-    return jnp.zeros((n,), jnp.int32).at[order[:n]].set(
-        leaf_of_pos, unique_indices=True, mode="promise_in_bounds")
+    through the ``order`` permutation, ONCE per tree, and with no lookup
+    by N indices: on the v5e a gather or a scatter pays 6 to 9 ns an
+    element where this two-operand sort moves one for 2.1 (10.5M rows:
+    22.5 ms a tree where a gather and a scatter took 179; PERF.md section
+    6, PR 33).
+
+    *Leaf of a position*: the L intervals ranked by ``start``, at each
+    start the step from the previous interval's leaf id to this one's
+    (inactive leaves rank last and spill to slot ``n``), and a cumulative
+    sum over ``[0, n)``: integer, exact.  *Row order*: ``order[:n]`` holds
+    every row once, so it is a unique key and the plain two-operand sort
+    by it IS the inverse permutation (asked for a stable sort XLA carries
+    an ``iota`` as a third operand: :func:`partition_window`)."""
+    with jax.named_scope("row_leaf"):
+        obs_counters.inc("row_leaf_dispatch", impl="sort")
+        L = leaf_start.shape[0]
+        starts = jnp.where(leaf_cnt > 0, leaf_start, n)   # inactive -> n
+        starts, leaf_ids = lax.sort(
+            (starts, jnp.arange(L, dtype=jnp.int32)), num_keys=1)
+        prev = jnp.concatenate([jnp.zeros((1,), jnp.int32), leaf_ids[:-1]])
+        steps = jnp.zeros((n + 1,), jnp.int32).at[starts].add(
+            leaf_ids - prev, indices_are_sorted=True,
+            mode="promise_in_bounds")
+        leaf_of_pos = jnp.cumsum(steps[:n])       # slot n is not read
+        _, row_leaf = lax.sort((order[:n], leaf_of_pos), num_keys=1,
+                               is_stable=False)
+        return row_leaf
 
 
 class _LoopState(NamedTuple):

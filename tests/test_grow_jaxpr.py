@@ -285,7 +285,11 @@ def test_gspmd_grower_has_no_order_carrier_copies():
 # the same table, 3,906,168 with the two half-step branches, 3,709,560
 # with ``order`` 24,577 entries shorter (two live copies of it; the same
 # for the stable three-operand sort and the two-operand one): the budget
-# stands.
+# stands.  Read again in PR 33, whose end-of-tree map (``row_leaf``: a
+# cumulative sum and one sort where a gather and a scatter of N were) is
+# outside the loop and moves no pin on the body: 3,709,560 on the parent,
+# 3,578,552 with it (one N-entry i32 temporary fewer); body 392 / 505
+# equations and 14 order copies as before.  Nothing re-recorded.
 
 TEMP_BYTES_BUDGET = 4_100_000
 TEMP_BYTES_FLOOR = 1_000_000    # sanity: hist_store alone is 368,640 —
@@ -313,3 +317,17 @@ def test_compiled_grower_temp_bytes_within_budget():
     # the helper records the evidence as gauges for reports/benches
     assert counters.snapshot()["gauges"]["exec_grow_pin_temp_bytes"] == \
         m["temp_bytes"]
+
+
+def test_row_leaf_map_is_scoped_and_counted_once_a_trace():
+    """The end-of-tree map carries what names it in a capture and in the
+    counters: the ``row_leaf`` scope on its sort (the benchmark's
+    ``row_leaf_ms_per_tree`` reads device time by that token) and one
+    ``row_leaf_dispatch{impl=sort}`` a trace."""
+    from lightgbm_tpu.obs.counters import counters
+    grow, args = _grow_and_args()
+    before = counters.get("row_leaf_dispatch").get("impl=sort", 0)
+    txt = jax.jit(grow).lower(*args).as_text(debug_info=True)
+    assert counters.get("row_leaf_dispatch") == {"impl=sort": before + 1}
+    assert re.search(r'jit\(grow_tree\)/row_leaf/sort', txt)
+    assert re.search(r'jit\(grow_tree\)/row_leaf/jit\(cumsum\)', txt)
