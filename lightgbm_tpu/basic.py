@@ -75,7 +75,12 @@ def _load_pandas_categorical(model_str: str):
 
 
 class Dataset:
-    """Lazily-constructed training dataset (basic.py:548+ semantics)."""
+    """Lazily-constructed training dataset over a numpy array, a pandas frame, a text file or a ``scipy.sparse`` matrix (basic.py:548+ semantics).
+
+    A ``scipy.sparse`` matrix of any format is read as CSR over its own
+    buffers and binned from its stored entries (``data/sparse.py``,
+    ``data/dataset.construct_csr``): no dense matrix is made, for training
+    data and for a ``reference=`` validation set alike."""
 
     def __init__(self, data, label=None, reference: Optional["Dataset"] = None,
                  weight=None, group=None, init_score=None,
@@ -246,9 +251,13 @@ class Dataset:
             if self.free_raw_data:
                 self.data = None
             return self
+        sparse = data_mod.sparse.from_scipy(self.data)
+        if sparse is not None:      # scipy.sparse of any format, as CSR
+            self.data = sparse
         if isinstance(self.data, data_mod.CsrMatrix):
-            # sparse C-ABI ingest: two-round chunked binning — the full
-            # dense float64 matrix never materializes (data/sparse.py)
+            # sparse ingest (scipy.sparse, the C ABI): binned from the
+            # stored entries — no dense matrix of any width materializes
+            # (data/sparse.py, dataset.construct_csr)
             names = (list(self.feature_name)
                      if isinstance(self.feature_name, (list, tuple))
                      else None)
@@ -676,7 +685,7 @@ class Dataset:
 
 
 class Booster:
-    """Training/prediction handle (basic.py:1213+ semantics)."""
+    """Training/prediction handle; ``predict`` takes a numpy array, a pandas frame, a text file or a ``scipy.sparse`` matrix (basic.py:1213+ semantics)."""
 
     def __init__(self, params: Optional[Dict[str, Any]] = None,
                  train_set: Optional[Dataset] = None,
@@ -852,6 +861,18 @@ class Booster:
         elif hasattr(data, "columns") and hasattr(data, "dtypes"):
             data = _data_from_pandas(data, self.pandas_categorical)[0]
         else:
+            sparse = (data if isinstance(data, data_mod.CsrMatrix)
+                      else data_mod.sparse.from_scipy(data))
+            if sparse is not None and len(sparse):
+                # one budget-bounded dense chunk at a time; per-row output
+                # width is fixed, so the chunks' outputs concatenate
+                args = dict(num_iteration=num_iteration, raw_score=raw_score,
+                            pred_leaf=pred_leaf, pred_contrib=pred_contrib,
+                            pred_early_stop=pred_early_stop,
+                            pred_parameter=pred_parameter, **kwargs)
+                return np.concatenate(
+                    [self.predict(block, **args)
+                     for _, block in sparse.iter_dense_chunks()], axis=0)
             data = _to_matrix(data)
         if num_iteration is None or num_iteration <= 0:
             num_iteration = self.best_iteration if self.best_iteration > 0 else -1

@@ -19,12 +19,15 @@ from typing import Dict, List, Optional, Sequence, Union
 import numpy as np
 
 from ..config import Config
+from ..obs import trace as obs_trace
+from ..obs.counters import counters as obs_counters
 from ..utils import log
 from ..utils.random import make_rng, sample_k
 from .binning import (BIN_TYPE_CATEGORICAL, BIN_TYPE_NUMERICAL, BinMapper,
                       MISSING_NAN, MISSING_NONE, MISSING_ZERO)
 from .bundling import BundleLayout, build_bundled_column, find_bundles
 from .metadata import Metadata
+from . import sparse as sparse_mod
 
 
 def jax_process_index() -> int:
@@ -222,20 +225,22 @@ def _fit_from_sample(ds: TrainingData, sample: np.ndarray, config: Config,
         if n_proc > 1 and jax_process_index() != 0:
             bundles = None     # rank 0 decides, everyone else receives
         else:
-            bs = sample[:min(len(sample), 20000)]
-            nonzero = np.zeros((bs.shape[0], len(ds.used_features)),
-                               dtype=bool)
-            for b0 in range(0, len(ds.used_features), _COL_BLOCK):
-                chunk = ds.used_features[b0:b0 + _COL_BLOCK]
-                cols_t = _columns_T(bs, chunk)
-                for k, _ in enumerate(chunk):
-                    nonzero[:, b0 + k] = (cols_t[k] != 0) | np.isnan(cols_t[k])
-            bundles_local = find_bundles(
-                nonzero,
-                [ds.bin_mappers[j].num_bin for j in ds.used_features],
-                config.max_conflict_rate)
-            bundles = [[ds.used_features[k] for k in b]
-                       for b in bundles_local]
+            with obs_trace.phase("dataset.find_bundles"):
+                bs = sample[:min(len(sample), 20000)]
+                nonzero = np.zeros((bs.shape[0], len(ds.used_features)),
+                                   dtype=bool)
+                for b0 in range(0, len(ds.used_features), _COL_BLOCK):
+                    chunk = ds.used_features[b0:b0 + _COL_BLOCK]
+                    cols_t = _columns_T(bs, chunk)
+                    for k, _ in enumerate(chunk):
+                        nonzero[:, b0 + k] = ((cols_t[k] != 0)
+                                              | np.isnan(cols_t[k]))
+                bundles_local = find_bundles(
+                    nonzero,
+                    [ds.bin_mappers[j].num_bin for j in ds.used_features],
+                    config.max_conflict_rate)
+                bundles = [[ds.used_features[k] for k in b]
+                           for b in bundles_local]
         if n_proc > 1:
             # the bundle plan must be identical everywhere; rank 0's
             # local sample decides (the mapper set is already global)
@@ -247,6 +252,9 @@ def _fit_from_sample(ds: TrainingData, sample: np.ndarray, config: Config,
             ds.used_features = layout.sub_features
             log.info("EFB bundled %d features into %d columns",
                      len(layout.sub_features), layout.num_columns)
+            obs_counters.inc("efb_layout", logical=len(layout.sub_features),
+                             physical=layout.num_columns,
+                             max_slots=layout.max_col_bins())
 
 
 def _bin_rows(ds: TrainingData, data: np.ndarray, out: np.ndarray) -> None:
@@ -388,17 +396,22 @@ def construct_csr(csr,
                   feature_names: Optional[Sequence[str]] = None,
                   categorical_features: Optional[Sequence[int]] = None,
                   reference: Optional[TrainingData] = None) -> TrainingData:
-    """Two-round construction from a host :class:`~.sparse.CsrMatrix`
-    without densifying it (the C-ABI sparse ingest).
+    """Construction from a host :class:`~.sparse.CsrMatrix` in time and
+    memory proportional to its STORED ENTRIES (``scipy.sparse`` input and
+    the C-ABI sparse ingest; the reference's sparse push,
+    ``LGBM_DatasetCreateFromCSR``).
 
     Round 1 densifies ONLY the sampled rows — CSR rows are O(nnz) random
     access, so unlike the text-file path no full pass is needed; round 2
-    streams budget-bounded dense chunks through :func:`_bin_rows`
-    straight into the final uint8/16 matrix.  Peak extra memory is the
-    sample matrix plus one chunk; the full ``[nrow, ncol]`` float64
-    matrix never exists.  Sample indices and ordering match the
-    in-memory path exactly, so the fitted mappers — and therefore the
-    trained model — are bit-identical to densify-then-construct."""
+    (:func:`_bin_stored`) fills every physical column with what an absent
+    entry bins to and writes the stored entries' bins over it, one
+    budget-bounded run of rows at a time.  No ``[nrow, ncol]`` buffer of
+    any width exists, and beside the source and the binned matrix only
+    one run's entries.  Sample indices and ordering match the in-memory
+    path exactly, and a row where two columns of a bundle are both set
+    keeps the later one as ``build_bundled_column`` does, so the fitted
+    mappers, the binned matrix — and therefore the trained model — are
+    bit-identical to densify-then-construct."""
     num_data, num_features = csr.shape
     ds = TrainingData()
     ds.num_data = num_data
@@ -428,16 +441,76 @@ def construct_csr(csr,
         del sample
 
     dtype = np.uint8 if ds.max_num_bin() <= 256 else np.uint16
-    ncols = (ds.layout.num_columns
-             if ds.layout is not None and ds.layout.has_bundles
-             else len(ds.used_features))
-    binned = np.empty((num_data, ncols), dtype=dtype)
-    for r0, block in csr.iter_dense_chunks():
-        _bin_rows(ds, block, binned[r0:r0 + len(block)])
-    ds.binned = binned
+    with obs_trace.phase("dataset.bin_sparse"):
+        ds.binned = _bin_stored(ds, csr, dtype)
 
     _set_metadata(ds, num_data, label, weight, group, init_score)
     return ds
+
+
+def _bin_stored(ds: TrainingData, csr, dtype) -> np.ndarray:
+    """Bin a :class:`~.sparse.CsrMatrix` from its stored entries alone,
+    into the ``[nrow, physical columns]`` matrix: a budget-bounded run of
+    rows at a time (:meth:`~.sparse.CsrMatrix.iter_by_column`), each
+    physical column of the run starts as the bin an absent entry (0.0)
+    has, then each source column's stored values are binned and written
+    at their rows.  Beside the source and the result only one run's
+    entries and columns exist.
+
+    In a bundle the source columns go in bundle order, so where two are
+    non-default in one row the later one stays
+    (:func:`~.bundling.build_bundled_column`'s rule, the reference's
+    push order)."""
+    mappers = ds.bin_mappers
+    lay = ds.layout if ds.layout is not None and ds.layout.has_bundles \
+        else None
+    bundles = lay.bundles if lay else [[j] for j in ds.used_features]
+    # every column's first slot, a list a bundle (the layout's is flat)
+    ends = np.cumsum([len(b) for b in bundles])
+    offsets = [lay.sub_offset[e - len(b):e] if lay else [-1]
+               for b, e in zip(bundles, ends)]
+    zero_bin = {j: mappers[j].value_to_bin_scalar(0.0)
+                for b in bundles for j in b}
+    itemsize = np.dtype(dtype).itemsize
+    binned = np.empty((csr.nrow, len(bundles)), dtype=dtype)
+    for r0, r1, colptr, rows, values in csr.iter_by_column(
+            sparse_mod.CSR_CHUNK_BUDGET_BYTES
+            // max(1, len(bundles) * itemsize)):
+        n = r1 - r0
+        # built a column a line, turned row-major a run at a time: a
+        # column of a row-major matrix is a strided write of n bytes
+        lines = np.empty((len(bundles), n), dtype=dtype)
+
+        def stored(j):
+            """(rows, values, bins) of source column ``j`` in the run."""
+            lo, hi = int(colptr[j]), int(colptr[j + 1])
+            bins = np.empty(hi - lo, dtype=dtype)
+            mappers[j].bin_into(values[lo:hi], bins)
+            return rows[lo:hi], values[lo:hi], bins
+
+        for line, bundle, offs in zip(lines, bundles, offsets):
+            if len(bundle) == 1:
+                line[:] = zero_bin[bundle[0]]
+                at, _, bins = stored(bundle[0])
+                line[at] = bins
+                continue
+            line[:] = 0                            # slot 0: all default
+            for j, off in zip(bundle, offs):
+                db = mappers[j].default_bin
+                at, vals, bins = stored(j)
+                if zero_bin[j] != db:
+                    # an absent entry is not in this column's default bin
+                    # (a categorical column): every row of it is written,
+                    # so it is walked whole, as one column of n values
+                    whole = np.zeros(n, dtype=np.float64)
+                    whole[at] = vals
+                    at, bins = np.arange(n), np.empty(n, dtype=dtype)
+                    mappers[j].bin_into(whole, bins)
+                keep = bins != db
+                at, b = at[keep], bins[keep].astype(np.int32)
+                line[at] = (off + b - (b > db)).astype(dtype)
+        binned[r0:r1] = lines.T
+    return binned
 
 
 def _filter_cnt(config: Config, sample_cnt: int, num_data: int) -> int:

@@ -264,9 +264,14 @@ class SerialStrategy:
     * ``reduce_hist(hist) -> hist`` — cross-shard reduction of a freshly
       measured histogram (data-parallel: ``psum``; voting: identity, its
       reduction happens selectively inside ``find``);
+    * ``expand(ctx, hist, pg, ph, pc) -> hist`` — a measured histogram
+      over PHYSICAL (bundle) columns as one over the logical columns the
+      scan reads (EFB; called only on a bundled data set, under the
+      device scope ``bundle_expand``);
     * ``find(ctx, hist, pg, ph, pc, feat_ok) -> (SplitResult, feat_ok')``
       — globally agreed best split (feature indices in the full/global
-      numbering) plus the leaf's per-feature is_splittable flags.
+      numbering) plus the leaf's per-feature is_splittable flags, from
+      the histogram ``expand`` returned.
       ``feat_ok`` [E] carries the PARENT leaf's flags: features it
       prunes are excluded from this scan, and from the whole subtree —
       the reference's feature-pruning heuristic
@@ -295,10 +300,11 @@ class SerialStrategy:
     def reduce_hist(self, hist):
         return hist
 
+    def expand(self, ctx, hist, pg, ph, pc):
+        return expand_bundle_hist(hist, pg, ph, pc, ctx[2])
+
     def find(self, ctx, hist, pg, ph, pc, feat_ok):
-        meta, feat_valid, maps, fctx = ctx
-        if maps is not None:
-            hist = expand_bundle_hist(hist, pg, ph, pc, maps)
+        meta, feat_valid, _, fctx = ctx
         return best_split(hist, pg, ph, pc, meta.num_bin,
                           meta.missing_type, meta.default_bin,
                           feat_valid & feat_ok, self.cfg.split_config(),
@@ -383,7 +389,8 @@ def route_goes_left(binf, meta: FeatureMeta, feat, thr, dleft,
     lives here once.  ``binf`` is the PHYSICAL bin column (bundle decode
     happens inside when the meta carries EFB maps)."""
     if meta.col is not None:  # EFB: physical slot -> logical bin
-        binf = decode_bundle_bin(binf, feat, meta)
+        with jax.named_scope("bundle_decode"):
+            binf = decode_bundle_bin(binf, feat, meta)
     mt_f = meta.missing_type[feat]
     nb_f = meta.num_bin[feat]
     db_f = meta.default_bin[feat]
@@ -699,6 +706,18 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
             with jax.named_scope("split_find"):
                 return strategy.find(ctx, hist, pg, ph, pc, feat_ok)
 
+        bundled = meta.col is not None
+
+        def expand(hist, pg, ph, pc):
+            """EFB: the measured [F_physical, B, 3] histogram as the
+            [E_logical, B, 3] one the scan reads.  A scope of its own,
+            opened BESIDE ``split_find`` and never inside it: a trace
+            charges an operation to the leftmost scope of its name."""
+            obs_counters.inc("bundle_expand_dispatch",
+                             logical=meta.num_bin.shape[0], physical=fh)
+            with jax.named_scope("bundle_expand"):
+                return strategy.expand(ctx, hist, pg, ph, pc)
+
         def hist_subset(rows, g_, h_, c_, site="split"):
             return subset_histogram(rows, g_, h_, c_, hist_width,
                                     method=cfg.hist_method, site=site)
@@ -826,8 +845,9 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
             else:
                 hist_root = globalize(hist_subset(hbins, gw, hw, cw,
                                                   site="root"))
-        res_root, root_feat_ok = find(hist_root, root_g, root_h, root_c,
-                                      feat_ok_all)
+        res_root, root_feat_ok = find(
+            expand(hist_root, root_g, root_h, root_c) if bundled
+            else hist_root, root_g, root_h, root_c, feat_ok_all)
         res_root = _depth_gate(res_root, jnp.asarray(0), cfg.max_depth)
 
         hist_store0 = jnp.zeros((L, 3 * fh * cfg.max_bin), dtype)
@@ -989,12 +1009,17 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
             lr3 = jnp.stack([lax.slice(frow, (0,), (3,)),
                              lax.slice(frow, (3,), (6,))])   # [2, 3]
             sl3 = jnp.where(small_left, lr3, lr3[::-1])
-            # the scope is entered OUTSIDE the vmap too: inside it alone
+            # the scopes are entered OUTSIDE the vmap too: inside it alone
             # the children's scan is named ``vmap(split_find)``, which a
             # trace's scope pattern does not read as ``split_find``
+            scan2 = hist2
+            if bundled:
+                with jax.named_scope("bundle_expand"):
+                    scan2 = jax.vmap(expand)(hist2, sl3[:, 0], sl3[:, 1],
+                                             sl3[:, 2])
             with jax.named_scope("split_find"):
                 res2, fok2 = jax.vmap(find, in_axes=(0, 0, 0, 0, None))(
-                    hist2, sl3[:, 0], sl3[:, 1], sl3[:, 2], fok_parent)
+                    scan2, sl3[:, 0], sl3[:, 1], sl3[:, 2], fok_parent)
             res2 = _depth_gate(res2, child_depth, cfg.max_depth)
             feat_ok = state.feat_ok.at[pair_sl].set(fok2 & fok_parent[None, :],
                                                     unique_indices=True)
@@ -1102,9 +1127,10 @@ class StreamedGrower:
                                    meta.default_bin, cfg.max_bin, scfg)
                     if scfg.split_find == "fused" else None)
             obs_counters.inc("split_find_dispatch", impl=cfg.split_find)
-            with jax.named_scope("split_find"):
-                if maps is not None:
+            if maps is not None:
+                with jax.named_scope("bundle_expand"):
                     hist = expand_bundle_hist(hist, pg, ph, pc, maps)
+            with jax.named_scope("split_find"):
                 return best_split(hist, pg, ph, pc, meta.num_bin,
                                   meta.missing_type, meta.default_bin,
                                   feat_valid & feat_ok, scfg,

@@ -37,7 +37,9 @@ users:
   "which step recompiled, and what it cost".  These are the
   :data:`PROCESS_COUNTERS`: they describe the process (a data set binned
   before ``train()``, a program compiled by an earlier booster), so the
-  per-training reset keeps them.
+  per-training reset keeps them.  ``efb_layout`` tagged ``logical=``,
+  ``physical=``, ``max_slots=`` is of their kind: one count a data set
+  that exclusive feature bundling packed, at its construction.
 
 Counts recorded from inside jit tracing are TRACE-time counts (once per
 compiled call site), which is exactly the "per call site" identity the
@@ -53,7 +55,7 @@ from typing import Any, Dict, Iterable, List, Optional
 
 # families that outlive a training (see the module docstring)
 PROCESS_COUNTERS = ("phase_seconds", "phase_calls", "compile_seconds",
-                    "compile_calls", "compile_cache_hits")
+                    "compile_calls", "compile_cache_hits", "efb_layout")
 
 
 def _tag_key(tags: Dict[str, Any]) -> str:

@@ -134,9 +134,10 @@ def make_gspmd_grower(cfg: GrowerConfig, mesh: Mesh,
 
         def find(hist, pg, ph, pc, feat_ok):
             obs_counters.inc("split_find_dispatch", impl=cfg.split_find)
-            with jax.named_scope("split_find"):
-                if maps is not None:
+            if maps is not None:
+                with jax.named_scope("bundle_expand"):
                     hist = expand_bundle_hist(hist, pg, ph, pc, maps)
+            with jax.named_scope("split_find"):
                 return best_split(hist, pg, ph, pc, meta.num_bin,
                                   meta.missing_type, meta.default_bin,
                                   feat_valid & feat_ok, scfg,

@@ -139,14 +139,16 @@ class FeatureParallelStrategy(SerialStrategy):
     def hist_bins(self, ctx, bins):
         return ctx[2]
 
+    def expand(self, ctx, hist, pg, ph, pc):
+        # the local physical histograms into the (global) logical feature
+        # space; features outside this shard's window are zeroed and
+        # masked, so the global numbering needs no feature_base shift
+        return expand_bundle_hist(hist, pg, ph, pc, ctx[6])
+
     def find(self, ctx, hist_child, pg, ph, pc, feat_ok):
         meta, feat_valid, _, meta_local, fv_local, start, maps = ctx
         if maps is not None:
-            # expand the local physical histograms into the (global) logical
-            # feature space; features outside this shard's window are zeroed
-            # and masked, so the global numbering needs no feature_base shift
-            hist_log = expand_bundle_hist(hist_child, pg, ph, pc, maps)
-            res, ok = best_split(hist_log, pg, ph, pc, meta.num_bin,
+            res, ok = best_split(hist_child, pg, ph, pc, meta.num_bin,
                                  meta.missing_type, meta.default_bin,
                                  feat_valid & maps[5] & feat_ok,
                                  self.cfg.split_config(),
@@ -235,23 +237,23 @@ class VotingStrategy(SerialStrategy):
     # communication compression); the parent-minus-smaller subtraction in
     # the grower is therefore performed in each shard's local space.
 
+    def expand(self, ctx, hist, pg, ph, pc):
+        # EFB: expand the LOCAL physical histograms with LOCAL parent
+        # sums (every row lands in exactly one bin of physical column 0,
+        # so its bin sums are the local leaf totals), not the global
+        # ``pg``/``ph``/``pc``.  Expansion is linear in the histogram
+        # given additive parents, so the psum of locally-expanded slices
+        # in ``find`` equals the expansion of the psum-reduced histogram.
+        pl = hist[0].sum(axis=0)                             # [3] local parent
+        return expand_bundle_hist(hist, pl[0], pl[1], pl[2], ctx[2])
+
     def find(self, ctx, hist_child, pg, ph, pc, feat_ok):
         # the voting scan runs on a SLICED feature subset, so the serial
         # strategy's full-width fused ctx does not apply (best_split
         # derives the masks inline on the fused path)
-        meta, feat_valid, maps, _ = ctx
+        meta, feat_valid, _, _ = ctx
         feat_valid = feat_valid & feat_ok
         scfg = self.cfg.split_config()
-        if maps is not None:
-            # EFB: expand the LOCAL physical histograms with LOCAL parent
-            # sums (every row lands in exactly one bin of physical column 0,
-            # so its bin sums are the local leaf totals).  Expansion is
-            # linear in the histogram given additive parents, so the psum of
-            # locally-expanded slices below equals the expansion of the
-            # psum-reduced histogram.
-            pl = hist_child[0].sum(axis=0)                   # [3] local parent
-            hist_child = expand_bundle_hist(hist_child, pl[0], pl[1], pl[2],
-                                            maps)
         f = hist_child.shape[0]
         k = min(self.top_k, f)
         # local votes from local histograms with LOCAL parent sums (PV-tree
