@@ -6,10 +6,12 @@ TPU-native re-design of ``SerialTreeLearner::Train``
 * the reference's ``DataPartition`` index reordering is kept as-is on device:
   an index array ``order`` groups rows contiguously by leaf
   (``data_partition.hpp:94-146``); per split only the SPLITTING leaf's
-  window of ``order`` is sliced out (a size of ``_bucket_sizes``), routed,
-  stably sorted left before right and written back — O(leaf) per split, exactly
-  the reference's per-leaf partition cost, summing to O(N·log L) per
-  tree instead of O(N·L);
+  window of ``order`` is sliced out (a size of ``_partition_sizes``),
+  routed, stably sorted left before right and written back — O(leaf) per
+  split, exactly the reference's per-leaf partition cost, summing to
+  O(N·log L) per tree instead of O(N·L); the few leaves that hold a good share of all
+  rows are split by one sort of all N rows instead (``partition_dense``:
+  a sort is cheaper an element than the window's read by row id);
 * per split only the **smaller child** is histogrammed — its rows are
   gathered through ``order`` into a padded buffer of a static size chosen by
   ``lax.switch`` (``_bucket_sizes``: ~log2(N) compiled buckets) and reduced by
@@ -226,6 +228,8 @@ class _LoopState(NamedTuple):
     surface).  ``TreeArrays`` is unpacked ONCE after the loop."""
     step: jnp.ndarray
     order: jnp.ndarray           # [N + tail] i32: row ids grouped by leaf
+    rl: jnp.ndarray              # [N] i32: the leaf each row is in, dense
+    #                              (what the partition's dense branch keys on)
     lsc: jnp.ndarray             # [L, 2] i32: (first position, local count)
     hist_store: jnp.ndarray      # [L, 3 * F * B]: per-leaf histograms, a
     #                              leaf's flat (pool_flat)
@@ -423,9 +427,9 @@ def bin_flags(flags, binf):
 
 
 def pack_row_bits(flags):
-    """``bool[N]`` -> ``uint32[M]``: row ``i``'s flag is bit ``i >> log2 M``
-    of word ``i & (M - 1)``, ``M`` the power of two that makes 32 planes
-    cover ``N``.  A plane is a contiguous run of rows, so
+    """``bool[N]`` (or 0 / 1 words) -> ``uint32[M]``: row ``i``'s flag is
+    bit ``i >> log2 M`` of word ``i & (M - 1)``, ``M`` the power of two
+    that makes 32 planes cover ``N``.  A plane is a contiguous run of rows, so
     packing is one aligned slice per plane that holds rows, shifted and
     or-ed: elementwise, no relayout.  The flags are widened to words FIRST:
     sliced as bytes, the v5e's compiler computes them twice (its program
@@ -484,6 +488,51 @@ def partition_window(order, start, cnt, size: int, left_bits):
     key = slot + jnp.where(goes_left, 0, jnp.where(valid, size, 2 * size))
     _, new_win = lax.sort((key, win), is_stable=False, num_keys=1)
     return lax.dynamic_update_slice(order, new_win, (start,)), nl
+
+
+def partition_dense(order, start, cnt, rl, left_leaf, right_leaf):
+    """:func:`partition_window`'s result for a leaf of any size, with no
+    read by row id: ONE one-operand sort over all ``n`` rows.
+
+    ``rl`` is the dense row -> leaf vector AFTER the split, ``i32[n]``: the
+    leaf's rows that go left carry ``left_leaf``, those that go right
+    ``right_leaf``, every other row its own leaf.  The key is unique,
+    ``group * n + row`` with the group left / right / any other leaf, so
+    the sorted keys less their group's offset are the left child's rows
+    ascending, then the right child's ascending, then the rest: the first
+    ``cnt`` of them, placed at ``start``, are the leaf's new window, and
+    every slot outside ``[start, start + cnt)`` comes back as it was, by a
+    dense select over ``order``.  Nothing here depends on a window size:
+    one branch serves every leaf above the window table
+    (:func:`_partition_sizes`).
+
+    **The invariant this rests on: every leaf's window of ``order`` ascends
+    by row id.**  The root's is ``arange(n)``, a stable partition keeps
+    both children's ascending, and nothing else writes ``order``.  So
+    "the sequence they had" (:func:`partition_window`) IS ascending row id,
+    and the two functions return the same ``order`` bit for bit
+    (``tests/test_partition_window.py``).
+
+    On the v5e a read by row id costs 7.13 ns a padded window slot
+    whatever its operand's size and this sort 1.27 ns a row, so for a leaf
+    that holds more than an eighth of all rows sorting all of them is the
+    cheaper transport: under 14 ms a call at 10.5M rows where the
+    root's window took 115 (PERF.md section 6, PR 35)."""
+    n = rl.shape[0]
+    if 3 * n > 2 ** 31:
+        raise ValueError(f"{n} rows: 3 * n overflows the int32 sort key")
+    total = order.shape[0]
+    group = jnp.where(rl == left_leaf, 0, jnp.where(rl == right_leaf, 1, 2))
+    nl = jnp.sum((group == 0).astype(jnp.int32))
+    key = lax.sort(group * n + jnp.arange(n, dtype=jnp.int32),
+                   is_stable=False)
+    rows = key - jnp.where(key < n, 0, jnp.where(key < 2 * n, n, 2 * n))
+    # rows[j] belongs at order[start + j]: a slice of the sorted rows,
+    # padded on both sides, that starts ``start`` slots before them
+    placed = lax.dynamic_slice(jnp.pad(rows, (n, total - n)), (n - start,),
+                               (total,))
+    pos = jnp.arange(total, dtype=jnp.int32)
+    return jnp.where((pos >= start) & (pos < start + cnt), placed, order), nl
 
 
 def pool_flat(hist):
@@ -561,6 +610,25 @@ def _depth_gate(res: SplitResult, leaf_depth, max_depth) -> SplitResult:
 # PR 30).
 HALF_STEP_ABOVE_LOG2 = 13
 
+# A padded window slot costs what this many rows of the dense branch cost,
+# so the partition's window table ends at the last size of at most
+# n / WINDOW_SLOT_COSTS_DENSE_ROWS slots and a larger leaf is partitioned
+# by ONE sort over all n rows (``partition_dense``).  Measured on the v5e
+# in the grow program (traced runs of the cells; PERF.md section 6, PR
+# 35).  A window costs 7.13 ns a padded slot for its read by row id at
+# every row count, and 0.86 to 2.0 for its sort: 8.5 ms at 1,048,576
+# slots, 13.1 at 1,572,864, 115 at the root's 12,582,912.  The dense
+# branch's one-operand sort costs 13.3 ms a call at 10.5M rows (1.27 ns a
+# row; 12.75 at 10M), 1.77 at 2.27M (0.78), 0.225 at 400,000 (0.56), its
+# passes under 0.7 ms more at 10.5M rows: a sort's passes grow with log n
+# where the read's price stays, so a slot costs 6.3 dense rows at 10.5M,
+# 9.4 at 2.27M and 12 at 400,000, and the two transports tie at 1,572,864
+# slots, between 196,608 and 262,144, and at 32,768.  8 puts the tie's
+# size on the dense side at 10.5M and 10M rows (one branch fewer to
+# compile, and ``order``'s tail halved) and keeps one size too many at
+# the two smaller row counts, which costs under 1 ms a tree there.
+WINDOW_SLOT_COSTS_DENSE_ROWS = 8
+
 
 def _bucket_sizes(cfg: "GrowerConfig", n: int):
     """The static, ascending table of window sizes covering [1, n]: one
@@ -582,23 +650,36 @@ def _bucket_sizes(cfg: "GrowerConfig", n: int):
         k += 1
 
 
+def _partition_sizes(cfg: "GrowerConfig", n: int):
+    """The partition's window table: :func:`_bucket_sizes`' sizes up to the
+    last one whose window branch is cheaper than the dense branch over all
+    ``n`` rows (:data:`WINDOW_SLOT_COSTS_DENSE_ROWS`), and the smallest
+    size in any case.  A leaf with more rows than the table's last size
+    takes :func:`partition_dense`, the branch after the table's
+    (``_bucket_index(cnt, sizes) == len(sizes)``)."""
+    sizes = _bucket_sizes(cfg, n)
+    return sizes[:1] + [s for s in sizes[1:]
+                        if s * WINDOW_SLOT_COSTS_DENSE_ROWS <= n]
+
+
 def _order_tail(sizes):
     """Sentinel slots ``order`` needs past its ``n`` rows so that no window
     is sliced out of bounds (``dynamic_slice`` would clamp its start and
     move the window).  A leaf of ``cnt`` rows ends at or before ``n`` and
     gets the smallest size that holds ``cnt``, so its window overhangs
     ``n`` by less than that size less the size below it: the tail is the
-    widest step of the table, not its largest size (4,194,303 slots for
-    12,582,912 at 10.5M rows)."""
+    widest step of the table, not its largest size (262,143 slots for
+    the partition's 1,048,576 at 10.5M rows; the dense branch slices no
+    window and needs none)."""
     return max([sizes[0]] + [b - a - 1 for a, b in zip(sizes, sizes[1:])])
 
 
 def _bucket_index(scnt, sizes):
-    """Index of the smallest bucket holding ``scnt`` rows: exact integer
-    comparisons against the static size table (a float log2 would
-    mis-round near large powers of two and silently drop rows)."""
-    table = jnp.asarray(sizes[:-1], jnp.int32)
-    return jnp.sum((scnt > table).astype(jnp.int32))
+    """Index of the smallest bucket holding ``scnt`` rows, ``len(sizes)``
+    where none does: exact integer comparisons against the static size
+    table (a float log2 would mis-round near large powers of two and
+    silently drop rows)."""
+    return jnp.sum((scnt > jnp.asarray(sizes, jnp.int32)).astype(jnp.int32))
 
 
 def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
@@ -647,9 +728,11 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
         fh = (pack_plan.num_phys_cols if pack_plan is not None
               else hbins.shape[1])
 
-        # static window sizes: one partition branch each (and one gather
-        # branch each on the XLA reference rungs)
+        # static window sizes: one gather branch each on the XLA reference
+        # rungs (the whole table) and one partition branch each up to
+        # where the dense branch is the cheaper one
         bsizes = _bucket_sizes(cfg, n)
+        psizes = _partition_sizes(cfg, n)
 
         # sentinel row n: weight 0, bin 0 — receives all buffer padding
         hbins_pad = jnp.concatenate(
@@ -772,45 +855,50 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
 
         # ---- localized partition (DataPartition::Split,
         # data_partition.hpp:94-146).  The reference re-partitions only the
-        # SPLITTING leaf's index range; the same here: each branch routes
-        # the split column, then partition_window slices the leaf's window
+        # SPLITTING leaf's index range; the same here: the split column
+        # is routed once, then a window branch slices the leaf's window
         # out of ``order`` and writes it back stably partitioned — O(leaf)
-        # per split, not O(N).  Routing decisions follow tree.h:257-313.
+        # per split, not O(N) — and a leaf larger than the table's last
+        # window takes the dense branch, one sort of all N rows, which is
+        # the cheaper transport there (_partition_sizes).  Routing
+        # decisions follow tree.h:257-313.
 
-        def partition_branch(size):
+        def route(feat, thr, dleft, is_cat_l, cat_row):
+            """``bool[N]``: the rows that go left.  The column is a
+            dense slice of the column-major copy and its N decisions are
+            elementwise (0.24 ms a split at 10.5M rows, paid by the
+            smallest window too)."""
+            col_idx = feat if meta.col is None else meta.col[feat]
+            colv = lax.dynamic_index_in_dim(
+                bins_cm, col_idx, axis=0, keepdims=False)
+            return route_goes_left(
+                colv.astype(jnp.int32), meta, feat, thr, dleft,
+                has_categorical=cfg.has_categorical,
+                is_cat_l=is_cat_l, cat_row=cat_row, max_bin=cfg.max_bin)
+
+        def window_branch(size):
 
             def branch(args):
-                if cfg.has_categorical:
-                    (order, start, cnt,
-                     feat, thr, dleft, is_cat_l, cat_row) = args
-                else:       # no categorical routing ops traced at all
-                    order, start, cnt, feat, thr, dleft = args
-                    is_cat_l = cat_row = None
-                # route the WHOLE split column, then read one bit per
-                # window row: the column is a dense slice of the
-                # column-major copy, its N decisions are elementwise
-                # (0.13 ms a split at 10.5M rows, paid by the smallest
-                # window too), and packed 32 to a word they make a
-                # table of N/8 bytes, small enough to stay on chip
-                # while a rank-1 gather reads it by row id: 8.6 ns an
-                # element on the v5e, where the (row, col) byte gather
-                # this replaces read 20 from HBM, and the column itself
-                # as s32[N], which the grow program also keeps in HBM,
-                # 23.5 (scripts/probe_route_read.py; PERF.md section 5)
+                # one bit per window row out of the routed column packed
+                # 32 to a word: a table of N/8 bytes, small enough to
+                # stay on chip while a rank-1 gather reads it by row id:
+                # 7.1 to 8.6 ns an element on the v5e, where the (row,
+                # col) byte gather this replaced read 20 from HBM, and
+                # the column itself as s32[N], which the grow program
+                # also keeps in HBM, 23.5 (scripts/probe_route_read.py;
+                # PERF.md section 5)
+                order, start, cnt, left_bits, _, _, _ = args
                 obs_counters.inc("partition_route_dispatch", read="column",
                                  size=size)
-                col_idx = feat if meta.col is None else meta.col[feat]
-                colv = lax.dynamic_index_in_dim(
-                    bins_cm, col_idx, axis=0, keepdims=False)
-                goes_left = route_goes_left(
-                    colv.astype(jnp.int32), meta, feat, thr, dleft,
-                    has_categorical=cfg.has_categorical,
-                    is_cat_l=is_cat_l, cat_row=cat_row, max_bin=cfg.max_bin)
-                return partition_window(order, start, cnt, size,
-                                        pack_row_bits(goes_left))
+                return partition_window(order, start, cnt, size, left_bits)
             return branch
 
-        pbranches = [partition_branch(s) for s in bsizes]
+        def dense_branch(args):
+            order, start, cnt, _, rl, l, new_leaf = args
+            obs_counters.inc("partition_route_dispatch", read="dense", size=n)
+            return partition_dense(order, start, cnt, rl, l, new_leaf)
+
+        pbranches = [window_branch(s) for s in psizes] + [dense_branch]
 
         # ---- root ----------------------------------------------------------
         root_g = strategy.reduce_scalar(jnp.sum(gw))
@@ -821,9 +909,8 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
         # fused_idx_fetch(row_tile) past the window, so the sentinel tail
         # must cover that too (sentinel reads are harmless — they only
         # ever resolve to the zero-weight panel row)
-        tail = _order_tail(bsizes)
-        if use_fused:
-            tail = max(tail, fused_idx_fetch(cfg.row_tile))
+        tail = (max(_order_tail(psizes), fused_idx_fetch(cfg.row_tile))
+                if use_fused else _order_tail(bsizes))
         order0 = jnp.concatenate(
             [jnp.arange(n, dtype=jnp.int32),
              jnp.full((tail,), n, jnp.int32)])
@@ -906,13 +993,24 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
             lrow = lax.dynamic_index_in_dim(state.lsc, l, axis=0,
                                             keepdims=False)
             start, cnt = lrow[0], lrow[1]
-            kp = _bucket_index(cnt, bsizes)
             cat_args = ((state.scat[l], state.scatb[l])
-                        if cfg.has_categorical else ())
+                        if cfg.has_categorical else (None, None))
             with jax.named_scope("partition"):
+                # the split column routed ONCE, before the switch, into
+                # one word a row: the window branches read the words as
+                # bits, the dense branch as ``rl``, whose update is one
+                # more pass over them.  The barrier keeps them ONE buffer:
+                # without it the v5e's compiler routes the column a second
+                # time for ``rl``, into a byte a row, at 0.58 ms a split
+                # where the words take 0.24 (PERF.md section 6, PR 35)
+                went_left = lax.optimization_barrier(
+                    route(feat, thr, dleft, *cat_args).astype(jnp.uint32))
+                rl = jnp.where((state.rl == l) & (went_left == 0), new_leaf,
+                               state.rl)
                 order, nl = lax.switch(
-                    kp, pbranches,
-                    (state.order, start, cnt, feat, thr, dleft) + cat_args)
+                    _bucket_index(cnt, psizes), pbranches,
+                    (state.order, start, cnt, pack_row_bits(went_left), rl,
+                     l, new_leaf))
             nr = cnt - nl
             lsc = state.lsc.at[pair_lr].set(
                 jnp.stack([jnp.stack([start, nl]),
@@ -971,7 +1069,7 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                     # fused panel — no bucket switch, no staging buffer
                     hist_small = hist_fused_window(order, sstart, scnt)
                 else:
-                    ki = _bucket_index(scnt, bsizes)
+                    ki = _bucket_index(scnt, bsizes[:-1])
                     hist_small = lax.switch(ki, branches,
                                             (order, sstart, scnt))
                 hist_small = globalize(hist_small)
@@ -1039,14 +1137,14 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                     mode="promise_in_bounds")
             else:
                 scat, scatb = state.scat, state.scatb
-            return _LoopState(i + 1, order, lsc, hist_store,
+            return _LoopState(i + 1, order, rl, lsc, hist_store,
                               feat_ok, sgain, sf32, si32, scat, scatb,
                               tnf, tni, tlf, tli, tcat, tcatb)
 
         state = _LoopState(jnp.asarray(0, jnp.int32), order0,
-                           lsc0, hist_store0, feat_ok_store0,
-                           sgain0, sf32_0, si32_0, scat0, scatb0,
-                           tnf0, tni0, tlf0, tli0, tcat0, tcatb0)
+                           jnp.zeros((n,), jnp.int32), lsc0, hist_store0,
+                           feat_ok_store0, sgain0, sf32_0, si32_0, scat0,
+                           scatb0, tnf0, tni0, tlf0, tli0, tcat0, tcatb0)
         state = lax.while_loop(cond, body, state)
         # unpack the packed carriers into the public TreeArrays ONCE per
         # tree (a handful of column slices outside the loop)

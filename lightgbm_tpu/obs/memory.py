@@ -375,10 +375,12 @@ def predict_hbm(rows: int, features: int, bins: int = 255, leaves: int = 31,
     if bin_bytes is None:
         bin_bytes = 1 if bins < 256 else 2
     # the largest window of the grower's own table (lazy: grower imports obs)
-    from ..grower import GrowerConfig, _bucket_sizes, _order_tail
-    sizes = _bucket_sizes(GrowerConfig(bucket_min_log2=bucket_min_log2),
-                          rows_d)
-    maxbuf = sizes[-1]
+    from ..grower import (GrowerConfig, _bucket_sizes, _order_tail,
+                          _partition_sizes)
+    gcfg = GrowerConfig(bucket_min_log2=bucket_min_log2)
+    maxbuf = _bucket_sizes(gcfg, rows_d)[-1]
+    # the partition's own, shorter table sets ``order``'s sentinel tail
+    order_tail = _order_tail(_partition_sizes(gcfg, rows_d))
     residents = {
         # the binned matrix [N, C] (+ the nibble-packed histogram copy):
         # row-sharded over ``batch``; over ``feature`` too when the
@@ -465,8 +467,11 @@ def predict_hbm(rows: int, features: int, bins: int = 255, leaves: int = 31,
         transients = {
             # sentinel-padded copy of the histogram inputs
             "staging": (rows_d + 1) * row_bytes,
-            # order [N + tail] i32 + the final row->leaf map [N] i32
-            "order_partition": (rows_d + _order_tail(sizes)) * 4 + rows_d * 4,
+            # order [N + tail] i32, the dense row->leaf vector the
+            # partition carries and the final row->leaf map, [N] i32 each
+            "order_partition": (rows_d + order_tail) * 4 + 2 * rows_d * 4,
+            # the dense partition branch: its sort's key and result
+            "partition_dense_sort": 2 * rows_d * 4,
             "hist_store": pool_bytes,
             # the gather buffer for the largest window
             "gather_buffer": maxbuf * row_bytes,

@@ -10,7 +10,9 @@ silently re-widening:
 
 * the loop BODY may touch O(N)-sized carriers only through the two
   ``lax.switch``es (partition + gather-bucket — the sanctioned O(window)
-  machinery);
+  machinery) and through the ONE routing of the split column under the
+  ``partition`` scope, which is elementwise over N (PR 35: it feeds the
+  window branches' bits and the dense vector ``rl`` alike);
 * the ``hist_store`` pool may be touched only by ONE read (dynamic_slice)
   and ONE fused pair-write (scatter) — the two-dynamic_update_slice chain
   that triggered the copies must not come back;
@@ -30,8 +32,8 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp
 
 from lightgbm_tpu.grower import (FeatureMeta, GrowerConfig, _bucket_sizes,
-                                 _order_tail, make_grower)
-from lightgbm_tpu.utils.jaxpr_audit import audit_loop_body
+                                 _order_tail, _partition_sizes, make_grower)
+from lightgbm_tpu.utils.jaxpr_audit import audit_loop_body, find_while_body
 
 N, F, B, L = 32768, 8, 64, 15
 
@@ -66,11 +68,26 @@ def test_loop_body_has_no_unsanctioned_big_ops(split_find):
     # the same threshold pins both formulations.
     assert 4 * L * F * 2 * B > N > 4 * 2 * F * 2 * B
     big = audit_loop_body(jaxpr, min_elems=N)
-    prims = {r["prim"] for r in big}
-    assert prims <= {"cond"}, (
-        f"grow-loop body touches O(N)-sized operands outside the "
-        f"sanctioned partition/bucket switches: {big}")
     assert len([r for r in big if r["prim"] == "cond"]) == 2
+    # outside the two switches the body is N-wide only in the routing of
+    # the split column (PR 35: once a split, before the switch): every
+    # such equation sits under the ``partition`` scope and is a slice or
+    # elementwise — nothing there reads by index, sorts, accumulates or
+    # rewrites a carrier
+    wide = [e for e in find_while_body(jaxpr).eqns
+            if e.primitive.name != "cond" and max(
+                (int(np.prod(v.aval.shape)) for v in e.invars + e.outvars
+                 if hasattr(v, "aval")), default=0) >= N]
+    outside = [e.primitive.name for e in wide
+               if "partition" not in str(e.source_info.name_stack)]
+    assert not outside, (
+        f"grow-loop body touches O(N)-sized operands outside the "
+        f"sanctioned partition/bucket switches and the routing: {outside}")
+    prims = {e.primitive.name for e in wide}
+    assert not prims & {"gather", "scatter", "scatter-add", "sort", "cumsum",
+                        "dynamic_update_slice", "concatenate", "while",
+                        "transpose", "reduce_sum", "copy", "copy_p"}, prims
+    assert prims & {"jit", "pjit", "select_n"} and "dynamic_slice" in prims
 
     # hist_store audit: exactly one read + one fused pair-write
     store = [r for r in audit_loop_body(jaxpr, min_elems=store_elems)
@@ -118,30 +135,96 @@ def test_loop_body_has_no_host_transfers(split_find):
         f"per-split host round-trip has been reintroduced")
 
 
-def test_partition_gathers_a_column_not_the_matrix():
-    """The routing read (PR 26): under the ``partition`` scope the split
-    column is sliced out of the column-major copy INSIDE the switch branch,
-    routed whole, packed to bits and read by a rank-1 gather — no gather
-    may take a rank-2 operand, least of all the ``[N, F]`` matrix (on the
-    v5e the two-index byte gather on ``u8[10500000,28]`` read 20 ns an
-    element: ledger, PR 25), and the table it does read is N/8 bytes."""
-    from lightgbm_tpu.utils.jaxpr_audit import find_while_body
-    grow, args = _grow_and_args()
-    body = find_while_body(jax.make_jaxpr(grow)(*args))
+def _partition_switch(body):
+    """The one ``lax.switch`` under the ``partition`` scope."""
     switches = [e for e in body.eqns if e.primitive.name == "cond"
                 and "partition" in str(e.source_info.name_stack)]
     assert len(switches) == 1
-    branches = switches[0].params["branches"]
-    for br in branches:
+    return switches[0]
+
+
+def test_partition_gathers_a_column_not_the_matrix():
+    """The routing read (PR 26, and ONCE a split since PR 35): under the
+    ``partition`` scope the split column is sliced out of the column-major
+    copy by one ``dynamic_slice`` at the body's top level, routed whole and
+    packed to bits there; a window branch reads the bits by ONE rank-1
+    gather — no gather takes a rank-2 operand, least of all the ``[N, F]``
+    matrix (on the v5e the two-index byte gather on ``u8[10500000,28]`` read
+    20 ns an element: ledger, PR 25), and the table it does read is N/8
+    bytes — and slices no column of its own."""
+    grow, args = _grow_and_args()
+    body = find_while_body(jax.make_jaxpr(grow)(*args))
+    column_reads = [e for e in body.eqns
+                    if e.primitive.name == "dynamic_slice"
+                    and e.invars[0].aval.shape == (F, N)]
+    assert len(column_reads) == 1
+    assert "partition" in str(column_reads[0].source_info.name_stack)
+    branches = _partition_switch(body).params["branches"]
+    assert len(branches) == len(_partition_sizes(GrowerConfig(), N)) + 1
+    for br in branches[:-1]:
         inner = list(_walk_eqns(br.jaxpr))
         gathers = [e.invars[0].aval for e in inner
                    if e.primitive.name == "gather"]
         assert [(a.shape, str(a.dtype)) for a in gathers] == \
             [((N // 32,), "uint32")], gathers
-        # and the column comes from the [F, N] copy by ONE slice, here
-        slices = [e for e in inner if e.primitive.name == "dynamic_slice"
-                  and e.invars[0].aval.shape == (F, N)]
-        assert len(slices) == 1
+        assert not [e for e in inner if e.primitive.name == "dynamic_slice"
+                    and e.invars[0].aval.shape == (F, N)]
+
+
+def test_dense_branch_is_one_sort_of_one_operand_and_no_gather():
+    """The partition's last branch (PR 35) reads nothing by row id: no
+    gather, no scatter, ONE sort, of one ``i32[N]`` operand (a second
+    operand, or the ``iota`` a stable sort carries, would be a third more
+    time a pass: PERF.md section 6, PR 30), and ``order`` comes back by a
+    select, not by an update in place of a slice."""
+    grow, args = _grow_and_args()
+    body = find_while_body(jax.make_jaxpr(grow)(*args))
+    inner = list(_walk_eqns(
+        _partition_switch(body).params["branches"][-1].jaxpr))
+    prims = [e.primitive.name for e in inner]
+    assert not {"gather", "scatter", "scatter-add",
+                "dynamic_update_slice"} & set(prims)
+    sorts = [e for e in inner if e.primitive.name == "sort"]
+    assert len(sorts) == 1
+    assert [(v.aval.shape, str(v.aval.dtype)) for v in sorts[0].invars] == \
+        [((N,), "int32")]
+    assert sorts[0].params["is_stable"] is False
+
+
+def test_routing_is_one_buffer_of_words_behind_a_barrier():
+    """The split column's N decisions leave the routing as ONE ``uint32[N]``
+    buffer that an ``optimization_barrier`` pins: ``rl``'s update and the
+    bit table both read it.  Without the barrier the v5e's compiler
+    routed the column twice, the second time into a byte a row at 0.58 ms
+    a split (PERF.md section 6, PR 35)."""
+    grow, args = _grow_and_args()
+    body = find_while_body(jax.make_jaxpr(grow)(*args))
+    barriers = [e for e in body.eqns
+                if e.primitive.name == "optimization_barrier"]
+    assert len(barriers) == 1
+    assert "partition" in str(barriers[0].source_info.name_stack)
+    assert [(v.aval.shape, str(v.aval.dtype))
+            for v in barriers[0].outvars] == [((N,), "uint32")]
+
+
+def test_rl_is_carried_and_updated_by_one_select():
+    """The dense row -> leaf vector is a loop carrier of N ``int32`` that
+    the body writes once, by the select of the routing pass, and hands to
+    the switch read-only: no branch returns it, so the switch cannot copy
+    it, and nothing else in the body produces an N-wide ``int32``."""
+    grow, args = _grow_and_args()
+    body = find_while_body(jax.make_jaxpr(grow)(*args))
+    switch = _partition_switch(body)
+    assert [v.aval.shape for v in switch.outvars
+            if v.aval.shape and v.aval.shape[0] >= N] == [
+        (N + _order_tail(_bucket_sizes(GrowerConfig(), N)),)]
+    made = [e for e in body.eqns for v in e.outvars
+            if v.aval.shape == (N,) and str(v.aval.dtype) == "int32"
+            and e.primitive.name not in ("convert_element_type",)]
+    assert len(made) == 1 and made[0].primitive.name in ("jit", "pjit",
+                                                         "select_n")
+    rl_out = made[0].outvars[0]
+    assert rl_out in body.outvars and rl_out in switch.invars
 
 
 # ---- loop-body size ratchet ------------------------------------------------
@@ -161,9 +244,16 @@ def test_partition_gathers_a_column_not_the_matrix():
 # before and after the partition's read went from the (row, col) gather
 # to a column slice and a rank-1 gather (both live inside the switch
 # branch, which the body's top level does not count); the budgets keep
-# the ~15%.
+# the ~15%.  Re-recorded on purpose in PR 35: 528 and 641 (the parent
+# read 396 and 509 on the same day), because the routing of the split
+# column moved OUT of the partition's branches to the body's top level,
+# where it runs once a split whichever branch is taken: its slice, its
+# decision and the 32 planes of ``pack_row_bits`` are 132 equations that
+# every one of the 12 branches used to hold a copy of, so the traced
+# program shrank while this count grew.  On XLA:CPU they fuse into a few
+# thunks; the budgets keep the ~15%.
 
-BODY_EQNS_BUDGET = {False: 450, True: 580}
+BODY_EQNS_BUDGET = {False: 600, True: 730}
 
 
 @pytest.mark.parametrize("has_missing", [False, True])
@@ -222,21 +312,36 @@ def test_compiled_body_has_no_full_pool_copies():
 # slots here), where it was N + 2^ceil(log2 N), 65,536 entries, whatever
 # the table: each copy is 40,959 entries long.
 
-ORDER_COPY_BUDGET = 14      # one a window size + 2 (see above)
+#
+# Re-recorded on purpose in PR 35: 9.  The partition's table now ends where
+# the dense branch is the cheaper transport (``_partition_sizes``: 7 sizes
+# at this N, 64 to 4096, where the whole table has 12), so the text holds
+# seven window branches' copies, the body's and the initial carry's; the
+# dense branch, the eighth, builds ``order`` by a select over all of it and
+# draws no copy (the parent read 14 on the same day).  Still ONE executes a
+# split.  The carrier on this rung (``segment``: the XLA reference's
+# histogram ladder slices windows of the WHOLE table) stays N + 8,191; on
+# the fused rung it is N + the widest step of the partition's own table
+# (``_order_tail(_partition_sizes(...))``: 262,143 slots at 10.5M rows
+# where it was 4,194,303).
+
+ORDER_COPY_BUDGET = 9      # one a window size of the partition + 2
 
 
 def test_compiled_order_copy_count_ratchet():
     grow, args = _grow_and_args()
     txt = jax.jit(grow).lower(*args).compile().as_text()
-    sizes = _bucket_sizes(GrowerConfig(), N)
-    assert len(sizes) + 2 == ORDER_COPY_BUDGET
-    carrier = N + _order_tail(sizes)          # order [N + tail] i32
+    assert len(_partition_sizes(GrowerConfig(), N)) + 2 == ORDER_COPY_BUDGET
+    carrier = N + _order_tail(_bucket_sizes(GrowerConfig(), N))
     copies = re.findall(rf"= s32\[{carrier}\][^ ]* copy\(", txt)
     assert 1 <= len(copies) <= ORDER_COPY_BUDGET, (
         f"{len(copies)} order-carrier copies in the compiled executable "
         f"(budget {ORDER_COPY_BUDGET}, recorded on jax 0.9.0) "
         f"— copy-insertion around the conditional in-place update has "
         f"multiplied; re-measure deliberately before widening")
+    # ``rl``, the other N-wide carrier, is copied nowhere: the body
+    # updates it in place and no branch of the switch returns it
+    assert not re.findall(rf"= s32\[{N}\][^ ]* copy\(", txt)
 
 
 def test_gspmd_grower_has_no_order_carrier_copies():
@@ -289,7 +394,11 @@ def test_gspmd_grower_has_no_order_carrier_copies():
 # cumulative sum and one sort where a gather and a scatter of N were) is
 # outside the loop and moves no pin on the body: 3,709,560 on the parent,
 # 3,578,552 with it (one N-entry i32 temporary fewer); body 392 / 505
-# equations and 14 order copies as before.  Nothing re-recorded.
+# equations and 14 order copies as before.  Nothing re-recorded.  Read
+# again in PR 35 (the dense row -> leaf vector ``rl`` joins the carry,
+# N * 4 = 131,072 bytes, and five window branches with their temporaries
+# leave the text): 3,578,552 on the parent, 3,578,240 with it.  The budget
+# stands.
 
 TEMP_BYTES_BUDGET = 4_100_000
 TEMP_BYTES_FLOOR = 1_000_000    # sanity: hist_store alone is 368,640 —

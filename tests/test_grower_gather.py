@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from lightgbm_tpu.grower import (HALF_STEP_ABOVE_LOG2, FeatureMeta,
-                                 GrowerConfig, _bucket_sizes, make_grower,
+                                 GrowerConfig, _partition_sizes, make_grower,
                                  pack_gather_words, unpack_gather_words)
 
 
@@ -33,7 +33,9 @@ def test_pack_rejects_wide_dtypes():
 #
 # The partition slices the split column out of a column-major copy, routes
 # ALL its rows, packs the decisions to bits and gathers one word per window
-# row.  The router below knows nothing of that: it replays
+# row, or, for a leaf larger than its window table holds, sorts all the rows
+# keyed on the dense row -> leaf vector (PR 35).  The router below knows
+# nothing of that: it replays
 # the grown tree's splits on ``bins[rows, col]`` with its own copy of the
 # decision rule (tree.h:257-313) and must arrive at every split's left
 # count and at the grower's row -> leaf map.
@@ -41,8 +43,10 @@ def test_pack_rejects_wide_dtypes():
 def _route_case(kind):
     import lightgbm_tpu as lgb
     rng = np.random.RandomState(26)
-    # half_step: rows enough for windows of 3 * 2^12 and 3 * 2^13 slots
-    n = 30000 if kind == "half_step" else 4000
+    # half_step: rows enough for the partition's table to keep windows of
+    # 3 * 2^12 and 3 * 2^13 slots (it ends where the dense branch is the
+    # cheaper one: ``grower._partition_sizes``)
+    n = 200000 if kind == "half_step" else 4000
     params = {"max_bin": 31, "verbose": -1}
     cat = "auto"
     if kind in ("numeric_missing", "half_step"):
@@ -155,21 +159,26 @@ def test_grow_routes_like_plain_numpy_router(kind):
         jnp.asarray(bins), g, one * 0.25, one, meta,
         jnp.ones((len(fm["num_bin"]),), bool))
     # one count per traced partition branch: the grower says which read
-    # and which window sizes it was built with
-    sizes = _bucket_sizes(cfg, n)
+    # and which window sizes it was built with, the dense branch after them
+    sizes = _partition_sizes(cfg, n)
     assert counters.get("partition_route_dispatch") == {
-        f"read=column,size={s}": 1 for s in sizes}
+        **{f"read=column,size={s}": 1 for s in sizes},
+        f"read=dense,size={n}": 1}
     tree, num_leaves = _assert_routed_like_numpy(bins, fm, tree, row_leaf,
                                                  tag=kind)
     assert num_leaves > 8
     nodes = slice(0, num_leaves - 1)
     halves = [s for s in sizes if s & (s - 1)]
+    split_rows = tree.internal_count[nodes].astype(np.int64)
+    # both transports ran: the root and its like by the dense branch,
+    # smaller leaves in windows
+    assert (split_rows > sizes[-1]).any() and (split_rows <= sizes[-1]).any()
     if kind == "half_step":
         # splits ran in half-step windows: a split leaf's row count picked
         # a size that is no power of two
         assert halves == [12288, 24576]
-        split_rows = tree.internal_count[nodes].astype(np.int64)
-        picked = {min(s for s in sizes if s >= c) for c in split_rows}
+        picked = {min(s for s in sizes if s >= c)
+                  for c in split_rows if c <= sizes[-1]}
         assert picked & set(halves), sorted(picked)
     else:
         assert not halves and sizes[-1] <= 1 << HALF_STEP_ABOVE_LOG2
@@ -229,8 +238,9 @@ def _record_grow_calls(bst, rounds):
 
 def test_training_with_missing_values_routes_like_plain_numpy_router():
     """NaN-missing columns and a zero-heavy one through ``lgb.Booster``,
-    at a row count whose root window is a half-step size (3 * 2^12): five
-    trees, each replayed by the numpy router."""
+    at a row count whose largest leaves go through the dense branch (the
+    partition's window table ends at 1,024 slots of 12,000 rows) and the
+    rest through windows: five trees, each replayed by the numpy router."""
     import lightgbm_tpu as lgb
     rng = np.random.RandomState(12)
     n = 12000

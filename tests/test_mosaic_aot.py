@@ -128,8 +128,10 @@ FULL_GROWER_PROOFS = pytest.mark.skipif(
 ])
 def test_full_grower_lowers(v5e, n):
     """The FULL grower (partition switch over the window table with its
-    half-step sizes, the sort transport, while_loop, Pallas kernel) must
-    Mosaic-compile for v5e at the bench config, wherever the table ends."""
+    half-step sizes, the sort transport, the dense branch after the
+    table's last window, while_loop, Pallas kernel) must Mosaic-compile
+    for v5e at the bench config, wherever the whole table ends (the
+    partition's own ends at 16,384, 12,288 and 16,384 slots)."""
     import jax.numpy as jnp
     from lightgbm_tpu.grower import FeatureMeta, GrowerConfig, make_grower
     f = 28
