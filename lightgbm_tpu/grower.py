@@ -381,6 +381,28 @@ def _set(arr, idx, value):
     return arr.at[idx].set(value)
 
 
+# The ``jax.named_scope`` names of the grow programs (this file's and
+# ``parallel/gspmd.py``'s) are what a device trace reads them by:
+# ``partition`` (``part_route``, ``part_read``, ``part_sort``, ``part_dense``
+# and ``bundle_decode`` inside it), ``histogram`` (``hist_root``),
+# ``hist_pool``, ``split_find``, ``bundle_expand``, ``row_leaf``,
+# ``fused_panel``, ``node_tables``.  Bump when a scope is added, renamed or
+# moved: the cache key ignores names.  jax strips an operation's metadata
+# before it hashes a program for the persistent compile cache, so two
+# programs that differ in their scopes alone share one cache entry and the
+# later one runs with the earlier one's names; the program's own name IS in
+# the key, so the jitted grow functions carry the revision in theirs.
+SCOPE_REVISION = 2
+
+
+def scoped_program_name(fn):
+    """``fn`` renamed ``<its name>_s<SCOPE_REVISION>``: what ``jax.jit``
+    names the program after (``jit_grow_tree_s2``).  ``grow_tree`` stays in
+    the name: the benchmark finds the program by that substring."""
+    fn.__name__ = fn.__qualname__ = f"{fn.__name__}_s{SCOPE_REVISION}"
+    return fn
+
+
 def route_goes_left(binf, meta: FeatureMeta, feat, thr, dleft,
                     has_categorical: bool = False, is_cat_l=None,
                     cat_row=None, max_bin: int = 0):
@@ -477,17 +499,21 @@ def partition_window(order, start, cnt, size: int, left_bits):
     if 3 * size > 2 ** 31:
         raise ValueError(f"window of {size} slots: 3 * size overflows the "
                          f"int32 sort key")
-    win = lax.dynamic_slice(order, (start,), (size,))
-    slot = jnp.arange(size, dtype=jnp.int32)
-    valid = slot < cnt
-    # slots past the leaf may hold the sentinel N: read row 0's bit there
-    goes_left = take_row_bits(left_bits, jnp.where(valid, win, 0)) & valid
-    nl = jnp.sum(goes_left.astype(jnp.int32))
-    # slots past the leaf (the last group) are already contiguous at the
-    # window's tail, so the sort returns them where they were
-    key = slot + jnp.where(goes_left, 0, jnp.where(valid, size, 2 * size))
-    _, new_win = lax.sort((key, win), is_stable=False, num_keys=1)
-    return lax.dynamic_update_slice(order, new_win, (start,)), nl
+    with jax.named_scope("part_read"):    # the read by row id, per slot
+        win = lax.dynamic_slice(order, (start,), (size,))
+        slot = jnp.arange(size, dtype=jnp.int32)
+        valid = slot < cnt
+        # slots past the leaf may hold the sentinel N: read row 0's bit there
+        goes_left = take_row_bits(left_bits, jnp.where(valid, win, 0)) & valid
+        nl = jnp.sum(goes_left.astype(jnp.int32))
+        # slots past the leaf (the last group) are already contiguous at the
+        # window's tail, so the sort returns them where they were
+        key = slot + jnp.where(goes_left, 0,
+                               jnp.where(valid, size, 2 * size))
+    with jax.named_scope("part_sort"):    # the window's transport
+        _, new_win = lax.sort((key, win), is_stable=False, num_keys=1)
+        order = lax.dynamic_update_slice(order, new_win, (start,))
+    return order, nl
 
 
 def partition_dense(order, start, cnt, rl, left_leaf, right_leaf):
@@ -522,17 +548,21 @@ def partition_dense(order, start, cnt, rl, left_leaf, right_leaf):
     if 3 * n > 2 ** 31:
         raise ValueError(f"{n} rows: 3 * n overflows the int32 sort key")
     total = order.shape[0]
-    group = jnp.where(rl == left_leaf, 0, jnp.where(rl == right_leaf, 1, 2))
-    nl = jnp.sum((group == 0).astype(jnp.int32))
-    key = lax.sort(group * n + jnp.arange(n, dtype=jnp.int32),
-                   is_stable=False)
-    rows = key - jnp.where(key < n, 0, jnp.where(key < 2 * n, n, 2 * n))
-    # rows[j] belongs at order[start + j]: a slice of the sorted rows,
-    # padded on both sides, that starts ``start`` slots before them
-    placed = lax.dynamic_slice(jnp.pad(rows, (n, total - n)), (n - start,),
-                               (total,))
-    pos = jnp.arange(total, dtype=jnp.int32)
-    return jnp.where((pos >= start) & (pos < start + cnt), placed, order), nl
+    with jax.named_scope("part_dense"):
+        group = jnp.where(rl == left_leaf, 0,
+                          jnp.where(rl == right_leaf, 1, 2))
+        nl = jnp.sum((group == 0).astype(jnp.int32))
+        key = lax.sort(group * n + jnp.arange(n, dtype=jnp.int32),
+                       is_stable=False)
+        rows = key - jnp.where(key < n, 0, jnp.where(key < 2 * n, n, 2 * n))
+        # rows[j] belongs at order[start + j]: a slice of the sorted rows,
+        # padded on both sides, that starts ``start`` slots before them
+        placed = lax.dynamic_slice(jnp.pad(rows, (n, total - n)),
+                                   (n - start,), (total,))
+        pos = jnp.arange(total, dtype=jnp.int32)
+        order = jnp.where((pos >= start) & (pos < start + cnt), placed,
+                          order)
+    return order, nl
 
 
 def pool_flat(hist):
@@ -734,17 +764,22 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
         bsizes = _bucket_sizes(cfg, n)
         psizes = _partition_sizes(cfg, n)
 
-        # sentinel row n: weight 0, bin 0 — receives all buffer padding
-        hbins_pad = jnp.concatenate(
-            [hbins, jnp.zeros((1, hbins.shape[1]), hbins.dtype)], axis=0)
-        gw_pad = jnp.concatenate([gw, jnp.zeros((1,), dtype)])
-        hw_pad = jnp.concatenate([hw, jnp.zeros((1,), dtype)])
-        cw_pad = jnp.concatenate([cw, jnp.zeros((1,), dtype)])
+        # ``fused_panel`` (the name the GSPMD grower gives its panel's
+        # packing): what every tree builds anew out of arrays that change
+        # with no tree (the bins) or only in their weights: the padded
+        # copies, the column-major copy, the packed panel of either rung
+        with jax.named_scope("fused_panel"):
+            # sentinel row n: weight 0, bin 0 — receives all buffer padding
+            hbins_pad = jnp.concatenate(
+                [hbins, jnp.zeros((1, hbins.shape[1]), hbins.dtype)], axis=0)
+            gw_pad = jnp.concatenate([gw, jnp.zeros((1,), dtype)])
+            hw_pad = jnp.concatenate([hw, jnp.zeros((1,), dtype)])
+            cw_pad = jnp.concatenate([cw, jnp.zeros((1,), dtype)])
 
-        # column-major copy of the routing matrix, made once per tree
-        # outside the split loop: each partition branch slices its split
-        # column out of it
-        bins_cm = bins.T
+            # column-major copy of the routing matrix, made once per tree
+            # outside the split loop: each partition branch slices its split
+            # column out of it
+            bins_cm = bins.T
         n_hist_cols = hbins.shape[1]
         use_fused = cfg.hist_method == "fused"
         if use_fused:
@@ -758,9 +793,10 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                 raise ValueError(
                     f"hist_method=fused cannot run on this layout: {reason}")
             # rows padded to whole row tiles: the root fetches blocks
-            fused_panel, fused_per = pack_fused_panel(
-                hbins_pad, gw_pad, hw_pad, cw_pad,
-                row_multiple=cfg.row_tile)
+            with jax.named_scope("fused_panel"):
+                fused_panel, fused_per = pack_fused_panel(
+                    hbins_pad, gw_pad, hw_pad, cw_pad,
+                    row_multiple=cfg.row_tile)
         # the XLA reference rungs read a split's rows by ONE row gather
         # where the layout allows it: a gather costs per index, not per
         # byte (12.6 ns a row for 28 bytes, the same for one f32 column),
@@ -770,16 +806,17 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
         use_panel = (not use_fused and hbins.dtype.itemsize <= 2
                      and dtype == jnp.float32)
         if use_panel:
-            hwords_pad, words_per = pack_gather_words(hbins_pad)
-            n_words = hwords_pad.shape[1]
-            panel = jnp.concatenate(
-                [hwords_pad]
-                + [lax.bitcast_convert_type(w, jnp.uint32)[:, None]
-                   for w in (gw_pad, hw_pad, cw_pad)], axis=1)
+            with jax.named_scope("fused_panel"):
+                hwords_pad, words_per = pack_gather_words(hbins_pad)
+                n_words = hwords_pad.shape[1]
+                panel = jnp.concatenate(
+                    [hwords_pad]
+                    + [lax.bitcast_convert_type(w, jnp.uint32)[:, None]
+                       for w in (gw_pad, hw_pad, cw_pad)], axis=1)
 
         # the jax.named_scope names below are baked into the HLO: a device
         # trace attributes the per-split kernels to them (a host span here
-        # would fire once, while jit traces)
+        # would fire once, while jit traces).  SCOPE_REVISION lists them
 
         def find(hist, pg, ph, pc, feat_ok):
             # trace-time identity evidence (the hist_dispatch discipline):
@@ -916,7 +953,9 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
              jnp.full((tail,), n, jnp.int32)])
         num_logical = meta.num_bin.shape[0]
         feat_ok_all = jnp.ones((num_logical,), bool)
-        with jax.named_scope("histogram"):
+        # ``hist_root`` inside ``histogram``: the one histogram over all n
+        # rows, apart from the L - 1 per-split ones
+        with jax.named_scope("histogram"), jax.named_scope("hist_root"):
             if use_fused:
                 # the fused rung is SELF-CONTAINED: the root histogram goes
                 # through the fused kernel too — it is the one
@@ -975,26 +1014,31 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
 
         def body(state: _LoopState) -> _LoopState:
             i = state.step
-            l = jnp.argmax(state.sgain).astype(jnp.int32)
-            new_leaf = i + 1
-            node = i
-            pair_lr = jnp.stack([l, new_leaf])
+            # ``node_tables``, here and twice below: the reads and writes
+            # of the [L]-row tables a split costs beside its partition,
+            # kernel, pool and split find
+            with jax.named_scope("node_tables"):
+                l = jnp.argmax(state.sgain).astype(jnp.int32)
+                new_leaf = i + 1
+                node = i
+                pair_lr = jnp.stack([l, new_leaf])
 
-            # one row read per pool instead of one gather per field
-            irow = lax.dynamic_index_in_dim(state.si32, l, axis=0,
-                                            keepdims=False)
-            frow = lax.dynamic_index_in_dim(state.sf32, l, axis=0,
-                                            keepdims=False)
-            feat, thr = irow[0], irow[1]
-            dleft = irow[2].astype(bool)
+                # one row read per pool instead of one gather per field
+                irow = lax.dynamic_index_in_dim(state.si32, l, axis=0,
+                                                keepdims=False)
+                frow = lax.dynamic_index_in_dim(state.sf32, l, axis=0,
+                                                keepdims=False)
+                feat, thr = irow[0], irow[1]
+                dleft = irow[2].astype(bool)
 
-            # --- localized routing + stable partition of leaf l's window
-            #     (only that leaf's slice of ``order`` is touched) ---------
-            lrow = lax.dynamic_index_in_dim(state.lsc, l, axis=0,
-                                            keepdims=False)
-            start, cnt = lrow[0], lrow[1]
-            cat_args = ((state.scat[l], state.scatb[l])
-                        if cfg.has_categorical else (None, None))
+                # --- localized routing + stable partition of leaf l's
+                #     window (only that leaf's slice of ``order`` is
+                #     touched) ----------------------------------------------
+                lrow = lax.dynamic_index_in_dim(state.lsc, l, axis=0,
+                                                keepdims=False)
+                start, cnt = lrow[0], lrow[1]
+                cat_args = ((state.scat[l], state.scatb[l])
+                            if cfg.has_categorical else (None, None))
             with jax.named_scope("partition"):
                 # the split column routed ONCE, before the switch, into
                 # one word a row: the window branches read the words as
@@ -1002,60 +1046,69 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                 # more pass over them.  The barrier keeps them ONE buffer:
                 # without it the v5e's compiler routes the column a second
                 # time for ``rl``, into a byte a row, at 0.58 ms a split
-                # where the words take 0.24 (PERF.md section 6, PR 35)
-                went_left = lax.optimization_barrier(
-                    route(feat, thr, dleft, *cat_args).astype(jnp.uint32))
-                rl = jnp.where((state.rl == l) & (went_left == 0), new_leaf,
-                               state.rl)
+                # where the words take 0.24 (PERF.md section 6, PR 35).
+                # ``part_route``: the pass over all N rows that the
+                # smallest leaf's split pays too (entered twice, around
+                # the branch index, so that the operations keep the order
+                # they were traced in before they had this name)
+                with jax.named_scope("part_route"):
+                    went_left = lax.optimization_barrier(
+                        route(feat, thr, dleft,
+                              *cat_args).astype(jnp.uint32))
+                    rl = jnp.where((state.rl == l) & (went_left == 0),
+                                   new_leaf, state.rl)
+                which = _bucket_index(cnt, psizes)
+                with jax.named_scope("part_route"):
+                    left_bits = pack_row_bits(went_left)
                 order, nl = lax.switch(
-                    _bucket_index(cnt, psizes), pbranches,
-                    (state.order, start, cnt, pack_row_bits(went_left), rl,
-                     l, new_leaf))
-            nr = cnt - nl
-            lsc = state.lsc.at[pair_lr].set(
-                jnp.stack([jnp.stack([start, nl]),
-                           jnp.stack([start + nl, nr])]),
-                unique_indices=True, mode="promise_in_bounds")
+                    which, pbranches,
+                    (state.order, start, cnt, left_bits, rl, l, new_leaf))
+            with jax.named_scope("node_tables"):
+                nr = cnt - nl
+                lsc = state.lsc.at[pair_lr].set(
+                    jnp.stack([jnp.stack([start, nl]),
+                               jnp.stack([start + nl, nr])]),
+                    unique_indices=True, mode="promise_in_bounds")
 
-            # --- record the node (Tree::Split, tree.h:319-345): one row
-            #     write per packed table + one element write that relinks
-            #     the parent's child pointer (the root split has no parent;
-            #     its relink is redirected into row ``node``, which the
-            #     full row write below overwrites) --------------------------
-            prow = lax.dynamic_index_in_dim(state.tli, l, axis=0,
-                                            keepdims=False)
-            parent_node = prow[0]
-            child_depth = prow[1] + 1
-            pn_safe = jnp.where(parent_node >= 0, parent_node, node)
-            side = jnp.where(state.tni[pn_safe, 3] == ~l, 3, 4)
-            tni = state.tni.at[pn_safe, side].set(
-                node, mode="promise_in_bounds")
-            tni = tni.at[node].set(
-                jnp.stack([feat, thr, irow[2], ~l, ~new_leaf]),
-                mode="promise_in_bounds")
+                # --- record the node (Tree::Split, tree.h:319-345): one row
+                #     write per packed table + one element write that relinks
+                #     the parent's child pointer (the root split has no parent;
+                #     its relink is redirected into row ``node``, which the
+                #     full row write below overwrites) ----------------------
+                prow = lax.dynamic_index_in_dim(state.tli, l, axis=0,
+                                                keepdims=False)
+                parent_node = prow[0]
+                child_depth = prow[1] + 1
+                pn_safe = jnp.where(parent_node >= 0, parent_node, node)
+                side = jnp.where(state.tni[pn_safe, 3] == ~l, 3, 4)
+                tni = state.tni.at[pn_safe, side].set(
+                    node, mode="promise_in_bounds")
+                tni = tni.at[node].set(
+                    jnp.stack([feat, thr, irow[2], ~l, ~new_leaf]),
+                    mode="promise_in_bounds")
 
-            parent_g = frow[0] + frow[3]
-            parent_h = frow[1] + frow[4]
-            tnf = state.tnf.at[node].set(
-                jnp.stack([state.sgain[l],
-                           leaf_output(parent_g, parent_h,
-                                       cfg.lambda_l1, cfg.lambda_l2),
-                           state.tlf[l, 1]]),
-                mode="promise_in_bounds")
-            tlf = state.tlf.at[pair_lr].set(
-                jnp.stack([jnp.stack([frow[6], frow[2]]),
-                           jnp.stack([frow[7], frow[5]])]),
-                unique_indices=True, mode="promise_in_bounds")
-            tli = state.tli.at[pair_lr].set(
-                jnp.broadcast_to(jnp.stack([node, child_depth]), (2, 2)),
-                unique_indices=True, mode="promise_in_bounds")
-            if cfg.has_categorical:
-                tcat = state.tcat.at[node].set(cat_args[0],
-                                               mode="promise_in_bounds")
-                tcatb = state.tcatb.at[node].set(cat_args[1],
-                                                 mode="promise_in_bounds")
-            else:
-                tcat, tcatb = state.tcat, state.tcatb
+                parent_g = frow[0] + frow[3]
+                parent_h = frow[1] + frow[4]
+                tnf = state.tnf.at[node].set(
+                    jnp.stack([state.sgain[l],
+                               leaf_output(parent_g, parent_h,
+                                           cfg.lambda_l1, cfg.lambda_l2),
+                               state.tlf[l, 1]]),
+                    mode="promise_in_bounds")
+                tlf = state.tlf.at[pair_lr].set(
+                    jnp.stack([jnp.stack([frow[6], frow[2]]),
+                               jnp.stack([frow[7], frow[5]])]),
+                    unique_indices=True, mode="promise_in_bounds")
+                tli = state.tli.at[pair_lr].set(
+                    jnp.broadcast_to(jnp.stack([node, child_depth]), (2, 2)),
+                    unique_indices=True, mode="promise_in_bounds")
+                if cfg.has_categorical:
+                    tcat = state.tcat.at[node].set(cat_args[0],
+                                                   mode="promise_in_bounds")
+                    tcatb = state.tcatb.at[node].set(cat_args[1],
+                                                     mode="promise_in_bounds")
+                else:
+                    tcat, tcatb = state.tcat, state.tcatb
 
             # --- smaller-child histogram + parent subtraction ----------------
             # (the reference's smaller/larger trick,
@@ -1102,11 +1155,12 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
             # children go through ONE vmapped find: the candidate scan is
             # dozens of small ops on [E, B] arrays whose cost on TPU is
             # per-op launch, not math — batching the pair halves it
-            fok_parent = lax.dynamic_index_in_dim(state.feat_ok, l, axis=0,
-                                                  keepdims=False)
-            lr3 = jnp.stack([lax.slice(frow, (0,), (3,)),
-                             lax.slice(frow, (3,), (6,))])   # [2, 3]
-            sl3 = jnp.where(small_left, lr3, lr3[::-1])
+            with jax.named_scope("node_tables"):
+                fok_parent = lax.dynamic_index_in_dim(
+                    state.feat_ok, l, axis=0, keepdims=False)
+                lr3 = jnp.stack([lax.slice(frow, (0,), (3,)),
+                                 lax.slice(frow, (3,), (6,))])   # [2, 3]
+                sl3 = jnp.where(small_left, lr3, lr3[::-1])
             # the scopes are entered OUTSIDE the vmap too: inside it alone
             # the children's scan is named ``vmap(split_find)``, which a
             # trace's scope pattern does not read as ``split_find``
@@ -1119,24 +1173,25 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                 res2, fok2 = jax.vmap(find, in_axes=(0, 0, 0, 0, None))(
                     scan2, sl3[:, 0], sl3[:, 1], sl3[:, 2], fok_parent)
             res2 = _depth_gate(res2, child_depth, cfg.max_depth)
-            feat_ok = state.feat_ok.at[pair_sl].set(fok2 & fok_parent[None, :],
-                                                    unique_indices=True)
-            rows_f32, rows_i32 = pool_rows(res2, 1)
-            sgain = state.sgain.at[pair_sl].set(
-                res2.gain, unique_indices=True, mode="promise_in_bounds")
-            sf32 = state.sf32.at[pair_sl].set(
-                rows_f32, unique_indices=True, mode="promise_in_bounds")
-            si32 = state.si32.at[pair_sl].set(
-                rows_i32, unique_indices=True, mode="promise_in_bounds")
-            if cfg.has_categorical:
-                scat = state.scat.at[pair_sl].set(
-                    res2.is_cat, unique_indices=True,
-                    mode="promise_in_bounds")
-                scatb = state.scatb.at[pair_sl].set(
-                    res2.cat_bins, unique_indices=True,
-                    mode="promise_in_bounds")
-            else:
-                scat, scatb = state.scat, state.scatb
+            with jax.named_scope("node_tables"):
+                feat_ok = state.feat_ok.at[pair_sl].set(
+                    fok2 & fok_parent[None, :], unique_indices=True)
+                rows_f32, rows_i32 = pool_rows(res2, 1)
+                sgain = state.sgain.at[pair_sl].set(
+                    res2.gain, unique_indices=True, mode="promise_in_bounds")
+                sf32 = state.sf32.at[pair_sl].set(
+                    rows_f32, unique_indices=True, mode="promise_in_bounds")
+                si32 = state.si32.at[pair_sl].set(
+                    rows_i32, unique_indices=True, mode="promise_in_bounds")
+                if cfg.has_categorical:
+                    scat = state.scat.at[pair_sl].set(
+                        res2.is_cat, unique_indices=True,
+                        mode="promise_in_bounds")
+                    scatb = state.scatb.at[pair_sl].set(
+                        res2.cat_bins, unique_indices=True,
+                        mode="promise_in_bounds")
+                else:
+                    scat, scatb = state.scat, state.scatb
             return _LoopState(i + 1, order, rl, lsc, hist_store,
                               feat_ok, sgain, sf32, si32, scat, scatb,
                               tnf, tni, tlf, tli, tcat, tcatb)
@@ -1159,18 +1214,18 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
         def grow_tree_limited(max_steps, bins, gw, hw, cw, meta, feat_valid):
             return grow_impl(bins, bins, gw, hw, cw, meta, feat_valid,
                              max_steps=max_steps)
-        return grow_tree_limited
+        return scoped_program_name(grow_tree_limited)
 
     if pack_plan is None:
         # keep the historical 6-arg signature: histogram from the same
         # matrix routing reads
         def grow_tree(bins, gw, hw, cw, meta, feat_valid):
             return grow_impl(bins, bins, gw, hw, cw, meta, feat_valid)
-        return grow_tree
+        return scoped_program_name(grow_tree)
 
     def grow_tree_packed(bins, hist_bins, gw, hw, cw, meta, feat_valid):
         return grow_impl(bins, hist_bins, gw, hw, cw, meta, feat_valid)
-    return grow_tree_packed
+    return scoped_program_name(grow_tree_packed)
 
 
 class StreamedGrower:

@@ -11,6 +11,9 @@ root/split, ``split_find``, ``partition``, ``fused_panel``, the serving
 ``traverse``, ``objective``, ``score_update``) — falling back to the host
 ``TraceAnnotation`` phase windows (``lgb:boosting`` / ``lgb:tree`` /
 ``lgb:score`` / ...) that ``obs/trace.phase`` puts into every capture.
+It is NOT taught the grower's nested scopes (``part_read``, ``hist_root``,
+... : ``grower.SCOPE_REVISION``): it doubles nested operations on the chip
+(ROADMAP D6) and goes or is mended in a ``simplicity`` PR of its own.
 
 Capture discipline follows the PhaseTimers convention: the FIRST firing
 seen is the compile and is never captured; the next ``profile_iters``

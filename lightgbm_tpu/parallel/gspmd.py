@@ -75,7 +75,7 @@ from ..data.packing import (PACK_JOINT_BINS, pack_fused_panel,
                             unfold_packed_hist)
 from ..grower import (FeatureMeta, GrowerConfig, _depth_gate,
                       expand_bundle_hist, make_expand_maps, pool_rows,
-                      route_goes_left, unpack_tree)
+                      route_goes_left, scoped_program_name, unpack_tree)
 from ..obs.counters import counters as obs_counters
 from ..ops.histogram import subset_histogram_flat, subset_histogram_fused_local
 from ..ops.split import best_split, leaf_output, make_fused_ctx
@@ -403,8 +403,8 @@ def make_gspmd_grower(cfg: GrowerConfig, mesh: Mesh,
     if pack_plan is None:
         def grow_tree(bins, gw, hw, cw, meta, feat_valid):
             return grow_impl(bins, bins, gw, hw, cw, meta, feat_valid)
-        return jax.jit(grow_tree)
+        return jax.jit(scoped_program_name(grow_tree))
 
     def grow_tree_packed(bins, hist_bins, gw, hw, cw, meta, feat_valid):
         return grow_impl(bins, hist_bins, gw, hw, cw, meta, feat_valid)
-    return jax.jit(grow_tree_packed)
+    return jax.jit(scoped_program_name(grow_tree_packed))

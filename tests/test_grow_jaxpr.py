@@ -438,5 +438,5 @@ def test_row_leaf_map_is_scoped_and_counted_once_a_trace():
     before = counters.get("row_leaf_dispatch").get("impl=sort", 0)
     txt = jax.jit(grow).lower(*args).as_text(debug_info=True)
     assert counters.get("row_leaf_dispatch") == {"impl=sort": before + 1}
-    assert re.search(r'jit\(grow_tree\)/row_leaf/sort', txt)
-    assert re.search(r'jit\(grow_tree\)/row_leaf/jit\(cumsum\)', txt)
+    assert re.search(r'jit\(grow_tree_s\d+\)/row_leaf/sort', txt)
+    assert re.search(r'jit\(grow_tree_s\d+\)/row_leaf/jit\(cumsum\)', txt)
