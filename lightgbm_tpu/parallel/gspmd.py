@@ -122,8 +122,6 @@ def make_gspmd_grower(cfg: GrowerConfig, mesh: Mesh,
                   feat_valid):
         n, f = bins.shape
         dtype = gw.dtype
-        maps = (make_expand_maps(meta, cfg.max_bin)
-                if meta.col is not None else None)
         scfg = cfg.split_config()
         fctx = (make_fused_ctx(meta.num_bin, meta.missing_type,
                                meta.default_bin, cfg.max_bin, scfg)
@@ -131,12 +129,19 @@ def make_gspmd_grower(cfg: GrowerConfig, mesh: Mesh,
         num_logical = meta.num_bin.shape[0]
         fh = (pack_plan.num_phys_cols if pack_plan is not None
               else hist_src.shape[1])
+        maps = (make_expand_maps(meta, cfg.max_bin, fh)
+                if meta.col is not None else None)
+
+        def expand(hist, pg, ph, pc):
+            # the children as ONE batch, outside the find's vmap
+            # (``expand_bundle_hist``)
+            if maps is None:
+                return hist
+            with jax.named_scope("bundle_expand"):
+                return expand_bundle_hist(hist, pg, ph, pc, maps)
 
         def find(hist, pg, ph, pc, feat_ok):
             obs_counters.inc("split_find_dispatch", impl=cfg.split_find)
-            if maps is not None:
-                with jax.named_scope("bundle_expand"):
-                    hist = expand_bundle_hist(hist, pg, ph, pc, maps)
             with jax.named_scope("split_find"):
                 return best_split(hist, pg, ph, pc, meta.num_bin,
                                   meta.missing_type, meta.default_bin,
@@ -244,8 +249,9 @@ def make_gspmd_grower(cfg: GrowerConfig, mesh: Mesh,
         with jax.named_scope("histogram"):
             hist_root = measure(row_leaf0, jnp.asarray(0, jnp.int32),
                                 gw, hw, cw, site="root")
-        res_root, root_feat_ok = find(hist_root, root_g, root_h, root_c,
-                                      feat_ok_all)
+        res_root, root_feat_ok = find(
+            expand(hist_root, root_g, root_h, root_c), root_g, root_h,
+            root_c, feat_ok_all)
         res_root = _depth_gate(res_root, jnp.asarray(0), cfg.max_depth)
 
         store_spec = P(None, FEATURE_AXIS if shard_hist else None,
@@ -370,7 +376,8 @@ def make_gspmd_grower(cfg: GrowerConfig, mesh: Mesh,
                              lax.slice(frow, (3,), (6,))])
             sl3 = jnp.where(small_left, lr3, lr3[::-1])
             res2, fok2 = jax.vmap(find, in_axes=(0, 0, 0, 0, None))(
-                hist2, sl3[:, 0], sl3[:, 1], sl3[:, 2], fok_parent)
+                expand(hist2, sl3[:, 0], sl3[:, 1], sl3[:, 2]),
+                sl3[:, 0], sl3[:, 1], sl3[:, 2], fok_parent)
             res2 = _depth_gate(res2, child_depth, cfg.max_depth)
             feat_ok = feat_ok.at[pair_sl].set(fok2 & fok_parent[None, :],
                                               unique_indices=True)

@@ -115,7 +115,7 @@ class FeatureParallelStrategy(SerialStrategy):
         self.axis = axis_name
         self.num_shards = num_shards
 
-    def setup(self, bins, meta: FeatureMeta, feat_valid):
+    def setup(self, bins, meta: FeatureMeta, feat_valid, num_cols: int):
         n, f = bins.shape
         fl = f // self.num_shards
         ax = lax.axis_index(self.axis)
@@ -123,8 +123,8 @@ class FeatureParallelStrategy(SerialStrategy):
         bins_local = lax.dynamic_slice(bins, (0, start), (n, fl))
         if meta.col is not None:
             # bundled: logical meta stays global; expansion maps are local
-            maps = make_expand_maps(meta, self.cfg.max_bin,
-                                    col_start=start, col_count=fl)
+            maps = make_expand_maps(meta, self.cfg.max_bin, fl,
+                                    col_start=start)
             return (meta, feat_valid, bins_local, None, None, start, maps)
         meta_local = FeatureMeta(
             num_bin=lax.dynamic_slice(meta.num_bin, (start,), (fl,)),
@@ -150,11 +150,11 @@ class FeatureParallelStrategy(SerialStrategy):
         if maps is not None:
             res, ok = best_split(hist_child, pg, ph, pc, meta.num_bin,
                                  meta.missing_type, meta.default_bin,
-                                 feat_valid & maps[5] & feat_ok,
+                                 feat_valid & maps[4] & feat_ok,
                                  self.cfg.split_config(),
                                  is_cat=meta.is_categorical,
                                  with_feat_ok=True)
-            ok_global = ok & maps[5]
+            ok_global = ok & maps[4]
         else:
             fok_local = lax.dynamic_slice(feat_ok, (start,),
                                           (fv_local.shape[0],))
@@ -244,8 +244,9 @@ class VotingStrategy(SerialStrategy):
         # ``pg``/``ph``/``pc``.  Expansion is linear in the histogram
         # given additive parents, so the psum of locally-expanded slices
         # in ``find`` equals the expansion of the psum-reduced histogram.
-        pl = hist[0].sum(axis=0)                             # [3] local parent
-        return expand_bundle_hist(hist, pl[0], pl[1], pl[2], ctx[2])
+        pl = hist[..., 0, :, :].sum(axis=-2)             # [..., 3] local parent
+        return expand_bundle_hist(hist, pl[..., 0], pl[..., 1], pl[..., 2],
+                                  ctx[2])
 
     def find(self, ctx, hist_child, pg, ph, pc, feat_ok):
         # the voting scan runs on a SLICED feature subset, so the serial

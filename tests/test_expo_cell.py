@@ -242,17 +242,52 @@ def test_bundle_expand_ms_reads_its_scope():
         ["split_find_ms_per_tree", "bundle_expand_ms_per_tree"])
 
 
-def scope_stacks(jaxpr, outer=""):
-    """The scope stack of every equation of a jaxpr and of the jaxprs inside
-    it (a loop's body, a switch's branches), each under its equation's."""
+def scoped_eqns(jaxpr, outer=""):
+    """(scope stack, equation) of every equation of a jaxpr and of the
+    jaxprs inside it (a loop's body, a switch's branches), each under its
+    equation's."""
     for eqn in jaxpr.eqns:
         stack = f"{outer}/{eqn.source_info.name_stack}"
-        yield stack
+        yield stack, eqn
         for val in eqn.params.values():
             for v in (val if isinstance(val, (list, tuple)) else [val]):
                 sub = getattr(v, "jaxpr", v)
                 if hasattr(sub, "eqns"):
-                    yield from scope_stacks(sub, stack)
+                    yield from scoped_eqns(sub, stack)
+
+
+def scope_stacks(jaxpr):
+    return (stack for stack, _ in scoped_eqns(jaxpr))
+
+
+def _bundled_grow(bundled=True):
+    """The grow program's jaxpr over 3 physical columns of 16 slots holding
+    12 one-hot logical ones (or, unbundled, the 3 columns themselves), and
+    the counter key of its expansion."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.grower import FeatureMeta, GrowerConfig, make_grower
+    n, e, fp, b = 4096, 12, 3, 16
+    cfg = GrowerConfig(num_leaves=7, min_data_in_leaf=1, max_bin=b,
+                       hist_method="segment", has_missing=False)
+    if not bundled:
+        e = fp
+    meta = FeatureMeta(
+        num_bin=jnp.full((e,), 2 if bundled else 5, jnp.int32),
+        missing_type=jnp.zeros((e,), jnp.int32),
+        default_bin=jnp.zeros((e,), jnp.int32),
+        is_categorical=jnp.zeros((e,), bool),
+        col=(jnp.repeat(jnp.arange(fp, dtype=jnp.int32), e // fp)
+             if bundled else None),
+        offset=(jnp.tile(jnp.arange(1, 1 + e // fp, dtype=jnp.int32), fp)
+                if bundled else None))
+    rng = np.random.RandomState(0)
+    args = (jnp.asarray(rng.randint(0, 5, (n, fp)).astype(np.uint8)),
+            jnp.asarray(rng.randn(n).astype(np.float32)),
+            jnp.ones((n,), jnp.float32), jnp.ones((n,), jnp.float32),
+            meta, jnp.ones((e,), bool))
+    key = f"impl=slots,logical={e},physical={fp},slots={fp * b}"
+    return jax.make_jaxpr(make_grower(cfg))(*args), key, fp * b
 
 
 def test_bundle_scopes_in_the_grow_program():
@@ -260,28 +295,9 @@ def test_bundle_scopes_in_the_grow_program():
     trace charges an operation to the leftmost scope of its name, so nested
     it would read nothing), at the root and on the children;
     ``bundle_decode`` sits inside ``partition`` and stays charged to it."""
-    import jax
-    import jax.numpy as jnp
-    from lightgbm_tpu.grower import FeatureMeta, GrowerConfig, make_grower
     from lightgbm_tpu.obs.counters import counters
-    n, e, fp, b = 4096, 12, 3, 16
-    cfg = GrowerConfig(num_leaves=7, min_data_in_leaf=1, max_bin=b,
-                       hist_method="segment", has_missing=False)
-    meta = FeatureMeta(
-        num_bin=jnp.full((e,), 2, jnp.int32),
-        missing_type=jnp.zeros((e,), jnp.int32),
-        default_bin=jnp.zeros((e,), jnp.int32),
-        is_categorical=jnp.zeros((e,), bool),
-        col=jnp.repeat(jnp.arange(fp, dtype=jnp.int32), e // fp),
-        offset=jnp.tile(jnp.arange(1, 1 + e // fp, dtype=jnp.int32), fp))
-    rng = np.random.RandomState(0)
-    args = (jnp.asarray(rng.randint(0, 5, (n, fp)).astype(np.uint8)),
-            jnp.asarray(rng.randn(n).astype(np.float32)),
-            jnp.ones((n,), jnp.float32), jnp.ones((n,), jnp.float32),
-            meta, jnp.ones((e,), bool))
     before = dict(counters.get("bundle_expand_dispatch"))
-    jaxpr = jax.make_jaxpr(make_grower(cfg))(*args)
-    key = f"logical={e},physical={fp}"
+    jaxpr, key, _ = _bundled_grow()
     assert counters.get("bundle_expand_dispatch")[key] \
         == before.get(key, 0) + 2                 # the root, the children
     stacks = set(scope_stacks(jaxpr.jaxpr))
@@ -295,3 +311,22 @@ def test_bundle_scopes_in_the_grow_program():
     for s in decode:
         assert "partition" in s.split("bundle_decode")[0], s
     assert any("split_find" in s and "bundle_" not in s for s in stacks)
+
+
+def test_bundle_expand_moves_the_slots():
+    """The expansion moves the F_physical * B measured slots (the counter
+    names the form and the count: ``impl=slots``, ``slots``): no gather under
+    ``bundle_expand`` reads more indices than there are slots, where the
+    gather it replaced read E_logical * B.  A grow program on columns that
+    are not bundled has no ``bundle_expand`` at all."""
+    jaxpr, key, slots = _bundled_grow()
+    assert "impl=slots" in key and f"slots={slots}" in key
+    eqns = [(s, q) for s, q in scoped_eqns(jaxpr.jaxpr)
+            if "bundle_expand" in s]
+    assert any(q.primitive.name == "scatter" for _, q in eqns)
+    for s, q in eqns:
+        if q.primitive.name == "gather":
+            n_idx = int(np.prod(q.invars[1].aval.shape[:-1]))
+            assert n_idx <= slots, (s, q.invars[1].aval)
+    plain, _, _ = _bundled_grow(bundled=False)
+    assert not any("bundle_" in s for s in scope_stacks(plain.jaxpr))
