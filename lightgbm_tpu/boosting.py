@@ -288,15 +288,18 @@ class GBDT:
             with jax.named_scope("objective"):
                 return objective.get_gradients(scores)
 
-        self._grad_fn = jax.jit(get_gradients)
-        self.scores = jnp.zeros((self.num_class, n), jnp.float32)
+        self._grad_fn = (jax.jit(get_gradients)
+                         if self._row_shards(1) is None
+                         else self._sharded_grad_fn())
+        self.scores = jnp.zeros((self.num_class, n), jnp.float32,
+                                device=self._row_shards(2))
         self._has_init_score = train.metadata.init_score is not None
         if self._has_init_score:
             init = np.asarray(train.metadata.init_score, np.float32)
             self.scores = self.scores + init.reshape(self.num_class, n)
         self._feat_valid_base = np.ones(len(fm["is_categorical"]), dtype=bool)
-        self._bag_weight = jnp.ones((n,), jnp.float32)
-        self._bag_cnt = jnp.ones((n,), jnp.float32)
+        self._bag_weight = self._row_ones()
+        self._bag_cnt = self._row_ones()
         self._subset_state = None  # (bins[M,F], idx[M], w[M], cnt[M], hist)
         self._bag_rng = make_rng(cfg.bagging_seed)
         self._feat_rng = make_rng(cfg.feature_fraction_seed)
@@ -323,6 +326,41 @@ class GBDT:
         # census providers)
         obs_metrics.register_source(self._metrics_samples)
         self._memory_preflight(cfg, train)
+
+    def _row_shards(self, ndim: int):
+        """Where a per-row array of ``ndim`` axes (rows last) lives: the
+        GSPMD mesh's row shards, evenly, where one process holds the mesh
+        and the rows split evenly over its ``batch`` axis; None (the
+        default device) elsewhere.  Scores, gradients, the objective's
+        per-row arrays, the bagging vectors and the grower's row -> leaf
+        map all live there, so no array of N rows is whole on one device."""
+        if self._gspmd_mesh is None or self._multiproc or self._row_pad:
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from .parallel.mesh import BATCH_AXIS
+        return NamedSharding(self._gspmd_mesh,
+                             P(*([None] * (ndim - 1)), BATCH_AXIS))
+
+    def _row_ones(self) -> jnp.ndarray:
+        return jnp.ones((self.num_data,), jnp.float32,
+                        device=self._row_shards(1))
+
+    def _sharded_grad_fn(self):
+        """The gradient program with the objective's per-row arrays
+        (``Objective.row_arrays``) on the row shards, handed to it as
+        ARGUMENTS: jit bakes an array a function closes over into the
+        program as a constant, which every device then holds whole."""
+        placed = {k: jax.device_put(v, self._row_shards(v.ndim))
+                  for k, v in self.objective.row_arrays().items()}
+        # the objective keeps the placed arrays: the whole copies die
+        objective = self.objective = self.objective.with_row_arrays(placed)
+
+        def get_gradients(scores, arrays):
+            with jax.named_scope("objective"):
+                return objective.with_row_arrays(arrays).get_gradients(scores)
+
+        grads = jax.jit(get_gradients)
+        return lambda scores: grads(scores, placed)
 
     def _metrics_samples(self) -> list:
         """Live ``/metrics`` samples of this booster: per-phase
@@ -402,7 +440,9 @@ class GBDT:
             data_shards=(gplan.data if gplan is not None else 1),
             feature_shards=(gplan.feature if gplan is not None else 1),
             block_shard_bins=(gplan.block_shard_bins
-                              if gplan is not None else False))
+                              if gplan is not None else False),
+            gspmd_fused=(gplan is not None
+                         and self.grower_cfg.hist_method == "fused"))
         self.memory_prediction = pred
         obs_memory.preflight(
             pred, hbm_budget=cfg.hbm_budget,
@@ -455,6 +495,7 @@ class GBDT:
         self._hist_bins = None
         self._gspmd_mesh = None
         self._gspmd_plan = None
+        self._mesh_layout_due = False
         self._stream_store = None   # HostBlockStore when data_stream
         self._streamer = None       # resolved to chunked (data/stream.py)
         self._placement = None      # PlacementPlan the pre-flight walked
@@ -841,16 +882,22 @@ class GBDT:
         from .parallel import gspmd as gspmd_mod
         from .parallel import mesh as mesh_mod
         # histogram formulation under gspmd (``gspmd_hist``): flat (the
-        # masked whole-partition scatter-add — pure XLA, the forced A/B
-        # partner) or fused (the shard_map hybrid: the fused Pallas
-        # kernel per row shard, partitioner-owned cross-shard reduction).
-        # ``auto`` stays flat until the on-chip A/B flips it
-        # (capture-backlog discipline, scripts/decide_flips.py).  What
-        # resolve_hist_method chose for one device does not apply here:
-        # the form is ``gspmd_hist``'s, behind the same fused_gate_reason
-        # and a condition on the mesh that is only known below.
-        gspmd_hist = "flat" if cfg.gspmd_hist == "auto" else cfg.gspmd_hist
+        # masked whole-partition scatter-add — pure XLA, any layout) or
+        # fused (the fused Pallas kernel per row shard inside a shard_map
+        # island; on a mesh of row shards alone the island is the whole
+        # grow loop, parallel/gspmd.py).  ``auto`` takes fused where the
+        # one-device resolution took the fused kernel (the chip), one
+        # process holds the mesh and the plan below shards rows alone:
+        # where PR 38's A/B was made (42M x 28 over four chips: the
+        # v5e compiler refuses the flat form's workspace; PERF.md); flat
+        # elsewhere.  Behind the same fused_gate_reason and a condition
+        # on the mesh known below.
         procs = jax.process_count()
+        gspmd_hist = cfg.gspmd_hist
+        auto = gspmd_hist == "auto"
+        if auto:
+            gspmd_hist = ("fused" if self.grower_cfg.hist_method == "fused"
+                          and procs == 1 else "flat")
         if gspmd_hist == "fused" and procs > 1:
             log.warning("gspmd_hist=fused is single-process for now (the "
                         "hybrid's shard_map island has no multi-host "
@@ -957,6 +1004,8 @@ class GBDT:
             plan = plan._replace(block_shard_bins=False)
         elif sa in ("batch,feature", "feature,batch"):
             plan = plan._replace(block_shard_bins=True)
+        if auto and (plan.feature > 1 or plan.block_shard_bins):
+            gspmd_hist = "flat"
         if gspmd_hist == "fused":
             # shape-dependent half of the fused gate, now that the mesh
             # extents are known: each device's column slice must be exact
@@ -1032,6 +1081,7 @@ class GBDT:
                 hb, NamedSharding(mesh, P(mesh_mod.BATCH_AXIS, None)))
         self._gspmd_mesh = mesh
         self._gspmd_plan = plan
+        self._mesh_layout_due = True
         self._gspmd_row_sharding = NamedSharding(
             mesh, P(mesh_mod.BATCH_AXIS))
         if self._multiproc:
@@ -1042,6 +1092,31 @@ class GBDT:
         self.grow = gspmd_mod.make_gspmd_grower(
             self.grower_cfg, mesh, bundled=self.meta.col is not None,
             pack_plan=self._pack_plan, block_shard=plan.block_shard_bins)
+
+    def _record_mesh_layout(self, grow_args) -> None:
+        """Once, after the GSPMD grower's first call: one ``mesh_layout``
+        event (the mesh, the rows a shard holds, the histogram form, the
+        compiled program's collectives by kind) and the counter
+        ``grow_loop_collective_bytes{op}``, the payload of the collectives
+        inside the grow loop's body: what crosses chips at every split
+        (``utils/jaxpr_audit.hlo_loop_census``).  The lowering and the
+        compile hit jit's own cache: nothing compiles a second time."""
+        from .obs.collectives import hlo_census
+        from .utils.jaxpr_audit import hlo_loop_census
+        self._mesh_layout_due = False
+        compiled = self.grow.lower(*grow_args).compile()
+        census = hlo_census(compiled, label="grow")
+        loop = hlo_loop_census(compiled.as_text())
+        for op, rec in loop.items():
+            obs_counters.inc("grow_loop_collective_bytes",
+                             value=rec["bytes"], op=op)
+        plan = self._gspmd_plan
+        obs_counters.event(
+            "mesh_layout", data=plan.data, feature=plan.feature,
+            rows_per_shard=int(self.bins.shape[0]) // plan.data,
+            hist_form=self.grower_cfg.hist_method,
+            collectives={op: dict(rec) for op, rec in census.items()},
+            loop_collectives={op: dict(rec) for op, rec in loop.items()})
 
     def grow_hlo_census(self, label: str = "grow") -> Dict[str, Dict[str, int]]:
         """Compiled-HLO collective census of the CURRENT grower
@@ -1136,7 +1211,8 @@ class GBDT:
                     self._subset_state = None
                     mask = (self._bag_rng.random(n)
                             < cfg.bagging_fraction).astype(np.float32)
-                    self._bag_weight = jnp.asarray(mask)
+                    self._bag_weight = jax.device_put(
+                        mask, self._row_shards(1))
                     self._bag_cnt = self._bag_weight
                 self._bagging_on = True
         elif getattr(self, "_bagging_on", False):
@@ -1145,7 +1221,7 @@ class GBDT:
             # trees see the full data again
             self._bagging_on = False
             self._subset_state = None
-            self._bag_weight = jnp.ones((self.num_data,), jnp.float32)
+            self._bag_weight = self._row_ones()
             self._bag_cnt = self._bag_weight
 
     def _set_subset(self, idx: np.ndarray, w: np.ndarray) -> None:
@@ -1336,11 +1412,15 @@ class GBDT:
                 else:
                     hist_arg = ((self._hist_bins,)
                                 if self._pack_plan is not None else ())
-                    arrays, row_leaf = self.grow(
+                    grow_args = (
                         self.bins, *hist_arg,
                         self._dist_row_vec(g[k] * self._bag_weight),
                         self._dist_row_vec(h[k] * self._bag_weight),
                         self._dist_row_vec(cnt), self.meta, feat_mask)
+                    arrays, row_leaf = self.grow(*grow_args)
+                    if self._mesh_layout_due:
+                        self._record_mesh_layout(grow_args)
+                    del grow_args       # the weighted rows die with the call
                     row_leaf = self._local_rows(row_leaf)
                 nf_ok = gh_ok & jnp.isfinite(arrays.leaf_value).all()
                 if pipeline:
@@ -1485,10 +1565,11 @@ class GBDT:
     def _local_rows(self, row_leaf) -> jnp.ndarray:
         """The grower's row-sharded output -> this process's local rows."""
         if not self._multiproc:
+            if self._row_shards(1) is not None:
+                return row_leaf      # on the shards the scores live on
             if self._gspmd_mesh is not None:
-                # fully addressable single-process global array: read it
-                # out once per tree (the multiproc path's precedent) so
-                # the score update consumes an unsharded map
+                # rows the shards do not divide: the scores are whole on
+                # one device, so the map is read out and put there
                 return jnp.asarray(np.asarray(row_leaf)[:self.num_data])
             return row_leaf[:self.num_data] if self._row_pad else row_leaf
         if self._multiproc_replicated:   # fully addressable: read directly

@@ -476,15 +476,21 @@ class Config:
     gspmd_hist: str = "auto"       # histogram formulation inside the
                                    # gspmd program: flat (masked whole-
                                    # partition scatter-add — pure XLA,
-                                   # any layout, the forced A/B partner)
-                                   # | fused (the hybrid: each device
-                                   # runs the fused Pallas gather-
-                                   # histogram over its row shard inside
-                                   # a shard_map island; unfusable
-                                   # layouts downgrade loudly to flat)
-                                   # | auto (flat until the on-chip A/B
-                                   # flips it — capture-backlog
-                                   # discipline, scripts/decide_flips.py)
+                                   # any layout) | fused (the fused
+                                   # Pallas kernel per row shard inside
+                                   # a shard_map island; on a mesh of
+                                   # row shards alone the island holds
+                                   # the serial grower, one histogram
+                                   # psum a split; unfusable layouts
+                                   # downgrade loudly to flat) | auto
+                                   # (fused where the one-device method
+                                   # is the fused kernel, one process
+                                   # holds the mesh and it shards rows
+                                   # alone; flat elsewhere.  PR 38: the
+                                   # v5e compiler refuses flat at 42M x
+                                   # 28 on four chips, where fused runs;
+                                   # below that size the rule is
+                                   # unmeasured on a chip)
     collective_timeout: float = 120.0  # seconds one host-object collective
                                        # attempt may block before it is
                                        # failed and retried (parallel/sync.py)

@@ -219,6 +219,25 @@ def _row_leaf_from_intervals(order, leaf_start, leaf_cnt, n):
         return row_leaf
 
 
+# past 2**24 a float32 sum of row counts rounds: a split's counts are a
+# cumulative sum over the bins and the parent's count less it
+_F32_EXACT_ROWS = 2 ** 24
+
+
+def _leaf_rows(order, lsc, cw_pad, n):
+    """Each leaf's in-bag rows on this shard, as integers: its window's
+    length when every row is in the bag, else the bag flags of its window
+    of ``order`` summed (a gather of N rows, paid under bagging alone)."""
+    start, cnt = lsc[:, 0], lsc[:, 1]
+
+    def bagged():
+        flags = (cw_pad[order[:n]] > 0).astype(jnp.int32)
+        csum = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                jnp.cumsum(flags)])
+        return csum[start + cnt] - csum[start]
+    return lax.cond(jnp.all(cw_pad[:n] > 0), lambda: cnt, bagged)
+
+
 class _LoopState(NamedTuple):
     """Grow-loop carry.  The per-leaf split pool and the tree-in-progress
     travel as PACKED row matrices — one row write per updated leaf/node
@@ -282,7 +301,8 @@ class SerialStrategy:
       prunes are excluded from this scan, and from the whole subtree —
       the reference's feature-pruning heuristic
       (serial_tree_learner.cpp:406-417);
-    * ``reduce_scalar(x)`` — global sums of row statistics.
+    * ``reduce_scalar(x)`` — global sums of row statistics;
+    * ``row_shards()`` — the shards the rows are split over.
     """
 
     def __init__(self, cfg: "GrowerConfig"):
@@ -319,6 +339,9 @@ class SerialStrategy:
 
     def reduce_scalar(self, x):
         return x
+
+    def row_shards(self):
+        return 1
 
 
 def make_expand_maps(meta: FeatureMeta, num_bins: int, num_cols: int,
@@ -1244,9 +1267,17 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                            feat_ok_store0, sgain0, sf32_0, si32_0, scat0,
                            scatb0, tnf0, tni0, tlf0, tli0, tcat0, tcatb0)
         state = lax.while_loop(cond, body, state)
+        tlf = state.tlf
+        if n * strategy.row_shards() > _F32_EXACT_ROWS:
+            # the split scan's counts have rounded: each leaf's rows again,
+            # summed as integers over its window and over the shards
+            with jax.named_scope("leaf_rows"):
+                rows = strategy.reduce_scalar(
+                    _leaf_rows(state.order, state.lsc, cw_pad, n))
+                tlf = tlf.at[:, 1].set(rows.astype(dtype))
         # unpack the packed carriers into the public TreeArrays ONCE per
         # tree (a handful of column slices outside the loop)
-        tree = unpack_tree(state.step + 1, state.tni, state.tnf, state.tlf,
+        tree = unpack_tree(state.step + 1, state.tni, state.tnf, tlf,
                            state.tli, state.tcat, state.tcatb, cfg)
         row_leaf = _row_leaf_from_intervals(state.order, state.lsc[:, 0],
                                             state.lsc[:, 1], n)

@@ -24,8 +24,9 @@ flattened ``score[k * num_data + i]``.
 """
 from __future__ import annotations
 
+import copy
 import functools
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +63,23 @@ class Objective:
 
     def get_gradients(self, score: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
         raise NotImplementedError
+
+    # the per-row arrays get_gradients reads, rows on the last axis: on a
+    # mesh of row shards boosting places them there and hands them to the
+    # gradient program as arguments (boosting._sharded_grad_fn)
+    row_array_names: Tuple[str, ...] = ("labels", "weights")
+
+    def row_arrays(self) -> Dict[str, jnp.ndarray]:
+        return {k: getattr(self, k) for k in self.row_array_names
+                if getattr(self, k) is not None}
+
+    def with_row_arrays(self, arrays: Dict[str, jnp.ndarray]) -> "Objective":
+        """A shallow copy of this objective that reads ``arrays`` (named
+        as :meth:`row_arrays` names them) in place of its own."""
+        twin = copy.copy(self)
+        for k, v in arrays.items():
+            setattr(twin, k, v)
+        return twin
 
     def convert_output(self, x):
         return x
@@ -191,6 +209,9 @@ class BinaryLogloss(Objective):
         self._label_sign = jnp.where(self.labels > 0, 1.0, -1.0)
         self._label_weight = jnp.where(self.labels > 0, lw[1], lw[0])
 
+    row_array_names = Objective.row_array_names + ("_label_sign",
+                                                   "_label_weight")
+
     def get_gradients(self, score):
         sig = self.config.sigmoid
         ls = self._label_sign
@@ -224,6 +245,8 @@ class MulticlassSoftmax(Objective):
         self._onehot = jnp.asarray(
             np.eye(self.config.num_class, dtype=np.float32)[:, li])  # [K, N]
 
+    row_array_names = Objective.row_array_names + ("_onehot",)
+
     def get_gradients(self, score):
         p = jax.nn.softmax(score, axis=0)          # [K, N]
         g = p - self._onehot
@@ -256,6 +279,8 @@ class MulticlassOVA(Objective):
         self._sign = jnp.asarray(
             np.where(np.eye(self.config.num_class)[:, li] > 0, 1.0, -1.0)
             .astype(np.float32))
+
+    row_array_names = Objective.row_array_names + ("_sign",)
 
     def get_gradients(self, score):
         sig = self.config.sigmoid

@@ -411,7 +411,19 @@ def predict_hbm(rows: int, features: int, bins: int = 255, leaves: int = 31,
     # ``feature`` mesh axis (the planner's main lever: this is the
     # component that outgrows a chip first at Epsilon-wide shapes)
     pool_bytes = leaves * -(-features // fs) * bins * 3 * 4
-    if d > 1 or fs > 1:
+
+    def panel_width(cols):
+        """u32 words a row of the fused kernel's packed panel, padded to
+        the 128 lanes: the bins of ``cols`` columns 4 (1-byte) or 2 to a
+        word, and g, h, c."""
+        per = 4 if bin_bytes == 1 else 2
+        words = -(-(-(-cols // 8) * 8) // per) + 3
+        return -(-words // 128) * 128
+
+    # gspmd_hist=fused on a mesh of row shards alone runs the serial grower
+    # on each device's rows (parallel/gspmd.py): the serial layout per shard
+    shard_local = gspmd_fused and fs == 1 and not block_shard_bins
+    if (d > 1 or fs > 1) and not shard_local:
         # GSPMD grower layout (parallel/gspmd.py): no gather buckets, no
         # sentinel staging, no ``order`` permutation — the partition is
         # the row_leaf map and the per-split histogram is one flat
@@ -426,11 +438,7 @@ def predict_hbm(rows: int, features: int, bins: int = 255, leaves: int = 31,
             # kernel per split — the scatter workspace is replaced by
             # the resident-sized panel plus the compacted order vector
             # (with its aligned over-fetch tail)
-            sc = int(packed_cols) or features
-            cols_d = -(-sc // fs)
-            per = 4 if bin_bytes == 1 else 2
-            words = -(-(-(-cols_d // 8) * 8) // per) + 3
-            width = -(-words // 128) * 128
+            width = panel_width(-(-(int(packed_cols) or features) // fs))
             transients = {
                 "fused_panel": (rows_d + 1) * width * 4,
                 "fused_order": (rows_d + 2048) * 4,
@@ -476,6 +484,16 @@ def predict_hbm(rows: int, features: int, bins: int = 255, leaves: int = 31,
             # the gather buffer for the largest window
             "gather_buffer": maxbuf * row_bytes,
         }
+        if shard_local:
+            # the fused kernel reads two lane-padded u32 panels of the
+            # shard's rows (the sentinel-padded copy and the packed panel:
+            # 1,024 B a row at 28 one-byte columns, 10.75 GB of a chip's
+            # 11.4 at 10.5M rows in the v5e compile, PERF.md PR 38) where
+            # the line above counts the inputs' own bytes; it gathers
+            # nothing
+            transients["staging"] = 2 * (rows_d + 1) * panel_width(
+                int(packed_cols) or features) * 4
+            del transients["gather_buffer"]
     if serving_trees > 0:
         # the serving engine's term (docs/SERVING.md): resident SoA node
         # arrays [Tp, P] (feat/thr/left/right i32 + miss/cat_ref i32 +

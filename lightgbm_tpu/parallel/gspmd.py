@@ -42,8 +42,7 @@ The HISTOGRAM itself has two formulations under the same program shape
 (``gspmd_hist``, resolved in ``boosting._setup_gspmd``):
 
 * ``flat`` — the masked whole-partition scatter-add
-  (``subset_histogram_flat``): pure XLA, partitions on any layout, and
-  the forced A/B partner;
+  (``subset_histogram_flat``): pure XLA, partitions on any layout;
 * ``fused`` — the hybrid: a ``shard_map`` manual-sharding ISLAND inside
   the same jit'd program, in which each device runs the fused Pallas
   gather-histogram (``ops/pallas_hist.hist6_fused``) over its own row
@@ -56,11 +55,21 @@ The HISTOGRAM itself has two formulations under the same program shape
   census: no all-gather of row shards, ever).  One kernel from laptop
   CPU (``hist_interpret=True``) to pod slice.
 
+On a mesh of row shards alone (``feature`` extent 1, the
+``tree_learner=data`` layout) the ``fused`` form widens the island to the
+WHOLE grow loop (``parallel/learner.make_distributed_grower``'s data
+learner under the program name ``grow_tree``): each device grows on
+its own rows with the serial grower's machinery (its ``order`` window
+partition, the dense branch, the fused kernel over its own panel), and
+only the histogram reduction (``hist_reduce``, one psum of a
+[F, B, 3] table a split) and the root's three sums cross chips.  A
+split then costs each device what its leaf's rows cost, where the
+row -> leaf selection above costs all its rows (a cumulative sum and a
+scatter of N / d a split; PERF.md, PR 38).
+
 ``parallel/sync.py``'s hardened host-object ladder stays the
 control-plane (bin finding, checkpoint barriers, preemption agreement):
-GSPMD owns the data plane only.  The shard_map learners remain the
-forced A/B partner (``parallel_impl=shardmap``) until on-chip numbers
-land.
+GSPMD owns the data plane only.
 """
 from __future__ import annotations
 
@@ -79,6 +88,7 @@ from ..grower import (FeatureMeta, GrowerConfig, _depth_gate,
 from ..obs.counters import counters as obs_counters
 from ..ops.histogram import subset_histogram_flat, subset_histogram_fused_local
 from ..ops.split import best_split, leaf_output, make_fused_ctx
+from .learner import make_distributed_grower
 from .mesh import BATCH_AXIS, FEATURE_AXIS
 
 
@@ -110,6 +120,10 @@ def make_gspmd_grower(cfg: GrowerConfig, mesh: Mesh,
     shard_hist = int(mesh.shape[FEATURE_AXIS]) > 1
     f_shards = int(mesh.shape[FEATURE_AXIS])
     use_fused = cfg.hist_method == "fused"
+    if use_fused and f_shards == 1 and not block_shard:
+        # the island around the whole grow loop: the data-parallel learner
+        return make_distributed_grower(cfg, mesh, "data", bundled=bundled,
+                                       pack_plan=pack_plan, name="grow_tree")
 
     def cstr(x, spec):
         return lax.with_sharding_constraint(x, NamedSharding(mesh, spec))

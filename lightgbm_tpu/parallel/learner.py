@@ -27,11 +27,14 @@ module is the FORCED A/B PARTNER (``parallel_impl=shardmap``), not the
 default: the NamedSharding path lets the XLA partitioner insert and
 overlap the same collectives this file issues by hand.  ``auto`` still
 resolves here for multi-process training and for the voting learner
-(PV-tree's vote compression is call-site collective machinery by nature)
-— and the explicit choreography below remains the reference against
-which the compiler-owned path is A/B'd until on-chip numbers land.
+(PV-tree's vote compression is call-site collective machinery by nature).
+:class:`DataParallelStrategy` also runs inside the GSPMD program: on a
+mesh of row shards alone its ``gspmd_hist=fused`` island is the serial
+grower with this strategy's psums (``parallel/gspmd.py``).
 """
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +43,8 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..grower import (FeatureMeta, GrowerConfig, SerialStrategy, TreeArrays,
-                      expand_bundle_hist, make_expand_maps, make_grower)
+                      expand_bundle_hist, make_expand_maps, make_grower,
+                      scoped_program_name)
 from ..obs.collectives import note_collective
 from ..ops.split import SplitResult, best_split, per_feature_best_gain
 
@@ -91,11 +95,16 @@ class DataParallelStrategy(SerialStrategy):
 
     def reduce_hist(self, hist):
         note_collective("psum", hist, self.axis, site="reduce_hist")
-        return lax.psum(hist, self.axis)
+        # the one cross-chip step of a split, named for the trace
+        with jax.named_scope("hist_reduce"):
+            return lax.psum(hist, self.axis)
 
     def reduce_scalar(self, x):
         note_collective("psum", x, self.axis, site="reduce_scalar")
         return lax.psum(x, self.axis)
+
+    def row_shards(self):
+        return lax.axis_size(self.axis)
 
 
 class FeatureParallelStrategy(SerialStrategy):
@@ -203,6 +212,9 @@ class DataFeatureStrategy(FeatureParallelStrategy):
         note_collective("psum", x, self.data_axis, site="reduce_scalar")
         return lax.psum(x, self.data_axis)
 
+    def row_shards(self):
+        return lax.axis_size(self.data_axis)
+
 
 class VotingStrategy(SerialStrategy):
     """Data-parallel with top-k vote compression (PV-tree).
@@ -231,6 +243,9 @@ class VotingStrategy(SerialStrategy):
     def reduce_scalar(self, x):
         note_collective("psum", x, self.axis, site="reduce_scalar")
         return lax.psum(x, self.axis)
+
+    def row_shards(self):
+        return lax.axis_size(self.axis)
 
     # reduce_hist stays identity: histograms remain LOCAL and only the
     # voted feature slices are psum-reduced inside ``find`` (PV-tree's
@@ -304,7 +319,7 @@ class VotingStrategy(SerialStrategy):
 def make_distributed_grower(cfg: GrowerConfig, mesh: Mesh,
                             tree_learner: str = "data",
                             top_k: int = 20, bundled: bool = False,
-                            pack_plan=None):
+                            pack_plan=None, name: Optional[str] = None):
     """shard_map-wrapped grow function for a 1-D mesh.
 
     Returns ``fn(bins, gw, hw, cw, meta, feat_valid) -> (TreeArrays, row_leaf)``
@@ -315,6 +330,8 @@ def make_distributed_grower(cfg: GrowerConfig, mesh: Mesh,
     second positional arg — the nibble-packed histogram matrix, sharded
     like ``bins`` (data/voting only; the feature learner's column
     slicing is incompatible with shared bytes and boosting gates it off).
+    ``name`` names the jitted program (``scoped_program_name``): the GSPMD
+    grower's data-parallel island is this program under ``grow_tree``.
     """
     axis = mesh.axis_names[0]
     n_shards = mesh.devices.size
@@ -361,4 +378,10 @@ def make_distributed_grower(cfg: GrowerConfig, mesh: Mesh,
                              meta_spec, P()),
                    out_specs=(tree_spec, row_out),
                    check_vma=False)
-    return jax.jit(fn)
+    if name is None:
+        return jax.jit(fn)
+
+    def program(*args):
+        return fn(*args)
+    program.__name__ = name
+    return jax.jit(scoped_program_name(program))

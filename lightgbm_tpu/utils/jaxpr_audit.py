@@ -84,6 +84,53 @@ def hlo_collective_census(compiled_or_text) -> Dict[str, Dict[str, int]]:
     return out
 
 
+def hlo_loop_census(text: str) -> Dict[str, Dict[str, int]]:
+    """:func:`hlo_collective_census` of the grow loop's body alone: of the
+    ``while`` loops of the ENTRY computation the one whose body reaches
+    the most HLO lines (its branches, calls and nested loops: a kernel's
+    own grid loop is reached from the grow loop's, not the other way).
+    What it holds runs once a split; the root's and the tree's own
+    collectives stand outside it."""
+    comps: Dict[str, List[str]] = {}
+    entry, cur = None, None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$", line)
+        if cur is None and head:
+            cur = head.group(2)
+            comps[cur] = []
+            if head.group(1):
+                entry = cur
+        elif cur is not None and line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+
+    def reach(root):
+        seen, todo = set(), [root]
+        while todo:
+            name = todo.pop()
+            if name in seen or name not in comps:
+                continue
+            seen.add(name)
+            for line in comps[name]:
+                todo.extend(re.findall(
+                    r"(?:body|condition|to_apply|calls)=%?([\w.\-]+)", line))
+                for group in re.findall(
+                        r"branch_computations=\{([^}]*)\}", line):
+                    todo.extend(n.strip().lstrip("%")
+                                for n in group.split(","))
+        return seen
+
+    bodies = [reach(m.group(1)) for line in comps.get(entry, [])
+              for m in [re.search(r"\bwhile\(.*\bbody=%?([\w.\-]+)", line)]
+              if m]
+    if not bodies:
+        return {}
+    loop = max(bodies, key=lambda names: sum(len(comps[n]) for n in names))
+    return hlo_collective_census(
+        "\n".join(line for name in loop for line in comps[name]))
+
+
 def _aval_elems(v) -> int:
     aval = getattr(v, "aval", None)
     shape = getattr(aval, "shape", None)

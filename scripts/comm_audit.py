@@ -147,7 +147,8 @@ def _markdown(rows):
            "collective payloads are read off the traced grow program on "
            "the 8-virtual-device CPU mesh (shapes are backend-independent; "
            "time estimates use v5e ICI at 45 GB/s/link, ring all-reduce "
-           "2(S-1)/S, and are estimates until a multi-chip slice exists).",
+           "2(S-1)/S, and are estimates; the last section gives the data "
+           "learner's collectives as compiled for four v5e chips, PR 38).",
            "",
            "| shape | learner | per-split colls | MB/split | MB/tree | "
            "est. ICI ms/tree |",

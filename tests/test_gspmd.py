@@ -242,9 +242,12 @@ def test_gspmd_fused_zero_recompile_across_calls():
                 binsd, jax.device_put(g, rs), jax.device_put(h, rs),
                 jax.device_put(c, rs), _meta(), jnp.ones((F,), bool))[0])
     disp = counters.get("hist_dispatch")
-    # one trace per mesh per site: 2 meshes x {root, split}, never 4
+    # one trace per mesh per site: 2 meshes x {root, split}, never 4 (the
+    # 8x1 mesh runs the serial grower in its island: its root fetches the
+    # identity window by block; the 2x4 island's root selects by row_leaf)
     assert disp == {
-        "col_tiles=1,fetch=rows,interpret=True,method=fused,site=root": 2,
+        "col_tiles=1,fetch=block,interpret=True,method=fused,site=root": 1,
+        "col_tiles=1,fetch=rows,interpret=True,method=fused,site=root": 1,
         "col_tiles=1,fetch=rows,interpret=True,method=fused,site=split": 2,
     }, disp
 
