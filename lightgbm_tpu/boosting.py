@@ -54,6 +54,53 @@ class NonFiniteError(RuntimeError):
     ``nonfinite_policy`` could not (or was asked not to) recover."""
 
 
+# the most leaves whose values a row picks by selects (below); above it, a
+# gather.  On a v5e at 10,500,000 rows (scripts/probe_score_update.py; ms
+# a call, select / gather, and the select's compile): 31 leaves 0.32 /
+# 0.25, 0.8 s; 255 leaves 0.88 / 86.4, 4.4 s; 1023 leaves 3.80 / 56.1,
+# 10.6 s; 4095 leaves 15.7 / 75.1, 41.5 s and 226 MB of temporaries.  The
+# select saves a tree 52 ms at 1023 leaves and 59 at 4095, so its extra
+# compile is repaid in 200 trees at 1023 and in 700 at 4095.
+SELECT_MAX_LEAVES = 1023
+
+
+def leaf_value_of_rows(leaf_values, row_leaf):
+    """``leaf_values[row_leaf]``, bit for bit (NaN, infinities and -0.0
+    included), for every ``row_leaf`` in ``[0, L)``.
+
+    Up to :data:`SELECT_MAX_LEAVES` leaves, a binary tree of selects on the
+    leaf id's bits: the leaves padded with the last one to a power of two,
+    each level halves them by one bit test.  That is L - 1 selects and
+    log2 L tests a row, elementwise, so ONE loop fusion over the rows,
+    with no lookup by N indices (on the v5e a gather of one element an
+    index pays 6-9 ns: 86 ms a tree at 10.5M rows).  The form is chosen
+    by the leaf count, the one thing that makes the select dearer, and
+    counted once a trace as ``score_update_dispatch{impl, leaves}``."""
+    L = leaf_values.shape[0]
+    if L > SELECT_MAX_LEAVES:
+        obs_counters.inc("score_update_dispatch", impl="gather", leaves=L)
+        return leaf_values[row_leaf]
+    obs_counters.inc("score_update_dispatch", impl="select", leaves=L)
+    vals = [leaf_values[min(i, L - 1)]
+            for i in range(1 << max(1, (L - 1).bit_length()))]
+    bit = 1
+    while len(vals) > 1:
+        odd = (row_leaf & bit) != 0
+        vals = [jnp.where(odd, hi, lo) for lo, hi in zip(vals[::2], vals[1::2])]
+        bit <<= 1
+    return vals[0]
+
+
+@jax.jit
+def _update_score(scores_k, leaf_values, row_leaf, lr):
+    """Add ``lr`` times each row's leaf value to its score, the rows placed
+    by the grower's ``row_leaf``, under the ``score_update`` scope (entered
+    inside the traced function, so it is in the HLO whatever the cache
+    holds)."""
+    with jax.named_scope("score_update"):
+        return scores_k + lr * leaf_value_of_rows(leaf_values, row_leaf)
+
+
 @jax.jit
 def _route_update_score(scores_k, bins, split_feature, threshold_bin,
                         default_left, left_child, right_child, feat_info,
@@ -66,7 +113,7 @@ def _route_update_score(scores_k, bins, split_feature, threshold_bin,
         row_leaf = predict_binned_leaf(
             bins, split_feature, threshold_bin, default_left, left_child,
             right_child, feat_info, is_cat, cat_bins)
-        return scores_k + lr * leaf_values[row_leaf]
+        return scores_k + lr * leaf_value_of_rows(leaf_values, row_leaf)
 
 
 class _ValidSet:
@@ -307,12 +354,6 @@ class GBDT:
         metric_names = cfg.metric or [default_metric_for_objective(cfg.objective)]
         self.metric_names = metric_names
         self.train_metrics = self._make_metrics(train)
-
-        @jax.jit
-        def _update_score(scores_k, leaf_values, row_leaf, lr):
-            with jax.named_scope("score_update"):
-                return scores_k + lr * leaf_values[row_leaf]
-
         self._update_score = _update_score
 
         # device-memory observability (obs/memory.py): owner tags for the

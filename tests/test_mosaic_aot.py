@@ -314,3 +314,37 @@ def test_lambdarank_buckets_lower(v5e):
         v5e((1, n), jnp.float32)).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 4 * objectives._PAIR_BLOCK, temp
+
+
+def test_score_update_lowers_without_gather_or_collective():
+    """The score update at Higgs's rows and 255 leaves compiles for the v5e
+    with no gather (the pick of a row's leaf value is selects over the
+    leaves), on one chip and with the rows sharded over a 4 x 1 mesh of
+    the 2x2 host, where it holds no collective either."""
+    import re
+    import numpy as np
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from lightgbm_tpu.boosting import _update_score
+    from lightgbm_tpu.parallel.mesh import BATCH_AXIS
+    from lightgbm_tpu.utils.jaxpr_audit import hlo_collective_census
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"TPU AOT topology unavailable: {e}")
+    n, leaves = 10_500_000, 255
+    for devs in (topo.devices[:1], topo.devices):
+        mesh = Mesh(np.array(devs), (BATCH_AXIS,))
+
+        def arg(shape, dtype, spec):
+            return jax.ShapeDtypeStruct(
+                shape, dtype, sharding=NamedSharding(mesh, spec))
+        text = _update_score.lower(
+            arg((n * len(devs),), jnp.float32, P(BATCH_AXIS)),
+            arg((leaves,), jnp.float32, P()),
+            arg((n * len(devs),), jnp.int32, P(BATCH_AXIS)),
+            arg((), jnp.float32, P())).compile().as_text()
+        assert not re.search(r"\bgather\(", text)
+        assert hlo_collective_census(text) == {}
