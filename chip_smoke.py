@@ -117,7 +117,9 @@ def train_phase(X, y, Xv, yv, trees=TREES, params=PARAMS):
           f"valid AUC {auc:.4f} >= floor {VALID_AUC_FLOOR}")
 
     dispatch = counters.get("hist_dispatch")
-    want = {f"col_tiles=1,fetch={fetch},interpret=False,method=fused,site={s}"
+    width = bst.inner.grower_cfg.max_bin
+    want = {f"col_tiles=1,fetch={fetch},hi=16,interpret=False,method=fused,"
+            f"site={s},width={width}"
             for s, fetch in (("root", "block"), ("split", "rows"))}
     check(set(dispatch) == want,
           f"hist_dispatch is the compiled fused kernel at root and split "

@@ -28,7 +28,8 @@ import numpy as np
 
 from .config import Config
 from .data.dataset import TrainingData
-from .grower import FeatureMeta, GrowerConfig, StreamedGrower, make_grower
+from .grower import (FeatureMeta, GrowerConfig, StreamedGrower, layout_width,
+                     make_grower)
 from .metrics import Metric, create_metric, default_metric_for_objective
 from .obs import collectives as obs_collectives
 from .obs import devprof as obs_devprof
@@ -503,7 +504,7 @@ class GBDT:
             lambda_l1=cfg.lambda_l1,
             lambda_l2=cfg.lambda_l2,
             min_gain_to_split=cfg.min_gain_to_split,
-            max_bin=train.max_num_bin(),
+            max_bin=layout_width(train.max_num_bin()),
             hist_method=hist_method,
             row_tile=cfg.pallas_row_tile,
             bucket_min_log2=cfg.pallas_bucket_min_log2,
@@ -622,7 +623,7 @@ class GBDT:
         # artifacts read) names the kernel that runs
         from .data.packing import PACK_JOINT_BINS
         from .grower import resolve_hist_method
-        max_bin = train.max_num_bin()
+        max_bin = layout_width(train.max_num_bin())
         hist_method, reason = resolve_hist_method(
             cfg.use_pallas, cfg.cpu_hist_method, train.binned.dtype,
             jnp.float32, (max(PACK_JOINT_BINS, max_bin)

@@ -48,7 +48,7 @@ from .data.packing import (PACK_JOINT_BINS, pack_fused_panel,
 from .obs.counters import counters as obs_counters
 from .ops.histogram import (on_tpu, subset_histogram, subset_histogram_flat,
                             subset_histogram_fused)
-from .ops.pallas_hist import NIB, fused_idx_fetch
+from .ops.pallas_hist import FUSED_MAX_BINS, fused_idx_fetch
 from .ops.split import (MISSING_NAN, MISSING_ZERO, SplitConfig, SplitResult,
                         best_split, leaf_output, make_fused_ctx)
 
@@ -150,6 +150,23 @@ def decode_bundle_bin(raw, feat, meta: FeatureMeta):
     return jnp.where(off < 0, raw, sub)
 
 
+WIDE_WIDTH_STEP = 32   # past 256 bins the width is a multiple of this
+
+
+def layout_width(max_num_bin: int) -> int:
+    """The histogram width (``GrowerConfig.max_bin``) a grower is built at
+    for data whose widest column has ``max_num_bin`` bins: that many up to
+    256; past 256 rounded up to a multiple of ``WIDE_WIDTH_STEP``.  A
+    categorical column keeps the categories that cover 99 % of the rows its
+    bin mapper sampled, so the same data binned from another sample keeps a
+    bin or two more or fewer (278 to 279 of 297 categories on ``expo-cat``):
+    without the step each such width is a grow program of its own, compiled
+    anew.  The step's bins belong to no column, so no scan reads them."""
+    if max_num_bin <= 256:
+        return max_num_bin
+    return -(-max_num_bin // WIDE_WIDTH_STEP) * WIDE_WIDTH_STEP
+
+
 def fused_gate_reason(bins_dtype, weights_dtype, hist_width: int):
     """None when the fused-gather kernel can run on this layout, else the
     human-readable reason it cannot."""
@@ -157,9 +174,9 @@ def fused_gate_reason(bins_dtype, weights_dtype, hist_width: int):
         return f"bin dtype {jnp.dtype(bins_dtype)} is wider than 2 bytes"
     if jnp.dtype(weights_dtype) != jnp.float32:
         return f"weights dtype {jnp.dtype(weights_dtype)} is not float32"
-    if hist_width > NIB * NIB:
-        return (f"histogram width {hist_width} exceeds the "
-                f"nibble-factorized limit {NIB * NIB}")
+    if hist_width > FUSED_MAX_BINS:
+        return (f"histogram width {hist_width} exceeds the fused "
+                f"kernel's limit {FUSED_MAX_BINS}")
     return None
 
 
@@ -449,19 +466,20 @@ def _set(arr, idx, value):
 # ``parallel/gspmd.py``'s) are what a device trace reads them by:
 # ``partition`` (``part_route``, ``part_read``, ``part_sort``, ``part_dense``
 # and ``bundle_decode`` inside it), ``histogram`` (``hist_root``),
-# ``hist_pool``, ``split_find``, ``bundle_expand``, ``row_leaf``,
-# ``fused_panel``, ``node_tables``.  Bump when a scope is added, renamed or
-# moved: the cache key ignores names.  jax strips an operation's metadata
-# before it hashes a program for the persistent compile cache, so two
+# ``hist_pool``, ``split_find`` (``cat_scan`` inside it), ``bundle_expand``,
+# ``row_leaf``, ``fused_panel``, ``node_tables``.  Bump when a scope is
+# added, renamed or moved: the cache key ignores names.  jax strips an
+# operation's metadata before it hashes a program for the persistent
+# compile cache, so two
 # programs that differ in their scopes alone share one cache entry and the
 # later one runs with the earlier one's names; the program's own name IS in
 # the key, so the jitted grow functions carry the revision in theirs.
-SCOPE_REVISION = 2
+SCOPE_REVISION = 3
 
 
 def scoped_program_name(fn):
     """``fn`` renamed ``<its name>_s<SCOPE_REVISION>``: what ``jax.jit``
-    names the program after (``jit_grow_tree_s2``).  ``grow_tree`` stays in
+    names the program after (``jit_grow_tree_s3``).  ``grow_tree`` stays in
     the name: the benchmark finds the program by that substring."""
     fn.__name__ = fn.__qualname__ = f"{fn.__name__}_s{SCOPE_REVISION}"
     return fn
@@ -495,11 +513,12 @@ def route_goes_left(binf, meta: FeatureMeta, feat, thr, dleft,
 
 def bin_flags(flags, binf):
     """``flags[binf]`` for a per-bin ``bool[B]`` table and in-range bins of
-    any shape, without a gather per element: the table packed into ``B / 32``
-    words, the word picked by a chain of selects, the bit by a shift.  The
-    partition routes all N rows of the split column, and on the v5e the
-    gather costs 7.8 ns a row even from 255 entries (82.1 ms a split at
-    10.5M rows, against 0.24 for this chain of 8: PERF.md section 5)."""
+    any shape, without a gather per element: the table packed into
+    ``ceil(B / 32)`` words, the word picked by a chain of selects, the bit by
+    a shift.  The partition routes all N rows of the split column, and on
+    the v5e the gather costs 7.8 ns a row even from 255 entries (82.1 ms a
+    split at 10.5M rows, against 0.24 for the chain of 8 words that 255
+    bins take: PERF.md section 5); 279 bins take a chain of 9."""
     nb = flags.shape[0]
     nw = -(-nb // 32)
     bits = jnp.pad(flags, (0, 32 * nw - nb)).reshape(nw, 32)

@@ -372,8 +372,8 @@ def predict_hbm(rows: int, features: int, bins: int = 255, leaves: int = 31,
     d = max(int(data_shards), 1)
     fs = max(int(feature_shards), 1)
     rows_d = -(-rows // d)                  # rows per data shard (ceil)
-    if bin_bytes is None:
-        bin_bytes = 1 if bins < 256 else 2
+    if bin_bytes is None:           # the binned matrix's dtype (dataset.py)
+        bin_bytes = 1 if bins <= 256 else 2
     # the largest window of the grower's own table (lazy: grower imports obs)
     from ..grower import (GrowerConfig, _bucket_sizes, _order_tail,
                           _partition_sizes)

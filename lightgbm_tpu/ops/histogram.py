@@ -13,6 +13,8 @@ split is proportional to the smaller child, not to the dataset:
   rung: the row gather happens INSIDE the kernel (per-tile DMA of indexed
   panel rows into VMEM) and the contraction is nibble-factorized, so
   neither the gathered [M, F] matrix nor the one-hot ever exists in HBM.
+  It serves every width up to ``pallas_hist.FUSED_MAX_BINS`` (512, uint16
+  bins past 256).
   Takes the leaf's ``order`` window + offset, not gathered rows.
   ``subset_histogram_fused_local`` is the same rung entered from inside
   the GSPMD shard_map island (per-shard row -> leaf partition instead of
@@ -200,13 +202,16 @@ def subset_histogram_fused(order: jnp.ndarray, panel: jnp.ndarray,
     (data/packing.py:pack_fused_panel) -> [n_cols, num_bins, 3] f32 with
     the reference (sum_grad, sum_hess, count) layout; gradients/hessians
     carry the bf16 hi/lo accuracy contract (counts exact)."""
-    from .pallas_hist import hist6_fused
+    from .pallas_hist import fused_hi, hist6_fused
     # dispatch-identity evidence (trace-time, per call site): bench rungs
-    # and decide_flips verify the label against this counter
+    # and decide_flips verify the label against this counter; ``width`` is
+    # the histogram width the kernel was built at, ``hi`` its hi one-hot's
+    # height (16 up to 256 bins)
     obs_counters.inc("hist_dispatch", method="fused", site=site,
                      interpret=bool(interpret),
                      col_tiles=panel.shape[0],
-                     fetch="block" if contiguous else "rows")
+                     fetch="block" if contiguous else "rows",
+                     width=num_bins, hi=fused_hi(num_bins))
     _maybe_inject_hist_fault("fused", site)
     h6 = hist6_fused(order, panel, start, cnt, n_cols, words_per, num_bins,
                      row_tile=row_tile, num_row_tiles=num_row_tiles,
@@ -228,13 +233,14 @@ def subset_histogram_fused_local(row_leaf: jnp.ndarray, leaf_id,
     Returns the [n_cols, num_bins, 3] PARTIAL histogram over this shard's
     rows matching ``leaf_id``; the caller (parallel/gspmd.py) hands the
     cross-shard reduction to the SPMD partitioner."""
-    from .pallas_hist import hist6_fused_local
+    from .pallas_hist import fused_hi, hist6_fused_local
     # dispatch-identity evidence: under shard_map this traces once for the
     # whole mesh, same as any other trace-time counter — observed_kernel()
     # and the census must still attribute the hybrid to the fused kernel
     obs_counters.inc("hist_dispatch", method="fused", site=site,
                      interpret=bool(interpret),
-                     col_tiles=panel.shape[0], fetch="rows")
+                     col_tiles=panel.shape[0], fetch="rows",
+                     width=num_bins, hi=fused_hi(num_bins))
     _maybe_inject_hist_fault("fused", site)
     h6 = hist6_fused_local(row_leaf, leaf_id, panel, n_cols, words_per,
                            num_bins, row_tile=row_tile, interpret=interpret)

@@ -37,7 +37,19 @@ from ..data.packing import FUSED_COL_STEP, fused_col_tiles
 
 NUM_CH = 6   # weight channels: (g_hi, g_lo, h_hi, h_lo, c, unused)
 LANES = 128  # TPU vector register lane width — bin axis is padded to this
-NIB = 16     # nibble radix: bin = hi*16 + lo, each one-hot 16 wide
+NIB = 16     # nibble radix: bin = hi*16 + lo, the lo one-hot 16 wide
+FUSED_MAX_BINS = 512   # widest histogram the kernel serves (uint16 bins)
+HI_ALIGN = 8           # the hi one-hot past 256 bins: whole f32 sublane tiles
+
+
+def fused_hi(num_bins: int) -> int:
+    """Rows of the hi one-hot for a histogram ``num_bins`` wide: 16 up to
+    256 bins (the two-nibble form every layout of 256 or fewer bins has
+    always traced), past that ceil(num_bins / 16) rounded up to whole f32
+    sublane tiles (279 bins: 18 -> 24; 512: 32)."""
+    if num_bins <= NIB * NIB:
+        return NIB
+    return -(-(-(-num_bins // NIB)) // HI_ALIGN) * HI_ALIGN
 
 
 # ---------------------------------------------------------------------------
@@ -53,8 +65,21 @@ NIB = 16     # nibble radix: bin = hi*16 + lo, each one-hot 16 wide
 # DMAd into SMEM and each indexed panel row is DMAd from HBM straight into
 # VMEM, so the gathered [M, F] matrix never exists in HBM and the separate
 # gather dispatch disappears — and the contraction is the nibble-factorized
-# form (bin = hi*16 + lo, M = ch x hi = 96 rows, 16-wide lo one-hot) that
-# cuts the MXU slot cost ~2x at B_pad = 256.
+# form (bin = hi*16 + lo, M = NUM_CH x HI rows, 16-wide lo one-hot) that
+# cuts the MXU slot cost ~2x against a one-hot as wide as the histogram.
+#
+# HI, the hi one-hot's height, is static per layout (``fused_hi``): 16 up
+# to 256 bins, so M = 96 and the program is the one every uint8 layout has
+# always traced; past 256 bins (uint16 bins, two 16-bit bins a word) it is
+# ceil(width / 16) rounded up to whole f32 sublane tiles of 8, up to 512
+# bins (HI = 32, M = 192).  Only M grows with the width.  The 16-lane lo
+# one-hot, the FUSED_GROUP of 8 features to a 128-lane output group and the
+# step of 4 groups stay: they fix the output's lane layout (a group is 8 x
+# 16 lo bins = 128 lanes, whatever HI is), and widening the lo side instead
+# would break that 128-lane group for every width.  A taller M costs the MXU
+# M / 96 of today's pushes a feature and row tile (1.5x at 279 bins, where
+# HI = 24 holds 18 live rows in 24: the padding to 8 keeps every channel
+# band of the concatenated [M, TR] weight operand on whole tiles).
 #
 # What the v5e read (scripts/probe_hist_fetch.py, PR 28; PERF.md section
 # 5): a row of 28 columns is 4.9 ns of arithmetic, and before PR 28 it was
@@ -85,13 +110,14 @@ NIB = 16     # nibble radix: bin = hi*16 + lo, each one-hot 16 wide
 # * the width is a LOOP, not an unroll: a column tile is walked in steps of
 #   32 columns (``fori_loop``; one step inline for a narrow data set), each
 #   step reading its 8 or 16 word rows of the transposed tile at a dynamic
-#   sublane offset and adding into its own [96, 512] slab of the output
-#   block, so neither the program nor its VMEM stack grows with the column
-#   count (unrolled, 256 columns already asked for 22.5 MB of the 16 MB a
+#   sublane offset and adding into its own [NUM_CH x HI, 512] slab of the
+#   output block, so neither the program nor its VMEM stack grows with the
+#   column count (unrolled, 256 columns already asked for 22.5 MB of the 16 MB a
 #   kernel gets, and 2000 would compile for minutes: v5e AOT probe, PR 27).
 #   Both one-hots are built [16, TR] and the dot contracts the row axis of
 #   both operands: a step that indexes columns dynamically has no static
-#   [TR, 1] lane slice to make a column-shaped lo one-hot from;
+#   [TR, 1] lane slice to make a column-shaped lo one-hot from (the hi
+#   one-hot is [HI, TR]);
 # * the grid is 1-D over row tiles and may be DYNAMIC (a traced tile
 #   count): the grower passes ceil(cnt / row_tile), so a small leaf costs
 #   a small grid — this is what retires the gather-bucket ``lax.switch``
@@ -101,10 +127,10 @@ NIB = 16     # nibble radix: bin = hi*16 + lo, each one-hot 16 wide
 #   anywhere downstream.
 #
 # Mosaic surfaces kept deliberately boring (round-2/round-5 lessons): the
-# output block is written in whole [96, 512] slabs of four 128-lane groups
-# (8 features x 16 lo bins each), indexed on their leading axis — never a
-# sub-lane-width partial store, never a dynamic lane offset — and every
-# reshape happens outside the kernel in XLA.
+# output block is written in whole [NUM_CH x HI, 512] slabs of four
+# 128-lane groups (8 features x 16 lo bins each), indexed on their leading
+# axis — never a sub-lane-width partial store, never a dynamic lane offset —
+# and every reshape happens outside the kernel in XLA.
 # ---------------------------------------------------------------------------
 
 FUSED_GROUP = 8        # features per 128-lane output group (8 * NIB = 128)
@@ -151,7 +177,7 @@ def _loop(n: int, body):
 
 def _accumulate(rows_ref, words_vmem, out_ref, *, tile_words: int,
                 words_per: int, tile_steps: int, col_tiles: int,
-                row_tile: int):
+                row_tile: int, hi: int):
     """Add one fetched row tile, ``rows_ref`` [col_tiles, row_tile, 128]
     u32 in VMEM, into the output block: the kernel's arithmetic, whatever
     brought the rows."""
@@ -160,6 +186,8 @@ def _accumulate(rows_ref, words_vmem, out_ref, *, tile_words: int,
     wmask = jnp.uint32((1 << shift) - 1)
     step_words = FUSED_COL_STEP // words_per
     nib_iota = lax.broadcasted_iota(jnp.int32, (NIB, tr), 0)
+    hi_iota = (nib_iota if hi == NIB
+               else lax.broadcasted_iota(jnp.int32, (hi, tr), 0))
 
     def _tile(t):
         # word rows on the sublane axis (same orientation trick as the
@@ -179,7 +207,7 @@ def _accumulate(rows_ref, words_vmem, out_ref, *, tile_words: int,
         # integer round-to-nearest-even on the raw bits (bit-identical to
         # an f32->bf16->f32 round-trip), everything stays f32 through the
         # broadcasts, and the one cast to bf16 happens on the full
-        # [96, TR] tile right before the MXU.
+        # [NUM_CH x HI, TR] tile right before the MXU.
         chans32 = []
         for k in range(2):
             wf = lax.bitcast_convert_type(words_vmem[tile_words + k],
@@ -191,10 +219,10 @@ def _accumulate(rows_ref, words_vmem, out_ref, *, tile_words: int,
         chans32.append(jnp.zeros_like(chans32[-1]))
         # U's weight factor, feature-independent, built once per row and
         # column tile — strictly 2-D f32: each channel row broadcast to
-        # its 16-row band
+        # its HI-row band
         w_rep = jnp.concatenate(
-            [jnp.broadcast_to(ch[None, :], (NIB, tr)) for ch in chans32],
-            axis=0)                                  # [96, TR] f32
+            [jnp.broadcast_to(ch[None, :], (hi, tr)) for ch in chans32],
+            axis=0)                                  # [NUM_CH x HI, TR] f32
 
         def _step(s):
             w0 = s * step_words
@@ -208,13 +236,13 @@ def _accumulate(rows_ref, words_vmem, out_ref, *, tile_words: int,
                     sh = (c % words_per) * shift
                     binc = ((words[c // words_per] >> sh)
                             & wmask).astype(jnp.int32)   # [TR]
-                    oh_hi = ((binc >> 4)[None, :] == nib_iota
-                             ).astype(jnp.float32)       # [16, TR]
+                    oh_hi = ((binc >> 4)[None, :] == hi_iota
+                             ).astype(jnp.float32)       # [HI, TR]
                     # masked weights in f32, ONE full-tile bf16 cast before
                     # the dot (oh is 0/1, so bf16(w * oh) == bf16(w) * oh
                     # exactly)
                     u = (w_rep * jnp.concatenate([oh_hi] * NUM_CH, axis=0)
-                         ).astype(jnp.bfloat16)          # [96, TR]
+                         ).astype(jnp.bfloat16)          # [NUM_CH x HI, TR]
                     # the lo one-hot in the same [16, TR] orientation: the
                     # dot contracts the row axis of both operands
                     oh_lo = ((binc & 15)[None, :] == nib_iota
@@ -224,7 +252,7 @@ def _accumulate(rows_ref, words_vmem, out_ref, *, tile_words: int,
                         preferred_element_type=jnp.float32))
                 # one concatenated 128-lane group — the masked sub-lane
                 # partial stores Mosaic has mislowered never happen
-                groups.append(jnp.concatenate(blocks, axis=1))   # [96, 128]
+                groups.append(jnp.concatenate(blocks, axis=1))  # [M, 128]
             out_ref[t * tile_steps + s] += jnp.concatenate(groups, axis=1)
 
         _loop(tile_steps, _step)
@@ -236,7 +264,7 @@ def _hist_kernel_fused(sc_ref, order_ref, panel_ref, out_ref,
                        idx_smem, rows_vmem, words_vmem, idx_sem, row_sem, *,
                        sentinel: int, contiguous: bool, tile_words: int,
                        words_per: int, tile_steps: int, col_tiles: int,
-                       row_tile: int):
+                       row_tile: int, hi: int):
     ri = pl.program_id(0)
     slot = ri % 2
     start = sc_ref[0]
@@ -308,7 +336,7 @@ def _hist_kernel_fused(sc_ref, order_ref, panel_ref, out_ref,
 
     _accumulate(rows_ref, words_vmem, out_ref, tile_words=tile_words,
                 words_per=words_per, tile_steps=tile_steps,
-                col_tiles=col_tiles, row_tile=row_tile)
+                col_tiles=col_tiles, row_tile=row_tile, hi=hi)
 
 
 def hist6_fused(order: jnp.ndarray, panel: jnp.ndarray, start, cnt,
@@ -337,7 +365,10 @@ def hist6_fused(order: jnp.ndarray, panel: jnp.ndarray, start, cnt,
     ``cnt`` to ``num_row_tiles * row_tile`` is a sentinel row
     (``pack_fused_panel(..., row_multiple=row_tile)`` pads so).
     """
-    assert 1 < num_bins <= NIB * NIB, num_bins
+    assert 1 < num_bins <= FUSED_MAX_BINS, num_bins
+    hi = fused_hi(num_bins)
+    # a bin is read through a word's lane of 32 // words_per bits
+    assert num_bins <= 1 << (32 // words_per), (num_bins, words_per)
     assert row_tile % ISSUE_UNROLL == 0, row_tile
     assert order.shape[0] >= fused_idx_fetch(row_tile), order.shape
     col_tiles, tile_cols = fused_col_tiles(n_cols, words_per)
@@ -352,7 +383,7 @@ def hist6_fused(order: jnp.ndarray, panel: jnp.ndarray, start, cnt,
                 num_row_tiles, row_tile, panel.shape)
     sc = jnp.stack([jnp.asarray(start, jnp.int32),
                     jnp.asarray(cnt, jnp.int32)])
-    out_shape = (col_tiles * tile_steps, NUM_CH * NIB, STEP_LANES)
+    out_shape = (col_tiles * tile_steps, NUM_CH * hi, STEP_LANES)
     # the output block stays in VMEM over the row grid (twice: Pallas
     # double-buffers it) beside the two slots of panel rows and a tile's
     # transpose; past the compiler's default the kernel asks for what it
@@ -366,7 +397,7 @@ def hist6_fused(order: jnp.ndarray, panel: jnp.ndarray, start, cnt,
                           contiguous=contiguous,
                           tile_words=tile_cols // words_per,
                           words_per=words_per, tile_steps=tile_steps,
-                          col_tiles=col_tiles, row_tile=row_tile),
+                          col_tiles=col_tiles, row_tile=row_tile, hi=hi),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(num_row_tiles,),
@@ -389,11 +420,12 @@ def hist6_fused(order: jnp.ndarray, panel: jnp.ndarray, start, cnt,
     )(sc, order, panel)
     # [step, (ch, hi), (f, lo)] -> [ch, step * f, hi*16+lo], all in XLA (the
     # epilogue the retired gen-1 nibble form used, a step at a time); the
-    # phantom columns of the even spread drop out here
-    out5 = out3d.reshape(col_tiles * tile_steps, NUM_CH, NIB,
+    # phantom columns of the even spread and the bins past the width drop
+    # out here
+    out5 = out3d.reshape(col_tiles * tile_steps, NUM_CH, hi,
                          FUSED_COL_STEP, NIB)
     return out5.transpose(1, 0, 3, 2, 4).reshape(
-        NUM_CH, col_tiles * tile_cols, NIB * NIB)[:, :n_cols, :num_bins]
+        NUM_CH, col_tiles * tile_cols, hi * NIB)[:, :n_cols, :num_bins]
 
 
 def hist6_fused_local(row_leaf: jnp.ndarray, leaf_id, panel: jnp.ndarray,

@@ -200,136 +200,141 @@ def _categorical_candidates(hist, parent_g, parent_h, parent_c,
     order [F, B], used_bin [F]) with candidate order: dir=+1 ascending i,
     then dir=-1 ascending i (the reference's dirs = {1, -1} loop).
     """
-    dtype = hist.dtype
-    f, b, _ = hist.shape
-    T = min(int(cfg.max_cat_threshold), b)
-    g = hist[:, :, 0]
-    h = hist[:, :, 1]
-    nb = num_bin                                  # [F]
-    # used_bin = num_bin - 1 + (missing == None): the overflow/NaN bin is
-    # excluded from the scan unless the mapper saw every category
-    used_bin = nb - 1 + (missing_type == MISSING_NONE).astype(jnp.int32)
+    # the sort, the prefix sums and the max_cat_group scan, under a scope of
+    # their own inside ``split_find``: a trace reads their device time by it
+    with jax.named_scope("cat_scan"):
+        dtype = hist.dtype
+        f, b, _ = hist.shape
+        T = min(int(cfg.max_cat_threshold), b)
+        g = hist[:, :, 0]
+        h = hist[:, :, 1]
+        nb = num_bin                                  # [F]
+        # used_bin = num_bin - 1 + (missing == None): the overflow/NaN bin is
+        # excluded from the scan unless the mapper saw every category
+        used_bin = nb - 1 + (missing_type == MISSING_NONE).astype(jnp.int32)
 
-    l1 = jnp.asarray(cfg.lambda_l1, dtype)
-    l2 = jnp.asarray(cfg.lambda_l2, dtype)
-    min_data = jnp.asarray(cfg.min_data_in_leaf, dtype)
-    min_hess = jnp.asarray(cfg.min_sum_hessian_in_leaf, dtype)
+        l1 = jnp.asarray(cfg.lambda_l1, dtype)
+        l2 = jnp.asarray(cfg.lambda_l2, dtype)
+        min_data = jnp.asarray(cfg.min_data_in_leaf, dtype)
+        min_hess = jnp.asarray(cfg.min_sum_hessian_in_leaf, dtype)
 
-    pg = jnp.broadcast_to(jnp.asarray(parent_g, dtype), (f, 1))[:, 0] \
-        if jnp.ndim(parent_g) else jnp.full((f,), parent_g, dtype)
-    ph = jnp.broadcast_to(jnp.asarray(parent_h, dtype), (f, 1))[:, 0] \
-        if jnp.ndim(parent_h) else jnp.full((f,), parent_h, dtype)
-    pc = jnp.broadcast_to(jnp.asarray(parent_c, dtype), (f, 1))[:, 0] \
-        if jnp.ndim(parent_c) else jnp.full((f,), parent_c, dtype)
-    tot_h = ph + 2.0 * K_EPSILON
-    gain_shift = leaf_split_gain(pg, tot_h, l1, l2)
-    min_gain_shift = gain_shift + cfg.min_gain_to_split      # [F]
+        pg = jnp.broadcast_to(jnp.asarray(parent_g, dtype), (f, 1))[:, 0] \
+            if jnp.ndim(parent_g) else jnp.full((f,), parent_g, dtype)
+        ph = jnp.broadcast_to(jnp.asarray(parent_h, dtype), (f, 1))[:, 0] \
+            if jnp.ndim(parent_h) else jnp.full((f,), parent_h, dtype)
+        pc = jnp.broadcast_to(jnp.asarray(parent_c, dtype), (f, 1))[:, 0] \
+            if jnp.ndim(parent_c) else jnp.full((f,), parent_c, dtype)
+        tot_h = ph + 2.0 * K_EPSILON
+        gain_shift = leaf_split_gain(pg, tot_h, l1, l2)
+        min_gain_shift = gain_shift + cfg.min_gain_to_split      # [F]
 
-    # smoothing (feature_histogram.hpp:122-126)
-    smooth_hess = jnp.minimum(
-        cfg.max_cat_smooth,
-        jnp.maximum(cfg.cat_smooth_ratio * pc / jnp.maximum(nb, 1),
-                    cfg.min_cat_smooth))
-    smooth_grad = smooth_hess * pg / jnp.where(ph == 0, 1.0, ph)
+        # smoothing (feature_histogram.hpp:122-126)
+        smooth_hess = jnp.minimum(
+            cfg.max_cat_smooth,
+            jnp.maximum(cfg.cat_smooth_ratio * pc / jnp.maximum(nb, 1),
+                        cfg.min_cat_smooth))
+        smooth_grad = smooth_hess * pg / jnp.where(ph == 0, 1.0, ph)
 
-    bins_iota = lax.broadcasted_iota(jnp.int32, (f, b), 1)
-    in_scan = bins_iota < used_bin[:, None]
-    key = (g + smooth_grad[:, None]) / (h + smooth_hess[:, None])
-    key = jnp.where(in_scan, key, jnp.inf)        # invalid bins sort last
-    order = jnp.argsort(key, axis=1)              # [F, B] bin ids, ascending
+        bins_iota = lax.broadcasted_iota(jnp.int32, (f, b), 1)
+        in_scan = bins_iota < used_bin[:, None]
+        key = (g + smooth_grad[:, None]) / (h + smooth_hess[:, None])
+        key = jnp.where(in_scan, key, jnp.inf)        # invalid bins sort last
+        order = jnp.argsort(key, axis=1)      # [F, B] bin ids, ascending
 
-    # channel-stacked: ONE sorted gather / cumsum / prefix read over
-    # [F, B, 3] instead of three of each (same op-launch rationale as the
-    # numerical scan above)
-    shist = jnp.take_along_axis(hist, order[:, :, None], axis=1)
-    cs = jnp.cumsum(shist, axis=1)                # [F, B, 3]
-    last = jnp.clip(used_bin - 1, 0, b - 1)[:, None]
-    tot = jnp.take_along_axis(cs, last[:, :, None], axis=1)[:, 0]  # [F, 3]
-    tg, th_, tc = tot[:, 0], tot[:, 1], tot[:, 2]
+        # channel-stacked: ONE sorted gather / cumsum / prefix read over
+        # [F, B, 3] instead of three of each (same op-launch rationale as the
+        # numerical scan above)
+        shist = jnp.take_along_axis(hist, order[:, :, None], axis=1)
+        cs = jnp.cumsum(shist, axis=1)                # [F, B, 3]
+        last = jnp.clip(used_bin - 1, 0, b - 1)[:, None]
+        tot = jnp.take_along_axis(cs, last[:, :, None], axis=1)[:, 0]  # [F, 3]
+        tg, th_, tc = tot[:, 0], tot[:, 1], tot[:, 2]
 
-    pos = jnp.arange(T, dtype=jnp.int32)[None, :]            # [1, T]
-    # dir=+1: prefix of the sorted order
-    take_p1 = jnp.minimum(pos, b - 1)
-    pre_p1 = jnp.take_along_axis(cs, take_p1[:, :, None], axis=1)  # [F, T, 3]
-    lg_p1 = pre_p1[:, :, 0]
-    lh_p1 = pre_p1[:, :, 1]
-    lc_p1 = pre_p1[:, :, 2]
-    csc_sorted_c = jnp.take_along_axis(shist[:, :, 2], take_p1, axis=1)
-    # dir=-1: prefix of the reversed order = totals minus cumsum at ub-2-i
-    idx_m1 = used_bin[:, None] - 2 - pos                     # may be < 0
-    clip_m1 = jnp.clip(idx_m1, 0, b - 1)
-    pre_m1 = jnp.where((idx_m1 >= 0)[:, :, None],
-                       jnp.take_along_axis(cs, clip_m1[:, :, None], axis=1),
-                       0.0)                                  # [F, T, 3]
-    lg_m1 = tg[:, None] - pre_m1[:, :, 0]
-    lh_m1 = th_[:, None] - pre_m1[:, :, 1]
-    lc_m1 = tc[:, None] - pre_m1[:, :, 2]
-    step_m1 = jnp.clip(used_bin[:, None] - 1 - pos, 0, b - 1)
-    sc_m1 = jnp.take_along_axis(shist[:, :, 2], step_m1, axis=1)
+        pos = jnp.arange(T, dtype=jnp.int32)[None, :]            # [1, T]
+        # dir=+1: prefix of the sorted order
+        take_p1 = jnp.minimum(pos, b - 1)
+        pre_p1 = jnp.take_along_axis(cs, take_p1[:, :, None],
+                                     axis=1)                # [F, T, 3]
+        lg_p1 = pre_p1[:, :, 0]
+        lh_p1 = pre_p1[:, :, 1]
+        lc_p1 = pre_p1[:, :, 2]
+        csc_sorted_c = jnp.take_along_axis(shist[:, :, 2], take_p1, axis=1)
+        # dir=-1: prefix of the reversed order = totals minus cumsum at ub-2-i
+        idx_m1 = used_bin[:, None] - 2 - pos                     # may be < 0
+        clip_m1 = jnp.clip(idx_m1, 0, b - 1)
+        pre_m1 = jnp.where((idx_m1 >= 0)[:, :, None],
+                           jnp.take_along_axis(cs, clip_m1[:, :, None],
+                                               axis=1),
+                           0.0)                                  # [F, T, 3]
+        lg_m1 = tg[:, None] - pre_m1[:, :, 0]
+        lh_m1 = th_[:, None] - pre_m1[:, :, 1]
+        lc_m1 = tc[:, None] - pre_m1[:, :, 2]
+        step_m1 = jnp.clip(used_bin[:, None] - 1 - pos, 0, b - 1)
+        sc_m1 = jnp.take_along_axis(shist[:, :, 2], step_m1, axis=1)
 
-    # dir=-1 skipped when full-categorical and 2*max_cat_threshold covers all
-    # bins (feature_histogram.hpp:134-138)
-    dir_m1_on = ~((missing_type == MISSING_NONE)
-                  & (2 * cfg.max_cat_threshold >= nb))
+        # dir=-1 skipped when full-categorical and 2*max_cat_threshold covers
+        # all bins (feature_histogram.hpp:134-138)
+        dir_m1_on = ~((missing_type == MISSING_NONE)
+                      & (2 * cfg.max_cat_threshold >= nb))
 
-    cat_ok = feat_valid & is_cat                             # [F]
-    base_valid = cat_ok[:, None] & (pos < used_bin[:, None]) # [F, T]
+        cat_ok = feat_valid & is_cat                             # [F]
+        base_valid = cat_ok[:, None] & (pos < used_bin[:, None]) # [F, T]
 
-    def stack2(p1, m1):                                      # → [F, 2, T]
-        return jnp.stack([p1, m1], axis=1)
+        def stack2(p1, m1):                                  # → [F, 2, T]
+            return jnp.stack([p1, m1], axis=1)
 
-    lg2 = stack2(lg_p1, lg_m1)
-    lh2 = stack2(lh_p1, lh_m1) + K_EPSILON
-    lc2 = stack2(lc_p1, lc_m1)
-    step_c = stack2(csc_sorted_c, sc_m1)
-    valid2 = stack2(base_valid, base_valid & dir_m1_on[:, None])
+        lg2 = stack2(lg_p1, lg_m1)
+        lh2 = stack2(lh_p1, lh_m1) + K_EPSILON
+        lc2 = stack2(lc_p1, lc_m1)
+        step_c = stack2(csc_sorted_c, sc_m1)
+        valid2 = stack2(base_valid, base_valid & dir_m1_on[:, None])
 
-    rg2 = pg[:, None, None] - lg2
-    rh2 = tot_h[:, None, None] - lh2
-    rc2 = pc[:, None, None] - lc2
-    cont_ok = (lc2 >= min_data) & (lh2 >= min_hess)
-    right_ok = (rc2 >= min_data) & (rh2 >= min_hess)
+        rg2 = pg[:, None, None] - lg2
+        rh2 = tot_h[:, None, None] - lh2
+        rc2 = pc[:, None, None] - lc2
+        cont_ok = (lc2 >= min_data) & (lh2 >= min_hess)
+        right_ok = (rc2 >= min_data) & (rh2 >= min_hess)
 
-    # max_cat_group gating: sequential accounting over candidate positions
-    # (feature_histogram.hpp:142-147,169-177) — a T-step scan over [F, 2]
-    rest0 = jnp.full((f, 2), cfg.max_cat_group, dtype)
-    mdpg0 = jnp.maximum(1.0, jnp.floor(pc / cfg.max_cat_group))[:, None] \
-        * jnp.ones((1, 2), dtype)
-    cnt0 = jnp.zeros((f, 2), dtype)
+        # max_cat_group gating: sequential accounting over candidate positions
+        # (feature_histogram.hpp:142-147,169-177) — a T-step scan over [F, 2]
+        rest0 = jnp.full((f, 2), cfg.max_cat_group, dtype)
+        mdpg0 = jnp.maximum(1.0, jnp.floor(pc / cfg.max_cat_group))[:, None] \
+            * jnp.ones((1, 2), dtype)
+        cnt0 = jnp.zeros((f, 2), dtype)
 
-    def group_step(state, xs):
-        cnt, rest, mdpg = state
-        step_cnt, cont, rok, rcnt = xs
-        cnt = cnt + step_cnt
-        accept = cont & rok & (cnt >= mdpg)
-        new_rest = jnp.where(accept, rest - 1.0, rest)
-        new_mdpg = jnp.where(
-            accept & (new_rest > 0),
-            jnp.maximum(1.0, jnp.floor(rcnt / jnp.maximum(new_rest, 1.0))),
-            mdpg)
-        new_cnt = jnp.where(accept, 0.0, cnt)
-        return (new_cnt, new_rest, new_mdpg), accept
+        def group_step(state, xs):
+            cnt, rest, mdpg = state
+            step_cnt, cont, rok, rcnt = xs
+            cnt = cnt + step_cnt
+            accept = cont & rok & (cnt >= mdpg)
+            new_rest = jnp.where(accept, rest - 1.0, rest)
+            new_mdpg = jnp.where(
+                accept & (new_rest > 0),
+                jnp.maximum(1.0, jnp.floor(rcnt / jnp.maximum(new_rest, 1.0))),
+                mdpg)
+            new_cnt = jnp.where(accept, 0.0, cnt)
+            return (new_cnt, new_rest, new_mdpg), accept
 
-    xs = (jnp.moveaxis(step_c, 2, 0), jnp.moveaxis(cont_ok, 2, 0),
-          jnp.moveaxis(right_ok, 2, 0), jnp.moveaxis(rc2, 2, 0))
-    _, accepts = lax.scan(group_step, (cnt0, rest0, mdpg0), xs)
-    accept2 = jnp.moveaxis(accepts, 0, 2)                    # [F, 2, T]
+        xs = (jnp.moveaxis(step_c, 2, 0), jnp.moveaxis(cont_ok, 2, 0),
+              jnp.moveaxis(right_ok, 2, 0), jnp.moveaxis(rc2, 2, 0))
+        _, accepts = lax.scan(group_step, (cnt0, rest0, mdpg0), xs)
+        accept2 = jnp.moveaxis(accepts, 0, 2)                    # [F, 2, T]
 
-    gain2 = (leaf_split_gain(lg2, lh2, l1, l2)
-             + leaf_split_gain(rg2, rh2, l1, l2))
-    ok = valid2 & cont_ok & right_ok & accept2 \
-        & (gain2 > min_gain_shift[:, None, None])
-    gain2 = jnp.where(ok, gain2, -jnp.inf)
+        gain2 = (leaf_split_gain(lg2, lh2, l1, l2)
+                 + leaf_split_gain(rg2, rh2, l1, l2))
+        ok = valid2 & cont_ok & right_ok & accept2 \
+            & (gain2 > min_gain_shift[:, None, None])
+        gain2 = jnp.where(ok, gain2, -jnp.inf)
 
-    def flat(a):                                             # [F, 2, T] → [F, 2T]
-        return a.reshape(f, 2 * T)
+        def flat(a):                                   # [F, 2, T] → [F, 2T]
+            return a.reshape(f, 2 * T)
 
-    pos2 = jnp.broadcast_to(pos[None, :, :], (f, 2, T))
-    is_p1 = jnp.broadcast_to(
-        jnp.asarray([True, False])[None, :, None], (f, 2, T))
-    return (flat(gain2), flat(lg2), flat(lh2), flat(lc2),
-            flat(pos2), flat(is_p1), order, used_bin, min_gain_shift, tot_h,
-            l1, l2)
+        pos2 = jnp.broadcast_to(pos[None, :, :], (f, 2, T))
+        is_p1 = jnp.broadcast_to(
+            jnp.asarray([True, False])[None, :, None], (f, 2, T))
+        return (flat(gain2), flat(lg2), flat(lh2), flat(lc2),
+                flat(pos2), flat(is_p1), order, used_bin, min_gain_shift,
+                tot_h, l1, l2)
 
 
 class FusedSplitCtx(NamedTuple):

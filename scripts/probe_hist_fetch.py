@@ -157,7 +157,8 @@ def probe_hist(form, order, panel, start, cnt, n_cols, num_row_tiles,
     tile_steps = tile_cols // FUSED_COL_STEP
     acc = functools.partial(ph._accumulate, tile_words=tile_cols // 4,
                             words_per=4, tile_steps=tile_steps,
-                            col_tiles=col_tiles, row_tile=row_tile)
+                            col_tiles=col_tiles, row_tile=row_tile,
+                            hi=ph.NIB)     # uint8 bins: the 16-row hi one-hot
     out_shape = (col_tiles * tile_steps, ph.NUM_CH * ph.NIB, ph.STEP_LANES)
     held = (2 * 4 * out_shape[0] * out_shape[1] * out_shape[2]
             + (slots * col_tiles + 1) * row_tile * ph.LANES * 4)

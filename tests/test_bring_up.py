@@ -96,18 +96,22 @@ def test_wide_layout_resolves_to_the_fused_kernel(monkeypatch):
                     verbose_eval=False)
     assert bst.inner.grower_cfg.hist_method == "fused"
     assert not counters.events("layout_downgrade")
+    width = bst.inner.grower_cfg.max_bin
     assert set(counters.get("hist_dispatch")) == {
-        f"col_tiles=2,fetch={fetch},interpret=True,method=fused,site={s}"
-        for s, fetch in (("root", "block"), ("split", "rows"))}
+        f"col_tiles=2,fetch={fetch},hi=16,interpret=True,method=fused,"
+        f"site={s},width={width}" for s, fetch in (("root", "block"),
+                                                   ("split", "rows"))}
     assert bst.inner.models[0].num_leaves > 1
 
 
 @pytest.mark.parametrize("chip,use_pallas,cpu_method,bins,weights,width,want,why", [
     # the chip's default layout: the fused kernel, nothing to say
     (True, True, "segment", "uint8", "float32", 255, "fused", None),
-    # ROADMAP M2: 300 bins do not factor into two nibbles
-    (True, True, "segment", "uint16", "float32", 300, "einsum",
-     "exceeds the nibble-factorized limit 256"),
+    # ROADMAP M2: 300 bins are uint16 bins and a taller hi one-hot
+    (True, True, "segment", "uint16", "float32", 300, "fused", None),
+    # past the kernel's widest hi one-hot
+    (True, True, "segment", "uint16", "float32", 600, "einsum",
+     "exceeds the fused kernel's limit 512"),
     (True, True, "segment", "int32", "float32", 255, "einsum",
      "bin dtype int32 is wider than 2 bytes"),
     (True, True, "segment", "uint8", "float64", 255, "einsum",
@@ -118,8 +122,9 @@ def test_wide_layout_resolves_to_the_fused_kernel(monkeypatch):
     # interpreted kernel there, behind the same gate
     (False, True, "segment", "uint8", "float32", 255, "segment", None),
     (False, True, "fused", "uint8", "float32", 255, "fused", None),
-    (False, True, "fused", "uint16", "float32", 300, "segment",
-     "exceeds the nibble-factorized limit 256"),
+    (False, True, "fused", "uint16", "float32", 300, "fused", None),
+    (False, True, "fused", "uint16", "float32", 600, "segment",
+     "exceeds the fused kernel's limit 512"),
 ])
 def test_hist_method_is_resolved_in_one_table(monkeypatch, chip, use_pallas,
                                               cpu_method, bins, weights,
@@ -139,7 +144,7 @@ def test_fused_config_on_a_refused_layout_raises_the_gates_reason():
     import jax
     import jax.numpy as jnp
     from lightgbm_tpu.grower import FeatureMeta, GrowerConfig, make_grower
-    n, f, b = 256, 3, 300
+    n, f, b = 256, 3, 600
     cfg = GrowerConfig(num_leaves=4, max_bin=b, hist_method="fused",
                        hist_interpret=True)
     meta = FeatureMeta(num_bin=jnp.full((f,), b, jnp.int32),
@@ -148,13 +153,13 @@ def test_fused_config_on_a_refused_layout_raises_the_gates_reason():
                        is_categorical=jnp.zeros((f,), bool))
     one = jnp.ones((n,), jnp.float32)
     with pytest.raises(ValueError, match="hist_method=fused cannot run on "
-                       "this layout: histogram width 300 exceeds"):
+                       "this layout: histogram width 600 exceeds"):
         jax.jit(make_grower(cfg))(jnp.zeros((n, f), jnp.uint16), one, one,
                                   one, meta, jnp.ones((f,), bool))
 
 
 def test_refused_layout_trains_on_the_reference_with_one_event():
-    """300 bins through ``lgb.train`` with the fused kernel asked for: the
+    """600 bins through ``lgb.train`` with the fused kernel asked for: the
     one ``layout_downgrade`` comes from the booster's set-up, names the
     gate's reason, and ``grower_cfg.hist_method`` names what ran."""
     import lightgbm_tpu as lgb
@@ -164,7 +169,7 @@ def test_refused_layout_trains_on_the_reference_with_one_event():
     X = rng.randn(2000, 3)
     y = (X[:, 0] > 0).astype(np.float64)
     bst = lgb.train({"objective": "binary", "num_leaves": 4, "verbose": -1,
-                     "max_bin": 300, "min_data_in_bin": 1,
+                     "max_bin": 600, "min_data_in_bin": 1,
                      "cpu_hist_method": "fused"},
                     lgb.Dataset(X, label=y), num_boost_round=2,
                     verbose_eval=False)
@@ -172,7 +177,7 @@ def test_refused_layout_trains_on_the_reference_with_one_event():
     evs = counters.events("layout_downgrade")
     assert [(e["stage"], e["requested"], e["resolved"]) for e in evs] == [
         ("boosting", "fused", "segment")]
-    assert "nibble-factorized limit" in evs[0]["reason"]
+    assert "fused kernel's limit 512" in evs[0]["reason"]
     assert set(counters.get("hist_dispatch")) == {
         f"interpret=False,method=segment,site={site}"
         for site in ("root", "split")}

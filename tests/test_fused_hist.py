@@ -124,6 +124,68 @@ def test_fused_wide_bit_identical_integer_weights(f, tiles):
     np.testing.assert_array_equal(out, ref)
 
 
+@pytest.mark.parametrize("b,f,hi", [(257, 8, 24), (279, 8, 24),
+                                    (512, 12, 32), (279, 40, 24)])
+def test_fused_past_256_bins_matches_segment_oracle(b, f, hi):
+    """uint16 bins, two to a word: the hi one-hot grows to ``hi`` rows
+    (ceil(b / 16) on whole sublane tiles) and the kernel stays bit-identical
+    to the segment oracle on bf16-exact weights, every bin of the width
+    populated, over an unaligned window whose last tile runs into the
+    sentinel; 40 columns take two steps of the column loop."""
+    from lightgbm_tpu.ops.pallas_hist import fused_hi
+    assert fused_hi(b) == hi
+    n = 2048
+    bins, g, h, c = _problem(n, f, b, seed=b + f, integer_weights=True,
+                             dtype=np.uint16)
+    bins[:b, 0] = np.arange(b)          # the last bin is read, not only 0
+    panel, per = _fused_inputs(bins, g, h, c)
+    assert per == 2
+    perm = np.random.RandomState(5).permutation(n).astype(np.int32)
+    order = _order_with_tail(perm, n)
+    start, cnt = 97, 1700
+    sel = perm[start:start + cnt]
+    ref = np.asarray(subset_histogram_segment(
+        jnp.asarray(bins[sel]), jnp.asarray(g[sel]), jnp.asarray(h[sel]),
+        jnp.asarray(c[sel]), b))
+    out = np.asarray(subset_histogram_fused(
+        order, panel, start, cnt, f, per, b, row_tile=ROW_TILE,
+        num_row_tiles=-(-cnt // ROW_TILE), interpret=True))
+    assert out.shape == (f, b, 3)
+    np.testing.assert_array_equal(out, ref)
+
+
+def _kernel_jaxpr(b, f=28, per=4):
+    """The kernel body's jaxpr and its output block, as the grower traces
+    it at histogram width ``b``."""
+    import jax
+    from lightgbm_tpu.data.packing import fused_col_tiles
+    from lightgbm_tpu.ops.pallas_hist import hist6_fused
+    n = 4096
+    panel = jax.ShapeDtypeStruct((fused_col_tiles(f, per)[0], n + 1, 128),
+                                 jnp.uint32)
+    order = jax.ShapeDtypeStruct((n + fused_idx_fetch(ROW_TILE),), jnp.int32)
+    s = jax.ShapeDtypeStruct((), jnp.int32)
+    jx = jax.make_jaxpr(lambda o, p, a, c, nt: hist6_fused(
+        o, p, a, c, f, per, b, row_tile=ROW_TILE, num_row_tiles=nt))(
+            order, panel, s, s, s)
+    call = next(e for e in jx.jaxpr.eqns if e.primitive.name == "pallas_call")
+    return str(call.params["jaxpr"]), call.outvars[0].aval.shape
+
+
+def test_fused_kernel_at_256_bins_or_fewer_is_the_two_nibble_program():
+    """Every width of 256 bins or fewer traces ONE kernel program: a 16-row
+    hi one-hot, [96, 512] output slabs (6 channels x 16), whatever the
+    width; past 256 only the hi one-hot's height moves."""
+    from lightgbm_tpu.ops.pallas_hist import NUM_CH, fused_hi
+    assert {fused_hi(b) for b in (2, 16, 63, 255, 256)} == {16}
+    base, shape = _kernel_jaxpr(255)
+    assert shape == (1, NUM_CH * 16, 512)
+    for b in (63, 256):
+        assert _kernel_jaxpr(b) == (base, shape)
+    wide, wide_shape = _kernel_jaxpr(279, f=8, per=2)
+    assert wide_shape == (1, NUM_CH * 24, 512) and wide != base
+
+
 def test_fused_dynamic_grid_matches_static():
     """The grower's dynamic-grid form (traced tile count) must equal the
     static grid bin for bin."""
@@ -389,8 +451,9 @@ def test_hist_block_fetch_metric_reads_the_fetch_tag():
     bins, g, h, c = _problem(600, 4, 16, seed=3)
     _grow_tree_strings("fused", bins, g, h, c, 16, num_leaves=4)
     assert set(counters.get("hist_dispatch")) == {
-        f"col_tiles=1,fetch={fetch},interpret=True,method=fused,site={s}"
-        for s, fetch in (("root", "block"), ("split", "rows"))}
+        f"col_tiles=1,fetch={fetch},hi=16,interpret=True,method=fused,"
+        f"site={s},width=16" for s, fetch in (("root", "block"),
+                                              ("split", "rows"))}
     assert metrics.read_metric("hist_block_fetch", {}) == 1
     counters.reset()
     counters.inc("hist_dispatch", method="fused", site="root", col_tiles=1)

@@ -153,7 +153,7 @@ def test_program_name_holds_the_revision(kind, kwargs):
 
 
 def test_named_program_is_what_jax_compiles(op_names):
-    """The name reaches the module (``jit_grow_tree_s2``: in the cache's
+    """The name reaches the module (``jit_grow_tree_s3``: in the cache's
     key and in the trace's ``XLA Modules`` line) and every operation."""
     assert f"module @jit_grow_tree_s{SCOPE_REVISION} " in \
         _lowered("xla").as_text(debug_info=False)

@@ -245,10 +245,12 @@ def test_gspmd_fused_zero_recompile_across_calls():
     # one trace per mesh per site: 2 meshes x {root, split}, never 4 (the
     # 8x1 mesh runs the serial grower in its island: its root fetches the
     # identity window by block; the 2x4 island's root selects by row_leaf)
+    key = "col_tiles=1,fetch={},hi=16,interpret=True,method=fused,site={}," \
+        f"width={B}"
     assert disp == {
-        "col_tiles=1,fetch=block,interpret=True,method=fused,site=root": 1,
-        "col_tiles=1,fetch=rows,interpret=True,method=fused,site=root": 1,
-        "col_tiles=1,fetch=rows,interpret=True,method=fused,site=split": 2,
+        key.format("block", "root"): 1,
+        key.format("rows", "root"): 1,
+        key.format("rows", "split"): 2,
     }, disp
 
 

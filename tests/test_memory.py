@@ -273,3 +273,12 @@ def test_every_downgrade_warning_also_emits_a_layout_event():
         "warn-once downgrade paths without a layout_downgrade obs event "
         f"(add counters.event('layout_downgrade', ...) or exempt with a "
         f"reason): {missing}")
+
+
+@pytest.mark.parametrize("bins,bytes_a_bin", [(255, 1), (256, 1), (257, 2),
+                                              (279, 2)])
+def test_predict_hbm_counts_the_binned_matrix_at_its_dtype(bins, bytes_a_bin):
+    """Unasked, the binned matrix is counted at the dtype the data set
+    stores it in: uint8 up to 256 bins, uint16 past them."""
+    pred = obs_memory.predict_hbm(rows=1000, features=8, bins=bins)
+    assert pred["residents"]["binned"] == 1000 * 8 * bytes_a_bin

@@ -54,6 +54,8 @@ def v5e():
     ("static", 255, 28), ("dynamic", 255, 28), ("dynamic", 63, 28),
     ("dynamic", 256, 12), ("dynamic", 255, 2000), ("dynamic", 255, 513),
     ("contiguous", 255, 28), ("contiguous", 255, 2000),
+    # past 256 bins: uint16 bins two to a word, a 24-row hi one-hot
+    ("dynamic", 279, 8), ("contiguous", 279, 8), ("dynamic", 512, 8),
 ])
 def test_fused_hist_kernel_lowers(v5e, grid, num_bins, f):
     """The fused-gather kernel Mosaic-compiles for v5e: in-kernel
@@ -75,17 +77,18 @@ def test_fused_hist_kernel_lowers(v5e, grid, num_bins, f):
     # two, 2000 five: the kernel walks them in steps of 32 columns, so its
     # program does not grow with the width)
     contiguous = grid == "contiguous"
-    panel = (fused_col_tiles(f, 4)[0], n + (tr if contiguous else 1), 128)
+    per = 4 if num_bins <= 256 else 2          # uint8 or uint16 bins
+    panel = (fused_col_tiles(f, per)[0], n + (tr if contiguous else 1), 128)
     no = n + fused_idx_fetch(tr)
     if grid == "dynamic":
         fn = jax.jit(lambda o, p, s, c, nt: subset_histogram_fused(
-            o, p, s, c, f, 4, num_bins, row_tile=tr, num_row_tiles=nt))
+            o, p, s, c, f, per, num_bins, row_tile=tr, num_row_tiles=nt))
         fn.lower(v5e((no,), jnp.int32), v5e(panel, jnp.uint32),
                  v5e((), jnp.int32), v5e((), jnp.int32),
                  v5e((), jnp.int32)).compile()
     else:
         fn = jax.jit(lambda o, p, s, c: subset_histogram_fused(
-            o, p, s, c, f, 4, num_bins, row_tile=tr,
+            o, p, s, c, f, per, num_bins, row_tile=tr,
             num_row_tiles=n // tr if contiguous else 16,
             contiguous=contiguous))
         fn.lower(v5e((no,), jnp.int32), v5e(panel, jnp.uint32),
@@ -109,6 +112,30 @@ def test_fused_grower_lowers(v5e):
         is_categorical=v5e((f,), jnp.bool_))
     grow = jax.jit(make_grower(cfg))
     grow.lower(v5e((n, f), jnp.uint8), v5e((n,), jnp.float32),
+               v5e((n,), jnp.float32), v5e((n,), jnp.float32),
+               meta, v5e((f,), jnp.bool_)).compile()
+
+
+def test_categorical_fused_grower_lowers(v5e):
+    """The grower of the categorical Expo cell on the fused rung: 8
+    categorical uint16 columns whose widest keeps 279 bins, built at
+    ``layout_width``'s 288 (the kernel's 24-row hi one-hot, the
+    sort-by-ratio scan, the 9-word bitset routing) Mosaic-compiles for v5e
+    (about 22 s here at 2^17 rows)."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.grower import (FeatureMeta, GrowerConfig, layout_width,
+                                     make_grower)
+    n, f = 1 << 17, 8
+    cfg = GrowerConfig(num_leaves=255, min_data_in_leaf=1,
+                       min_sum_hessian_in_leaf=100.0,
+                       max_bin=layout_width(279),
+                       hist_method="fused", has_categorical=True)
+    meta = FeatureMeta(
+        num_bin=v5e((f,), jnp.int32), missing_type=v5e((f,), jnp.int32),
+        default_bin=v5e((f,), jnp.int32),
+        is_categorical=v5e((f,), jnp.bool_))
+    grow = jax.jit(make_grower(cfg))
+    grow.lower(v5e((n, f), jnp.uint16), v5e((n,), jnp.float32),
                v5e((n,), jnp.float32), v5e((n,), jnp.float32),
                meta, v5e((f,), jnp.bool_)).compile()
 

@@ -493,7 +493,8 @@ def test_dispatch_identity_einsum_vs_interpret_fused():
                                        interpret=True, site="t")
     assert counters.observed_kernel() == "fused"
     assert counters.get("hist_dispatch") == {
-        "col_tiles=1,fetch=rows,interpret=True,method=fused,site=t": 1}
+        "col_tiles=1,fetch=rows,hi=16,interpret=True,method=fused,site=t,"
+        "width=16": 1}
     # fused accumulates in bf16 hi/lo pairs (~f32 accuracy, not exact)
     np.testing.assert_allclose(np.asarray(h_e), np.asarray(h_f),
                                rtol=1e-3, atol=1e-4)
