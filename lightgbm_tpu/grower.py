@@ -18,7 +18,7 @@ TPU-native re-design of ``SerialTreeLearner::Train``
   a one-hot MXU matmul (Pallas kernel on TPU); the larger child is obtained
   by parent − smaller subtraction exactly like the reference
   (``serial_tree_learner.cpp:482-488``).  Per-leaf parent histograms live in
-  an HBM pool ``hist_store [L, 3 * F * B]`` — the reference's HistogramPool
+  an HBM pool ``hist_store [L, K, 128]`` — the reference's HistogramPool
   (``feature_histogram.hpp:429-597``) without the LRU, since HBM fits all
   leaves;
 * the split loop is a ``lax.while_loop`` with all per-leaf state in fixed
@@ -267,8 +267,9 @@ class _LoopState(NamedTuple):
     rl: jnp.ndarray              # [N] i32: the leaf each row is in, dense
     #                              (what the partition's dense branch keys on)
     lsc: jnp.ndarray             # [L, 2] i32: (first position, local count)
-    hist_store: jnp.ndarray      # [L, 3 * F * B]: per-leaf histograms, a
-    #                              leaf's flat (pool_flat)
+    hist_store: jnp.ndarray      # [L, K, 128]: per-leaf histograms, a
+    #                              leaf's K / 8 whole (8, 128) tiles
+    #                              (pool_flat, pool_tiles)
     feat_ok: jnp.ndarray         # [L, E] bool: per-leaf is_splittable flags
     sgain: jnp.ndarray           # [L] f32: per-leaf best gain (the heap key)
     sf32: jnp.ndarray            # [L, 8] f32 split pool: left_sum_g,
@@ -648,21 +649,72 @@ def partition_dense(order, start, cnt, rl, left_leaf, right_leaf):
     return order, nl
 
 
+POOL_TILE = (8, 128)     # the v5e's f32 tile: sublanes, lanes
+
+
+def pool_tiles(n_cols: int, num_bins: int) -> int:
+    """K, the rows of 128 lanes that hold one leaf's histogram in the pool:
+    its ``3 * n_cols * num_bins`` floats over 128, rounded up to a whole
+    number of (8, 128) tiles."""
+    sub, lanes = POOL_TILE
+    return -(-(3 * n_cols * num_bins) // (sub * lanes)) * sub
+
+
 def pool_flat(hist):
-    """[..., F, B, 3] histograms -> [..., 3 * F * B] rows of the per-leaf
-    pool, one statistic's [F, B] plane after another.  The pool is carried
-    flat so that its layout is not the compiler's to choose: as
-    ``[L, F, B, 3]`` the v5e's compiler kept it bins-minor in the split
-    loop, wanted the parent's slice features-minor for the subtraction,
-    and relaid the WHOLE pool on every split to get it (its program,
-    compiled here: a 1.56 GB copy a split at 255 x 2000 x 255).  A row of
-    a two-dimensional array can only be relaid as a row."""
-    return jnp.moveaxis(hist, -1, -3).reshape(hist.shape[:-3] + (-1,))
+    """[..., F, B, 3] histograms -> [..., K, 128] slices of the per-leaf
+    pool (K = :func:`pool_tiles`): one statistic's [F, B] plane after
+    another, the tail past ``3 * F * B`` zeros.  The pool is carried as
+    ``[L, K, 128]`` so that its layout is not the compiler's to choose and a
+    leaf is whole tiles: as ``[L, F, B, 3]`` the v5e's compiler relays the
+    WHOLE pool on every split (a 1.56 GB copy a split at 255 x 2000 x 255),
+    and as ``[L, 3 * F * B]`` rows it tiles the leaf axis as the sublanes,
+    so that one leaf is one sublane of every tile and each read or write of
+    a leaf moves eight leaves' bytes."""
+    lead = hist.shape[:-3]
+    n_cols, num_bins = hist.shape[-3:-1]
+    k = pool_tiles(n_cols, num_bins)
+    flat = jnp.moveaxis(hist, -1, -3).reshape(lead + (-1,))
+    flat = jnp.pad(flat, [(0, 0)] * len(lead)
+                   + [(0, k * POOL_TILE[1] - flat.shape[-1])])
+    return flat.reshape(lead + (k, POOL_TILE[1]))
 
 
-def pool_hist(flat, n_cols: int, num_bins: int):
-    """One leaf's :func:`pool_flat` row -> its [F, B, 3] histogram."""
-    return jnp.moveaxis(flat.reshape(3, n_cols, num_bins), 0, -1)
+def pool_hist(tiles, n_cols: int, num_bins: int):
+    """[..., K, 128] pool slices (:func:`pool_flat`) -> the [..., F, B, 3]
+    histograms, the zero tail dropped."""
+    lead = tiles.shape[:-2]
+    flat = tiles.reshape(lead + (-1,))[..., :3 * n_cols * num_bins]
+    return jnp.moveaxis(flat.reshape(lead + (3, n_cols, num_bins)), -3, -1)
+
+
+def pool_split(store, leaf, pair, hist_small):
+    """One split's work on the per-leaf pool ``store [L, K, 128]``: leaf
+    ``leaf``'s histogram read as one slice of whole tiles, the larger child
+    as the parent less the smaller ``hist_small [F, B, 3]`` (the reference's
+    subtraction, ``serial_tree_learner.cpp:482-488``), and both children
+    written to the rows ``pair`` (smaller, larger) by ONE pair scatter.
+    Returns the new store and the children's ``[2, F, B, 3]`` histograms in
+    (smaller, larger) order.
+
+    One scatter, not two ``dynamic_update_slice``s: a read-then-double-
+    update chain on the carried pool made XLA:CPU clone all of it twice a
+    split (docs/PERF.md round 7; pinned by tests/test_grow_jaxpr.py).  The
+    children are ONE buffer behind an optimization barrier, read by the
+    scan and by the write: without it XLA:CPU fuses the read and the
+    subtraction into both, the scan's copy may then run after the write,
+    and the pool is cloned every split.  Of the forms tried on the v5e at
+    2000 columns that XLA:CPU does not clone the pool for, this one is the
+    quickest end to end (PERF.md section 5)."""
+    n_cols, num_bins = hist_small.shape[:2]
+    parent = pool_hist(lax.dynamic_index_in_dim(store, leaf, axis=0,
+                                                keepdims=False),
+                       n_cols, num_bins)
+    hist2 = lax.optimization_barrier(
+        jnp.stack([hist_small, parent - hist_small]))
+    tiles2 = jnp.stack([pool_flat(hist_small), pool_flat(hist2[1])])
+    store = store.at[pair].set(tiles2, unique_indices=True,
+                               mode="promise_in_bounds")
+    return store, hist2
 
 
 def pool_rows(res: SplitResult, axis: int):
@@ -1063,7 +1115,10 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
             else hist_root, root_g, root_h, root_c, feat_ok_all)
         res_root = _depth_gate(res_root, jnp.asarray(0), cfg.max_depth)
 
-        hist_store0 = jnp.zeros((L, 3 * fh * cfg.max_bin), dtype)
+        k_pool = pool_tiles(fh, cfg.max_bin)
+        obs_counters.inc("hist_pool_layout", leaf_tiles=k_pool // POOL_TILE[0],
+                         pad=k_pool * POOL_TILE[1] - 3 * fh * cfg.max_bin)
+        hist_store0 = jnp.zeros((L, k_pool, POOL_TILE[1]), dtype)
         hist_store0 = hist_store0.at[0].set(pool_flat(hist_root))
         feat_ok_store0 = jnp.zeros((L, num_logical), bool).at[0].set(
             root_feat_ok)
@@ -1214,28 +1269,13 @@ def make_grower(cfg: GrowerConfig, strategy=None, pack_plan=None,
                                             (order, sstart, scnt))
                 hist_small = globalize(hist_small)
             # the pool's own work under one name: the parent's read, the
-            # subtraction and the pair write are [F, B, 3] each, which on a
-            # wide data set is most of a split outside the kernel
+            # subtraction and the pair write.  Everything downstream runs in
+            # (smaller, larger) order and is written back through the
+            # PERMUTED pair index
             with jax.named_scope("hist_pool"):
-                hist_parent = pool_hist(
-                    lax.dynamic_index_in_dim(state.hist_store, l, axis=0,
-                                             keepdims=False),
-                    fh, cfg.max_bin)
-                hist_large = hist_parent - hist_small
-                # everything downstream runs in (smaller, larger) order and is
-                # written back through the PERMUTED pair index — the former
-                # [F, B, 3]-wide hist_l/hist_r selects become two scalar-level
-                # index selects (same slots, same values, fewer wide ops).
-                # Both children still land in the store through ONE fused pair
-                # scatter: the round-7 discovery stands — a read-then-double-
-                # dynamic_update_slice chain on the carried pool made XLA:CPU
-                # clone all 22 MB of it twice per split (docs/PERF.md round 7;
-                # pinned by tests/test_grow_jaxpr.py).
-                hist2 = jnp.stack([hist_small, hist_large])
                 pair_sl = jnp.where(small_left, pair_lr, pair_lr[::-1])
-                hist_store = state.hist_store.at[pair_sl].set(
-                    pool_flat(hist2), unique_indices=True,
-                    mode="promise_in_bounds")
+                hist_store, hist2 = pool_split(state.hist_store, l, pair_sl,
+                                               hist_small)
 
             # children scan only the features the PARENT found splittable
             # (serial_tree_learner.cpp:406-417 pruning heuristic).  Both

@@ -32,10 +32,12 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp
 
 from lightgbm_tpu.grower import (FeatureMeta, GrowerConfig, _bucket_sizes,
-                                 _order_tail, _partition_sizes, make_grower)
+                                 _order_tail, _partition_sizes, make_grower,
+                                 pool_tiles)
 from lightgbm_tpu.utils.jaxpr_audit import audit_loop_body, find_while_body
 
 N, F, B, L = 32768, 8, 64, 15
+K = pool_tiles(F, B)     # the pool's rows of 128 lanes a leaf: 16
 
 
 def _grow_and_args(split_find="fused", has_missing=True):
@@ -59,7 +61,9 @@ def _grow_and_args(split_find="fused", has_missing=True):
 def test_loop_body_has_no_unsanctioned_big_ops(split_find):
     grow, args = _grow_and_args(split_find)
     jaxpr = jax.make_jaxpr(grow)(*args)
-    store_elems = L * F * B * 3
+    # re-recorded on purpose when the pool became [L, K, 128], a leaf
+    # whole (8, 128) tiles (3 * F * B = 1,536 floats in K = 16 rows)
+    store_elems = L * K * 128
 
     # O(N) audit: find-pair candidate arrays ([2, F, 2B, 4] = 8192 elems)
     # sit well under N, a stale-leaf rescan ([L, F, 2B, 4] = 61440) well
@@ -196,15 +200,23 @@ def test_routing_is_one_buffer_of_words_behind_a_barrier():
     buffer that an ``optimization_barrier`` pins: ``rl``'s update and the
     bit table both read it.  Without the barrier the v5e's compiler
     routed the column twice, the second time into a byte a row at 0.58 ms
-    a split (PERF.md section 6, PR 35)."""
+    a split (PERF.md section 6, PR 35).  The body's one other barrier is
+    the pool's: the children's [2, F, B, 3] histograms, one buffer that
+    the scan and the pool's write both read (``grower.pool_split``)."""
     grow, args = _grow_and_args()
     body = find_while_body(jax.make_jaxpr(grow)(*args))
     barriers = [e for e in body.eqns
                 if e.primitive.name == "optimization_barrier"]
-    assert len(barriers) == 1
-    assert "partition" in str(barriers[0].source_info.name_stack)
+    assert len(barriers) == 2
+    routing = [e for e in barriers
+               if "partition" in str(e.source_info.name_stack)]
+    assert len(routing) == 1
     assert [(v.aval.shape, str(v.aval.dtype))
-            for v in barriers[0].outvars] == [((N,), "uint32")]
+            for v in routing[0].outvars] == [((N,), "uint32")]
+    pool = [e for e in barriers
+            if "hist_pool" in str(e.source_info.name_stack)]
+    assert [(v.aval.shape, str(v.aval.dtype)) for e in pool
+            for v in e.outvars] == [((2, F, B, 3), "float32")]
 
 
 def test_rl_is_carried_and_updated_by_one_select():
@@ -272,10 +284,12 @@ def test_compiled_body_has_no_full_pool_copies():
     """Recorded copy-free on jax 0.4.37, lost on jax 0.9.0 (XLA:CPU cloned
     the [L, F, B, 3] pool twice a split; the v5e's compiler relaid it whole
     once a split), and back since the pool is carried as [L, 3 * F * B]
-    rows (``grower.pool_flat``)."""
+    rows; re-recorded on purpose for the [L, K, 128] pool of whole tiles
+    (``grower.pool_flat``): no copy of it either."""
     grow, args = _grow_and_args()
     txt = jax.jit(grow).lower(*args).compile().as_text()
-    shape = f"f32\\[{L},{3 * F * B}\\]"
+    shape = f"f32\\[{L},{K},128\\]"
+    assert re.search(rf"{shape}", txt), "the pool's shape is not in the text"
     copies = re.findall(rf"= {shape}[^ ]* copy", txt)
     assert not copies, (
         f"{len(copies)} full hist_store copies in the compiled "

@@ -140,6 +140,39 @@ def test_categorical_fused_grower_lowers(v5e):
                meta, v5e((f,), jnp.bool_)).compile()
 
 
+def test_wide_grower_pool_is_whole_tiles(v5e):
+    """Epsilon's grower (2000 columns, 255 bins and leaves, the fused rung)
+    compiled for the v5e carries the per-leaf pool as ``[L, K, 128]`` under
+    the (8, 128) tiling, so a leaf is K / 8 whole tiles, and nothing under
+    ``hist_pool`` holds a leaf as a ``[1, 3FB]`` or ``[2, 3FB]`` row, the
+    shape whose leaf axis the compiler tiled as the sublanes.  No copy of
+    the whole pool either (about 20 s here at 16,384 rows)."""
+    import re
+    import jax.numpy as jnp
+    from lightgbm_tpu.grower import (FeatureMeta, GrowerConfig, make_grower,
+                                     pool_tiles)
+    n, f, b, L = 1 << 14, 2000, 255, 255
+    cfg = GrowerConfig(num_leaves=L, min_data_in_leaf=1,
+                       min_sum_hessian_in_leaf=100.0, max_bin=b,
+                       hist_method="fused")
+    meta = FeatureMeta(
+        num_bin=v5e((f,), jnp.int32), missing_type=v5e((f,), jnp.int32),
+        default_bin=v5e((f,), jnp.int32),
+        is_categorical=v5e((f,), jnp.bool_))
+    txt = jax.jit(make_grower(cfg)).lower(
+        v5e((n, f), jnp.uint8), v5e((n,), jnp.float32),
+        v5e((n,), jnp.float32), v5e((n,), jnp.float32),
+        meta, v5e((f,), jnp.bool_)).compile().as_text()
+    k = pool_tiles(f, b)
+    pool = f"f32[{L},{k},128]"
+    layouts = set(re.findall(re.escape(pool) + r"\{([^}]*)\}", txt))
+    assert layouts == {"2,1,0:T(8,128)"}, layouts
+    rows = [line for line in txt.splitlines() if "hist_pool" in line
+            and re.search(rf"f32\[[12],{3 * f * b}\]", line)]
+    assert not rows, rows[:3]
+    assert not re.findall(rf"= {re.escape(pool)}[^ ]* copy", txt)
+
+
 FULL_GROWER_PROOFS = pytest.mark.skipif(
     os.environ.get("LGBM_TPU_AOT_FULL") != "1",
     reason="~25 min of uncacheable XLA:TPU AOT compiles; run with "
